@@ -3,8 +3,7 @@ request handler holds the socket.
 
 ``.item()``, ``float(jnp_value)``, ``np.asarray(jax_value)``,
 ``jax.device_get`` and ``.block_until_ready()`` all block the calling
-thread until the device (possibly a remote-attached TPU, ~100ms RTT)
-finishes and the value lands on host. On the serving path that turns
+thread until the device finishes and the value lands on host. On the serving path that turns
 one stray scalar read into a full device round-trip per request —
 the latency regression PR 1's load tests kept rediscovering. Models
 must return device arrays; the serving layer converts ONCE at the

@@ -44,13 +44,20 @@ def _cmd_status(args, storage: Storage) -> int:
     except Exception as exc:
         print(f"[ERROR] Storage check failed: {exc}")
         return 1
-    try:
-        import jax
+    from predictionio_tpu import native
+    from predictionio_tpu.utils.accelerator import describe_devices
 
-        devices = jax.devices()
-        print(f"[INFO] JAX backend: {devices[0].platform} x{len(devices)}")
-    except Exception as exc:
-        print(f"[WARN] JAX unavailable: {exc}")
+    try:
+        print(f"[INFO] JAX devices: {describe_devices()}")
+    except RuntimeError as exc:
+        # NoAcceleratorError, or the named backend's own start-up error
+        print(f"[ERROR] JAX backend check failed: {exc}")
+        return 1
+    # which host paths are native: without g++ both quietly run their
+    # pure-Python twins, several times slower
+    print("[INFO] Native components: "
+          f"eventlog={'loaded' if native.load_eventlog() else 'python'} "
+          f"packer={'loaded' if native.load_bucketize() else 'numpy'}")
     print("[INFO] Your system is all ready to go.")
     return 0
 
@@ -298,6 +305,17 @@ def _scaling_requested(args) -> bool:
         args.scale_cooldown_s)) or args.scale_dry_run
 
 
+def _is_pio_deploy(argv: list[str]) -> bool:
+    """Whether a replica command line launches ``pio deploy`` (through
+    bin/pio or ``python -m predictionio_tpu.cli.pio``) — a JAX process,
+    unlike the storage- and JAX-free router that spawns it."""
+    import os
+
+    return "deploy" in argv and any(
+        os.path.basename(a) == "pio" or a == "predictionio_tpu.cli.pio"
+        for a in argv)
+
+
 def _cmd_router(args, storage: Storage) -> int:
     """`pio router` — the fleet tier (docs/fleet.md): a thin router
     fronting N engine-server replicas with health-driven membership,
@@ -423,6 +441,24 @@ def _cmd_router(args, storage: Storage) -> int:
                       "replicas the per-engine controllers scale).")
                 return 1
             scaling = True
+
+    if replica_cmd is not None and _is_pio_deploy(shlex.split(replica_cmd)):
+        # each `pio deploy` replica opens the accelerator for itself
+        from predictionio_tpu.utils.accelerator import (
+            SharedChipError,
+            refuse_shared_chip,
+        )
+
+        most = max([len(replica_specs) + len(engine_replica_specs),
+                    args.max_replicas or 0]
+                   + [spec.max_replicas or 0 for spec in engine_specs])
+        try:
+            refuse_shared_chip(
+                "pio router --supervise --replica-cmd 'pio deploy ...' "
+                f"with up to {most} replicas", most)
+        except SharedChipError as exc:
+            print(f"[ERROR] {exc}")
+            return 1
 
     backends = tuple(args.backend or ()) + tuple(
         s.address for s in replica_specs)
@@ -1431,10 +1467,6 @@ def build_parser() -> argparse.ArgumentParser:
     return parser
 
 
-#: commands that run the JAX pipeline and therefore take part in the
-#: multi-host jax.distributed barrier
-COMPUTE_COMMANDS = frozenset({"train", "eval", "deploy", "run"})
-
 #: commands that never touch storage — they must work (CI lint hooks,
 #: version probes, the storage-free fleet router and its trace viewer)
 #: even when PIO_STORAGE_* env is broken or absent. `wal` rides here
@@ -1478,14 +1510,6 @@ def main(argv: list[str] | None = None) -> int:
     if not args.command:
         parser.print_help()
         return 1
-    if args.command in COMPUTE_COMMANDS:
-        # multi-host: wire jax.distributed over DCN when PIO_NUM_HOSTS > 1
-        # (the spark-submit --master surface of the reference). Only
-        # compute commands join the coordinator barrier — admin commands
-        # must not block on the other hosts.
-        from predictionio_tpu.parallel.distributed import maybe_initialize_distributed
-
-        maybe_initialize_distributed()
     if args.command in STORAGE_FREE_COMMANDS or (
             args.command == "status" and getattr(args, "router", None)):
         # `pio status --router` inspects a running router over HTTP —

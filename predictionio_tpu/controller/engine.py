@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import sys
 from typing import TYPE_CHECKING, Any, Callable, Generic, Mapping, Sequence
 
 from predictionio_tpu.controller.base import (
@@ -56,6 +57,22 @@ def _sanity_check(obj: Any, name: str, enabled: bool) -> None:
     if enabled and isinstance(obj, SanityCheck):
         logger.info("%s: running sanity check", name)
         obj.sanity_check()
+
+
+def _wait_for_device(model: Any) -> None:
+    """Block until the device arrays a trained model holds are computed.
+    JAX dispatch returns before the device finishes, so without this the
+    ``train`` stage would time the enqueue and bill the device's work to
+    whichever later stage first reads the arrays (``persist``). Framework
+    models are dataclasses — pytree leaves to JAX — so their fields are
+    unwrapped one level. A process that never imported jax has nothing
+    to wait for."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    if dataclasses.is_dataclass(model) and not isinstance(model, type):
+        model = [getattr(model, f.name) for f in dataclasses.fields(model)]
+    jax.block_until_ready(model)
 
 
 @dataclasses.dataclass
@@ -164,13 +181,19 @@ class Engine(Generic[TD, EI, PD, Q, P, A]):
                               not params.skip_sanity_check)
                 if hasattr(algo, "gather_model"):
                     model = algo.gather_model(ctx, model)
+                _wait_for_device(model)
             models.append(model)
 
-        persisted = [
-            algo.make_persistent_model(ctx.with_workflow_params(algorithm_slot=i), model)
-            if params.save_model else None
-            for i, (algo, model) in enumerate(zip(algorithms, models))
-        ]
+        # checkpoints are written here (the model-store row follows in
+        # run_train, under the same stage name): at catalog scale this
+        # is minutes of the run, and belongs to "persist", not to no stage
+        with span("persist"):
+            persisted = [
+                algo.make_persistent_model(
+                    ctx.with_workflow_params(algorithm_slot=i), model)
+                if params.save_model else None
+                for i, (algo, model) in enumerate(zip(algorithms, models))
+            ]
         return TrainResult(models=models, persisted=persisted)
 
     # -- deploy-time model restoration (Engine.prepareDeploy, :199-257) -----

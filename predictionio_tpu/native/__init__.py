@@ -5,21 +5,25 @@ eventlog.cc) or None when a toolchain isn't available — callers fall
 back to the pure-Python codec in storage/binevents.py, which implements
 the identical byte format.
 
-The library is built on demand with g++ (baked into the image) and
-cached next to the source; a rebuild happens only when the source is
-newer than the .so.
+Each library is built on demand with g++ (baked into the image) next to
+its source, under a name that carries a hash of that source: a binary
+is reused only when it was built from exactly the source on disk, so a
+stale or copied-in ``.so`` (they are git-ignored) is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 
+logger = logging.getLogger(__name__)
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "eventlog.cc")
-_SO = os.path.join(_DIR, "_eventlog.so")
+_CXX = ("g++", "-O2", "-std=c++17", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -30,29 +34,37 @@ _bucketize_lib: ctypes.CDLL | None = None
 _bucketize_failed = False
 
 
-def _build(src: str, so: str) -> str | None:
+def _build(src: str, stem: str) -> str | None:
+    """Path of ``<stem>-<hash>.so`` beside ``src``, compiled now unless
+    that exact name already exists; the hash covers the source bytes
+    and the compiler command. None when there is no toolchain."""
     try:
-        if os.path.exists(so) and (
-            not os.path.exists(src)  # prebuilt .so shipped without source
-            or os.path.getmtime(so) >= os.path.getmtime(src)
-        ):
-            return so
-    except OSError:
-        pass
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(" ".join(_CXX).encode() + f.read())
+    except OSError as e:
+        logger.warning("native source %s unreadable (%s); using the "
+                       "pure-Python path", src, e)
+        return None
+    so = os.path.join(os.path.dirname(src),
+                      f"{stem}-{digest.hexdigest()[:16]}.so")
+    if os.path.exists(so):
+        return so
     # compile to a per-pid temp path, then atomically rename into place:
     # two processes racing on first use must never dlopen a partially
     # written .so (rename is atomic within the directory)
     tmp = f"{so}.tmp.{os.getpid()}"
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src],
+            [*_CXX, "-o", tmp, src],
             check=True,
             capture_output=True,
             timeout=120,
         )
         os.replace(tmp, so)
         return so
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        logger.warning("building %s failed (%s); using the pure-Python "
+                       "path", os.path.basename(src), e)
         try:
             if os.path.exists(tmp):
                 os.remove(tmp)
@@ -61,17 +73,13 @@ def _build(src: str, so: str) -> str | None:
         return None
 
 
-def _ensure_built() -> str | None:
-    return _build(_SRC, _SO)
-
-
 def load_eventlog() -> ctypes.CDLL | None:
     """Compile (if needed) and load the native event log; None on failure."""
     global _lib, _load_failed
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        so = _ensure_built()
+        so = _build(os.path.join(_DIR, "eventlog.cc"), "_eventlog")
         if so is None:
             _load_failed = True
             return None
@@ -120,8 +128,7 @@ def load_bucketize() -> ctypes.CDLL | None:
     with _bucketize_lock:
         if _bucketize_lib is not None or _bucketize_failed:
             return _bucketize_lib
-        so = _build(os.path.join(_DIR, "bucketize.cc"),
-                    os.path.join(_DIR, "_bucketize.so"))
+        so = _build(os.path.join(_DIR, "bucketize.cc"), "_bucketize")
         if so is None:
             _bucketize_failed = True
             return None

@@ -369,10 +369,7 @@ def _cost_analysis_flops(jitted: Any, args: tuple,
     serving."""
     try:
         compiled = jitted.lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops") if hasattr(cost, "get") else None
+        flops = compiled.cost_analysis().get("flops")
         # XLA reports -1 for programs it cannot price — that is "no
         # data", not negative work
         return float(flops) if flops is not None and flops >= 0 else None

@@ -236,15 +236,13 @@ class TrainProfiler:
         if trace is not None:
             trace.observer = self._on_span
         if self.profile_dir:
-            try:
-                import jax.profiler
+            # asked for by name (--profile-dir): a trace that cannot
+            # start fails the run instead of leaving an empty directory
+            import jax.profiler
 
-                os.makedirs(self.profile_dir, exist_ok=True)
-                jax.profiler.start_trace(self.profile_dir)
-                self._jax_trace_on = True
-            except Exception as e:
-                logger.warning("--profile-dir: jax.profiler trace "
-                               "unavailable (%s); continuing without", e)
+            os.makedirs(self.profile_dir, exist_ok=True)
+            jax.profiler.start_trace(self.profile_dir)
+            self._jax_trace_on = True
         # the wall clock starts AFTER the capture machinery is up:
         # jax.profiler.start_trace costs seconds on a cold process, and
         # charging it to the run would deflate MFU and report an
@@ -322,6 +320,11 @@ class TrainProfiler:
             "mfuReason": mfu_reason,
             "hbm": {
                 "peakBytes": hbm_peak,
+                # where the arrays landed: one entry per local device
+                "perDevice": {
+                    label: {"peakBytes": s.get("peak_bytes_in_use"),
+                            "bytesInUse": s.get("bytes_in_use")}
+                    for label, s in sorted(mem.items())} or None,
                 "perStage": {name: dict(vals)
                              for name, vals in self._stage_mem.items()}
                             or None,

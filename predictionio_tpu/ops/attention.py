@@ -21,12 +21,13 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from predictionio_tpu.utils.jax_compat import shard_map
+import numpy as np
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-_NEG = jnp.float32(-1e30)  # large-negative instead of -inf: keeps exp() NaN-free
+# large-negative instead of -inf: keeps exp() NaN-free. A NumPy scalar:
+# a jnp one would start the JAX backend when this module is imported
+_NEG = np.float32(-1e30)
 
 
 def full_attention(

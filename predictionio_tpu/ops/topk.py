@@ -20,7 +20,9 @@ import numpy as np
 
 from predictionio_tpu.obs.compile import instrumented_jit
 
-NEG_INF = jnp.float32(-jnp.inf)
+# a NumPy scalar: a jnp one would start the JAX backend (and claim the
+# chip) when this module is imported
+NEG_INF = np.float32(-np.inf)
 
 
 @partial(instrumented_jit, static_argnames=("k",))
@@ -313,8 +315,6 @@ def _sharded_topk_fn(mesh, k: int, shard_rows: int):
     recompile the eval hot path on every invocation."""
     from jax.sharding import PartitionSpec as P
 
-    from predictionio_tpu.utils.jax_compat import shard_map
-
     # a shard can only contribute its own rows: on tall-skinny meshes
     # (model axis > I/k, e.g. 1×8 serving a small catalog) the local
     # top-k clamps to shard_rows and the gathered n_model * k_loc >= k
@@ -343,10 +343,9 @@ def _sharded_topk_fn(mesh, k: int, shard_rows: int):
         out_specs=(P("data", None), P("data", None)),
     )
     # the all-gather makes both outputs replicated over "model", which
-    # the static replication checker cannot infer — disable it (the
-    # jax_compat shim normalizes the check_rep -> check_vma rename)
+    # the static replication checker cannot infer — disable it
     return instrumented_jit(
-        shard_map(local, mesh=mesh, check_vma=False, **specs),
+        jax.shard_map(local, mesh=mesh, check_vma=False, **specs),
         jit_name="sharded_topk")
 
 

@@ -384,11 +384,11 @@ class ALSAlgorithm(ShardedAlgorithm):
         B = len(known)
         # pad the BATCH dimension to the shared power-of-two menu
         # (ops/topk.BATCH_WIDTHS): every distinct B is a fresh jit
-        # signature, and on a remote-compile backend each costs tens
-        # of seconds — the serving micro-batcher produces arbitrary
-        # batch sizes, so without this a varying-concurrency workload
-        # compiles forever instead of dispatching (padding rows repeat
-        # row 0 and are sliced off the result). Eval-scale batches
+        # signature and a fresh compile — the serving micro-batcher
+        # produces arbitrary batch sizes, so without this a
+        # varying-concurrency workload keeps compiling instead of
+        # dispatching (padding rows repeat row 0 and are sliced off
+        # the result). Eval-scale batches
         # pass through unpadded (serving_batch docstring). The
         # recompile sentinel (obs/compile.py) watches this contract in
         # production: a post-warmup width that misses the compiled

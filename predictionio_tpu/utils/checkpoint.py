@@ -9,8 +9,9 @@ restore places shards straight back onto the target mesh — no
 gather-to-host, no retrain-on-deploy, which is the SURVEY.md §7
 "better than the reference" contract for sharded model persistence.
 
-A plain-numpy fallback (the ``npz`` backend) keeps the same directory
-API working when orbax is unavailable.
+A plain-numpy backend (``npz``) keeps the same directory API working
+where orbax is not installed. Where it is installed, a failed orbax
+save raises — the format never changes quietly.
 
 Crash safety (docs/fleet.md "trustworthy generations"): a canary-vs-
 stable rollout is only meaningful when each replica group really runs
@@ -112,19 +113,19 @@ def save_sharded(directory: str, arrays: Mapping[str, Any]) -> str:
     os.makedirs(directory, exist_ok=True)
     ocp = _ocp()
     if ocp is not None:
-        try:
-            path = os.path.join(os.path.abspath(directory), _ORBAX_SUBDIR)
-            with ocp.Checkpointer(ocp.StandardCheckpointHandler()) as ckptr:
-                ckptr.save(path, dict(arrays), force=True)
-            # shape/dtype manifest only: hashing a sharded array would
-            # gather it to host (module docstring)
-            _write_meta(directory, "orbax", {
-                name: _array_meta(name, v, checksum=False)
-                for name, v in arrays.items()
-            })
-            return "orbax"
-        except Exception as exc:
-            logger.warning("orbax save failed (%s); falling back to npz", exc)
+        # a failed orbax save raises: quietly writing npz instead would
+        # change the on-disk format (and gather sharded tables to host)
+        # behind the operator's back
+        path = os.path.join(os.path.abspath(directory), _ORBAX_SUBDIR)
+        with ocp.Checkpointer(ocp.StandardCheckpointHandler()) as ckptr:
+            ckptr.save(path, dict(arrays), force=True)
+        # shape/dtype manifest only: hashing a sharded array would
+        # gather it to host (module docstring)
+        _write_meta(directory, "orbax", {
+            name: _array_meta(name, v, checksum=False)
+            for name, v in arrays.items()
+        })
+        return "orbax"
     manifest = {
         name: _array_meta(name, v, checksum=True)
         for name, v in arrays.items()
@@ -245,13 +246,8 @@ def load_sharded(
         path = os.path.join(os.path.abspath(directory), _ORBAX_SUBDIR)
         with ocp.Checkpointer(ocp.StandardCheckpointHandler()) as ckptr:
             if shardings:
-                ckpt_meta = ckptr.metadata(path)
-                # orbax API drift: metadata() returns an object with
-                # .item_metadata on older releases, a plain dict of
-                # per-array metadata on newer ones
-                items = getattr(ckpt_meta, "item_metadata", ckpt_meta)
                 targets = {}
-                for name, m in items.items():
+                for name, m in ckptr.metadata(path).item_metadata.items():
                     sh = shardings.get(name)
                     if sh is not None:
                         targets[name] = jax.ShapeDtypeStruct(

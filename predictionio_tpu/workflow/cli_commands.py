@@ -68,6 +68,28 @@ def _check_template_min_version(template_json: str = "template.json") -> bool:
     return True
 
 
+def _claim_devices(launcher: str = "", processes: int = 1) -> bool:
+    """The start-of-command device rule of every compute command
+    (utils/accelerator): place the compile cache, claim the devices and
+    print which they are — or, for a ``launcher`` that fans out over
+    ``processes`` JAX processes, refuse when those would share a chip
+    and otherwise leave the claim to each child (a process that has
+    touched JAX must not fork workers). False, with the error printed,
+    when there is no accelerator and JAX_PLATFORMS did not ask for cpu."""
+    from predictionio_tpu.utils import accelerator
+
+    try:
+        accelerator.refuse_shared_chip(launcher, processes)
+        if processes == 1:
+            accelerator.start_compute()
+    except RuntimeError as exc:
+        # NoAcceleratorError / SharedChipError, or the start-up error of
+        # the backend JAX_PLATFORMS names
+        print(f"[ERROR] {exc}")
+        return False
+    return True
+
+
 def _serve(server, label: str, ip: str) -> int:
     """Print the bound address and block until interrupt — shared by every
     server-launching subcommand."""
@@ -111,7 +133,7 @@ def _configure_train(sub) -> None:
 def _cmd_train(args, storage) -> int:
     from predictionio_tpu.workflow.train import run_train
 
-    if not _check_template_min_version():
+    if not _claim_devices() or not _check_template_min_version():
         return 1
     variant = _load_variant(args.engine_json)
     if variant is None:
@@ -184,8 +206,14 @@ def _configure_eval(sub) -> None:
 
 
 def _cmd_eval(args, storage) -> int:
-    from predictionio_tpu.workflow.evaluation import run_evaluation
+    from predictionio_tpu.workflow.evaluation import (
+        resolve_parallel,
+        run_evaluation,
+    )
 
+    parallel = resolve_parallel(args.parallel)
+    if not _claim_devices(f"pio eval --parallel {parallel}", parallel):
+        return 1
     generator = args.params_generator or _default_generator(args.evaluation)
     try:
         outcome = run_evaluation(
@@ -193,7 +221,7 @@ def _cmd_eval(args, storage) -> int:
             generator,
             workflow_params=WorkflowParams(batch=args.batch),
             storage=storage,
-            parallel=args.parallel,
+            parallel=parallel,
         )
     except Exception as exc:
         # the instance row already says FAILED (workflow/evaluation.py)
@@ -367,7 +395,9 @@ def _deploy_worker(config) -> None:
     from predictionio_tpu.api.engine_server import create_engine_server
     from predictionio_tpu.serving.placement import apply_worker_affinity
     from predictionio_tpu.storage.registry import Storage
+    from predictionio_tpu.utils.accelerator import start_compute
 
+    start_compute()
     # before the model loads, so its pages fault in on the pinned
     # cores; a respawn re-applies (the index rides the config, and the
     # stripe is carved from the CLI's pre-pin CPU snapshot — a respawn
@@ -438,6 +468,8 @@ def _cmd_deploy(args, storage) -> int:
         }.items() if v is not None},
     )
     workers = max(1, config.workers)
+    if not _claim_devices(f"pio deploy --workers {workers}", workers):
+        return 1
     if workers == 1:
         if args.supervise:
             # nothing to supervise: the supervisor owns worker
@@ -548,6 +580,11 @@ def _cmd_deploy(args, storage) -> int:
         # the parent is worker 0 of the pool: pin it to its own stripe
         # (carved from the same pre-pin snapshot the workers use)
         apply_worker_affinity(0, workers, cpus=config.cpu_allowlist)
+        # only now that every sibling has forked may this process touch
+        # JAX (_claim_devices)
+        from predictionio_tpu.utils.accelerator import start_compute
+
+        start_compute()
         server = create_engine_server(storage=storage, config=config)
         print(f"[INFO] Engine instance "
               f"{server.service.deployed.instance.id} listening on "
@@ -793,6 +830,8 @@ def _cmd_run(args, storage) -> int:
     with storage initialised."""
     import importlib
 
+    if not _claim_devices():
+        return 1
     target = args.main
     mod_name, _, fn_name = target.partition(":")
     fn_name = fn_name or "main"

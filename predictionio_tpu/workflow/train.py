@@ -133,9 +133,11 @@ def run_train(
     # read/prepare/train stages against the ambient binding, persist is
     # timed here, and `pio train` prints the breakdown
     trace = Trace("train", request_id=instance_id)
-    if profiler is not None:
-        profiler.begin(trace)
     try:
+        if profiler is not None:
+            # inside the try: a --profile-dir trace that cannot start
+            # marks the instance FAILED and fails the command
+            profiler.begin(trace)
         try:
             with use_trace(trace):
                 result = engine.train(ctx, engine_params)

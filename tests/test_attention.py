@@ -227,3 +227,30 @@ class TestPallasFlashAttention:
             q = jnp.zeros((1, 1, S, 8), jnp.float32)
             pa.flash_attention(q, q, q, causal=True, force=True)
             assert len(calls) == expect, (S, expect)
+        # K/V residency bounds the envelope too (what Mosaic accepted
+        # on a v5e): bf16 D=64 reaches the top, f32 D=64 stops at 8192
+        for dtype, S, expect in ((jnp.bfloat16, 16384, 1),
+                                 (jnp.float32, 8192, 1),
+                                 (jnp.float32, 16384, 0)):
+            calls.clear()
+            q = jnp.zeros((1, 1, S, 64), dtype)
+            pa.flash_attention(q, q, q, causal=True)
+            assert len(calls) == expect, (dtype, S)
+
+    def test_compiled_kernel_failure_raises(self, monkeypatch):
+        """On a TPU a kernel that fails to build or compile raises; it
+        never answers with XLA's result (which would hide a broken
+        kernel behind a correct one)."""
+        from predictionio_tpu.ops import pallas_attention as pa
+
+        def refuse(*args):
+            raise RuntimeError("Mosaic refused the kernel")
+
+        monkeypatch.setattr(pa, "_mode", lambda: "compiled")
+        monkeypatch.setattr(pa, "_flash_call", refuse)
+        monkeypatch.setattr(
+            pa, "full_attention",
+            lambda *a, **kw: pytest.fail("fell back to full_attention"))
+        q = jnp.zeros((1, 1, 2048, 8), jnp.float32)
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            pa.flash_attention(q, q, q, causal=True)

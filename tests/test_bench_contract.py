@@ -212,12 +212,15 @@ def test_single_json_line_with_primary_contract(tiny_bench, capsys, monkeypatch)
 
 def test_section_failure_keeps_primary_metric(tiny_bench, capsys, monkeypatch):
     """A crashing section must surface as error_<name>, never lose the
-    headline metric (the driver records whatever line is printed)."""
+    headline metric (the driver records whatever line is printed) —
+    and fail the run: only --skip-heavy's skips leave the exit code 0."""
     monkeypatch.setattr("sys.argv", ["bench.py"])
     monkeypatch.setattr(
         tiny_bench, "bench_quality",
         lambda: (_ for _ in ()).throw(RuntimeError("boom")))
-    tiny_bench.main()
+    with pytest.raises(SystemExit) as exit_info:
+        tiny_bench.main()
+    assert exit_info.value.code == 1
     line = json.loads(capsys.readouterr().out.strip())
     assert line["value"] > 0
     assert "error_quality" in line and "boom" in line["error_quality"]

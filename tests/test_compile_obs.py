@@ -387,8 +387,8 @@ class TestTrainProfileCli:
         out = capsys.readouterr().out
         assert "Train profile:" in out
         assert "TRAIN_REPORT.json" in out
-        # --profile-dir captured a jax.profiler trace (or degraded with
-        # a warning — the directory at least exists either way)
+        # --profile-dir captured a jax.profiler trace (a trace that
+        # cannot start fails the command: obs/device.TrainProfiler)
         assert (tmp_path / "jaxtrace").is_dir()
         report = json.loads((tmp_path / "TRAIN_REPORT.json").read_text())
         assert report["schema"] == "pio.train_report.v1"
