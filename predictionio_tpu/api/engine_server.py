@@ -1399,6 +1399,18 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _respond(self, status: int, payload: Any,
                  extra_headers: Mapping[str, str] | None = None) -> None:
+        trace = getattr(self, "_trace", None)
+        if trace is None:
+            self._write_response(status, payload, extra_headers)
+            return
+        # the last stretch of a traced query that does work: body
+        # encode, header lines, the buffered write (the socket flush
+        # itself happens after _dispatch, outside every span)
+        with trace.span("respond"):
+            self._write_response(status, payload, extra_headers)
+
+    def _write_response(self, status: int, payload: Any,
+                        extra_headers: Mapping[str, str] | None) -> None:
         self._last_status = status
         if isinstance(payload, _HtmlPage):
             data = str(payload).encode()

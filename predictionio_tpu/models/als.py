@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.obs.compile import instrumented_jit
+from predictionio_tpu.obs.trace import span
 from predictionio_tpu.ops import ann as ann_ops
 from predictionio_tpu.ops import topk as topk_ops
 from predictionio_tpu.utils.bimap import BiMap, EntityIdIxMap
@@ -590,34 +591,42 @@ class ALSModel:
         ann through the IVF probe + exact-rescore kernel (ops/ann) —
         one jitted dispatch either way. ``allow=None`` uses the
         device-cached all-ones vector."""
-        uv = self.user_factors[jnp.asarray(np.asarray(uixs,
-                                                      dtype=np.int32))]
-        allow_v = self._allow_or_default(allow)
-        if self._ann_active():
-            centroids, flat_items, flat_vecs, cell_offset, nprobe, rescore = \
-                self._ann_args()
-            vals, idxs = ann_ops.ann_topk(
-                uv, self.item_factors, centroids, flat_items, flat_vecs,
-                cell_offset, jnp.asarray(seen_cols), jnp.asarray(seen_mask),
-                allow_v, k, nprobe, rescore)
-            self._record_ann(
-                self.ann_index.shortlist_width(nprobe, rescore),
-                int(uv.shape[0]))
-            return vals, idxs
-        mesh = self._serving_mesh()
-        if mesh is not None and allow_v.ndim == 1:
-            # deployed-sharded dispatch (docs/parallelism.md): local
-            # top-k per model shard, candidate all-gather, global merge
-            return topk_ops.recommend_topk_sharded(
+        # dispatch.gather / dispatch.enqueue: ambient spans on the
+        # batcher's per-dispatch trace (no-ops with tracing off). Both
+        # time the HOST side only — upload + launch return before the
+        # device finishes; batch_predict's dispatch.device_wait awaits it
+        with span("dispatch.gather"):
+            # an eager gather: a device launch of its own before the
+            # top-k program
+            uv = self.user_factors[jnp.asarray(np.asarray(uixs,
+                                                          dtype=np.int32))]
+            allow_v = self._allow_or_default(allow)
+        with span("dispatch.enqueue"):
+            if self._ann_active():
+                centroids, flat_items, flat_vecs, cell_offset, nprobe, \
+                    rescore = self._ann_args()
+                vals, idxs = ann_ops.ann_topk(
+                    uv, self.item_factors, centroids, flat_items, flat_vecs,
+                    cell_offset, jnp.asarray(seen_cols),
+                    jnp.asarray(seen_mask), allow_v, k, nprobe, rescore)
+                self._record_ann(
+                    self.ann_index.shortlist_width(nprobe, rescore),
+                    int(uv.shape[0]))
+                return vals, idxs
+            mesh = self._serving_mesh()
+            if mesh is not None and allow_v.ndim == 1:
+                # deployed-sharded dispatch (docs/parallelism.md): local
+                # top-k per model shard, candidate all-gather, global merge
+                return topk_ops.recommend_topk_sharded(
+                    uv, self.item_factors,
+                    jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
+                    jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
+                    allow_v, k, mesh)
+            return topk_ops.recommend_topk_fused(
                 uv, self.item_factors,
-                jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
-                jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
-                allow_v, k, mesh)
-        return topk_ops.recommend_topk_fused(
-            uv, self.item_factors,
-            # NumPy stays NumPy on purpose: the dispatcher's host-side
-            # _trim_seen can only right-size concrete host arrays
-            seen_cols, seen_mask, allow_v, k)
+                # NumPy stays NumPy on purpose: the dispatcher's host-side
+                # _trim_seen can only right-size concrete host arrays
+                seen_cols, seen_mask, allow_v, k)
 
     def predict_rating(self, user_id: str, item_id: str) -> float | None:
         uix = self.user_ids.get(user_id)

@@ -17,7 +17,11 @@ Propagation has two legs:
   (``EngineService._query_with_deadline``) inherit the trace for free.
 - **explicit** — queue handoffs (QueryBatcher.submit) carry the trace
   object on the queue entry; the dispatcher thread calls
-  ``Trace.add_span`` with externally measured intervals.
+  ``Trace.add_span`` with externally measured intervals. One dispatch
+  serves several requests, so the dispatcher binds ONE ambient trace of
+  its own around ``query_batch`` (only when a traced request rides in
+  the batch) and copies what ``span()`` recorded there onto each
+  request's trace (``Trace.add_spans_from``).
 
 Traces are sampled into a bounded :class:`TraceLog` ring per server,
 served as JSON on ``GET /traces.json``.
@@ -189,6 +193,17 @@ class Trace:
                 pass
         return span_id
 
+    def add_spans_from(self, other: "Trace",
+                       parent_id: str = _ROOT_PARENT) -> None:
+        """Copy every span of ``other`` onto this trace as children of
+        ``parent_id`` — the batcher's per-dispatch trace, recorded once
+        on the dispatcher thread, lands on each coalesced request's own
+        trace (both clocks are ``time.perf_counter``)."""
+        origin = other.start_perf
+        for name, _, _, start, dur in other.spans():
+            self.add_span(name, origin + start, origin + start + dur,
+                          parent_id)
+
     def finish(self, **tags: Any) -> None:
         self._duration = time.perf_counter() - self._t0
         if tags:
@@ -337,12 +352,10 @@ class TraceLog:
     def __init__(self, maxlen: int = 64):
         self._lock = threading.Lock()
         self._ring: deque[Trace] = deque(maxlen=maxlen)
-        self._recorded = 0
 
     def record(self, trace: Trace) -> None:
         with self._lock:
             self._ring.append(trace)
-            self._recorded += 1
 
     def snapshot(self) -> list[dict]:
         with self._lock:
@@ -355,8 +368,3 @@ class TraceLog:
         with self._lock:
             traces = [t for t in self._ring if t.trace_id == trace_id]
         return [t.to_dict() for t in traces]
-
-    @property
-    def recorded(self) -> int:
-        with self._lock:
-            return self._recorded

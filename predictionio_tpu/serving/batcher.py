@@ -39,6 +39,7 @@ from concurrent.futures import TimeoutError as FuturesTimeoutError
 from typing import Any, NamedTuple
 
 from predictionio_tpu.api.stats import ServingStats
+from predictionio_tpu.obs.trace import Trace, use_trace
 from predictionio_tpu.serving.batch_policy import BatchPolicy, FixedBatchPolicy
 from predictionio_tpu.utils.resilience import (
     deadline_scope,
@@ -76,6 +77,22 @@ class _Pending(NamedTuple):
     #: (contextvars do not follow queue entries); None when tracing is
     #: off — the dispatcher's whole tracing cost is this None check
     trace: Any = None
+
+
+#: the spans that end where the dispatcher hands a result back
+_DISPATCH_SPANS = ("batcher.device_dispatch", "batcher.fallback_predict")
+
+
+def _record_wake(trace) -> None:
+    """``batcher.wake`` on the caller's trace: from the end of its
+    dispatch (read back from the trace the dispatcher wrote before
+    ``set_result``) to this thread running again."""
+    now = time.perf_counter()
+    for name, _, _, start, dur in reversed(trace.spans()):
+        if name in _DISPATCH_SPANS:
+            trace.add_span("batcher.wake", trace.start_perf + start + dur,
+                           now)
+            return
 
 
 class QueryBatcher:
@@ -127,8 +144,10 @@ class QueryBatcher:
         around the batch dispatch and any per-query fallbacks. A budget
         that is ALREADY exhausted fails here, before the queue. The
         caller's ``trace`` (obs/trace.py) rides the queue entry the
-        same way: the dispatcher records this query's queue-wait and
-        device-dispatch spans onto it."""
+        same way: the dispatcher records this query's queue-wait, hold
+        and device-dispatch spans (with the dispatch's own phases as
+        children) onto it, and the caller adds ``batcher.wake`` when it
+        runs again."""
         if self._stopped:
             raise RuntimeError("query batcher is stopped")
         rem = remaining_deadline()
@@ -154,7 +173,10 @@ class QueryBatcher:
                 except Exception:
                     pass
             try:
-                return fut.result(timeout=timeout)
+                result = fut.result(timeout=timeout)
+                if trace is not None:
+                    _record_wake(trace)
+                return result
             except FuturesTimeoutError:
                 if not fut.done():
                     # the WAIT expired (a blown budget) — not an
@@ -200,6 +222,10 @@ class QueryBatcher:
             item = self._queue.get()
             if item is None:
                 return
+            # the dispatcher is free from here on: what a query waits
+            # after this is the policy holding the door (batcher.hold),
+            # what it waited before was the previous dispatch
+            t_first = time.perf_counter()
             batch = [item]
             # the policy decides how long to hold the door for FUTURE
             # arrivals and how many to wait for (snapped to the
@@ -236,7 +262,7 @@ class QueryBatcher:
                         stop = True
                         break
                     batch.append(nxt)
-            self._finish(batch)
+            self._finish(batch, t_first)
             if stop:
                 return
 
@@ -257,7 +283,7 @@ class QueryBatcher:
             except Exception:
                 pass
 
-    def _finish(self, batch: list[_Pending]) -> None:
+    def _finish(self, batch: list[_Pending], t_first: float) -> None:
         # 1. fail anything already past its deadline — dispatching it
         # would burn a device slot on a client that stopped waiting
         now = time.monotonic()
@@ -287,18 +313,30 @@ class QueryBatcher:
             t0 = time.perf_counter()
             # queue-wait attribution (enqueue -> dispatch start): one
             # lock acquisition for the whole batch's samples, plus the
-            # per-entry trace spans when tracing rode along
+            # per-entry trace spans when tracing rode along;
+            # batcher.hold is the part of the wait spent with the
+            # dispatcher free (from the batch's first dequeue, or this
+            # entry's own arrival if later)
             self.stats.observe_queue_waits([t0 - e.t_enq for e in live])
-            for e in live:
-                if e.trace is not None:
-                    e.trace.add_span("batcher.queue_wait", e.t_enq, t0)
-            with self._scope(min(deadlines) if deadlines else None):
+            traced = [e for e in live if e.trace is not None]
+            for e in traced:
+                e.trace.add_span(
+                    "batcher.hold", max(t_first, e.t_enq), t0,
+                    e.trace.add_span("batcher.queue_wait", e.t_enq, t0))
+            # one ambient trace for the dispatch, only when a traced
+            # query rides in it: ``span()`` calls under query_batch
+            # (dispatch.* phases, obs/compile's xla_compile) record on
+            # it and are copied below onto each traced entry's trace
+            dispatch = Trace("dispatch") if traced else None
+            ambient = (use_trace(dispatch) if dispatch is not None
+                       else contextlib.nullcontext())
+            with self._scope(min(deadlines) if deadlines else None), ambient:
                 results = deployed.query_batch([g[0].query for g in groups])
             dt = time.perf_counter() - t0
             self.stats.observe_device_time(dt)
-            for e in live:
-                if e.trace is not None:
-                    e.trace.add_span("batcher.device_dispatch", t0, t0 + dt)
+            for e in traced:
+                e.trace.add_spans_from(dispatch, e.trace.add_span(
+                    "batcher.device_dispatch", t0, t0 + dt))
             # query_batch records request bookkeeping only for the
             # group leaders it saw; the deduped waiters were answered
             # by the same dispatch and must count as served requests

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from predictionio_tpu.controller import (
@@ -38,6 +39,7 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.controller.base import PersistentModelManifest
 from predictionio_tpu.models.als import ALSModel, build_allow_vector
+from predictionio_tpu.obs.trace import active_trace, span
 from predictionio_tpu.ops import topk as topk_ops
 from predictionio_tpu.ops.als import (
     RatingsCOO,
@@ -338,29 +340,71 @@ class ALSAlgorithm(ShardedAlgorithm):
         """All queries scored in one matmul + top_k — the RDD-join
         analogue (ALSAlgorithm batchPredict path). Queries carrying
         white/black-list filters need a per-query eligibility vector, so
-        they take the single-query path; the unfiltered rest batch."""
+        they take the single-query path; the unfiltered rest batch.
+
+        Each phase is an ambient ``dispatch.*`` span (obs/trace.span: a
+        no-op unless the batcher bound its per-dispatch trace), recorded
+        here and in ``ALSModel.batch_topk`` where the work happens:
+        prepare → gather → enqueue → device_wait → fetch → results."""
         if not queries:
             return []
+        with span("dispatch.prepare"):
+            single, unknown, known = self._route(model, queries)
+            batch = self._pad_batch(model, known) if known else None
+        out = [(qi, self.predict(model, q)) for qi, q in single]
+        out += [(qi, PredictedResult()) for qi in unknown]
+        if batch is None:
+            return out
+        uixs, cols, mask, k = batch
+        # the model dispatches by its configured retrieval: brute picks
+        # flat vs chunked-scan (ops/topk), ann probes the IVF index and
+        # exact-rescores the shortlist (ops/ann); seen arrays stay
+        # NumPy so the brute dispatcher's host-side _trim_seen can
+        # right-size them
+        vals, idxs = model.batch_topk(uixs, cols, mask, None, k)
+        if active_trace() is not None:
+            # traced dispatches only: split the wait for the device
+            # from the copy back; untraced, the first np.asarray below
+            # is the one sync, as it always was
+            with span("dispatch.device_wait"):
+                jax.block_until_ready((vals, idxs))
+        B = len(known)
+        with span("dispatch.fetch"):
+            vals = np.asarray(vals)[:B]
+            idxs = np.asarray(idxs)[:B]
+        with span("dispatch.results"):
+            inv = model.item_ids.inverse
+            for j, (qi, _, num) in enumerate(known):
+                scores = []
+                for v, i in zip(vals[j][:num], idxs[j][:num]):
+                    if not np.isfinite(v):
+                        break
+                    scores.append(ItemScore(item=inv[int(i)], score=float(v)))
+                out.append((qi, PredictedResult(item_scores=tuple(scores))))
+        return out
 
-        def single_path(q: Query) -> bool:
+    @staticmethod
+    def _route(model: ALSModel, queries):
+        """(single-path ``(qi, query)``, unknown-user ``qi``, known
+        ``(qi, user index, num)``) — which queries the batched kernel
+        can score."""
+        single, unknown, known = [], [], []
+        for qi, q in queries:
             # per-query eligibility vectors AND online-overlay users
             # (folded vector / cold-start items — the batched kernel
             # scores only the base tables; models/als.needs_online_path)
-            return (q.white_list is not None or bool(q.black_list)
-                    or model.needs_online_path(q.user))
+            if (q.white_list is not None or bool(q.black_list)
+                    or model.needs_online_path(q.user)):
+                single.append((qi, q))
+            elif q.user in model.user_ids:
+                known.append((qi, model.user_ids[q.user], q.num))
+            else:
+                unknown.append(qi)
+        return single, unknown, known
 
-        out = [(qi, self.predict(model, q)) for qi, q in queries
-               if single_path(q)]
-        queries = [(qi, q) for qi, q in queries if not single_path(q)]
-        known = [
-            (qi, model.user_ids[q.user], q.num)
-            for qi, q in queries
-            if q.user in model.user_ids
-        ]
-        out += [(qi, PredictedResult()) for qi, q in queries
-                if q.user not in model.user_ids]
-        if not known:
-            return out
+    def _pad_batch(self, model: ALSModel, known):
+        """(uixs, seen cols, seen mask, k) for ``batch_topk``, every
+        axis padded to its compile-shape menu."""
         uixs = np.asarray([u for _, u, _ in known], dtype=np.int32)
         max_num = max(n for _, _, n in known)
         # right-size the seen arrays to the smallest menu width covering
@@ -410,23 +454,7 @@ class ALSAlgorithm(ShardedAlgorithm):
         # menu-ized STATIC top_k width (ops/topk.serving_k: client-
         # controlled num must not retrace; results trim per query below)
         k = topk_ops.serving_k(min(max_num, n_items), n_items)
-        # the model dispatches by its configured retrieval: brute picks
-        # flat vs chunked-scan (ops/topk), ann probes the IVF index and
-        # exact-rescores the shortlist (ops/ann); seen arrays stay
-        # NumPy so the brute dispatcher's host-side _trim_seen can
-        # right-size them
-        vals, idxs = model.batch_topk(uixs, cols, mask, None, k)
-        vals = np.asarray(vals)[:B]
-        idxs = np.asarray(idxs)[:B]
-        inv = model.item_ids.inverse
-        for j, (qi, _, num) in enumerate(known):
-            scores = []
-            for v, i in zip(vals[j][:num], idxs[j][:num]):
-                if not np.isfinite(v):
-                    break
-                scores.append(ItemScore(item=inv[int(i)], score=float(v)))
-            out.append((qi, PredictedResult(item_scores=tuple(scores))))
-        return out
+        return uixs, cols, mask, k
 
     # -- persistence: orbax-style directory checkpoint + manifest ----------
     def make_persistent_model(self, ctx, model: ALSModel):

@@ -193,29 +193,10 @@ N_ITEMS = 37
 
 
 def _train_rec_model(storage, tmp_path, monkeypatch):
-    monkeypatch.setenv("PIO_MODEL_DIR", str(tmp_path))
-    app_id = storage.get_meta_data_apps().insert(App(0, "RecompileApp"))
-    events = storage.get_events()
-    events.init(app_id)
-    rng = np.random.default_rng(7)
-    for u in range(N_USERS):
-        for i in rng.choice(N_ITEMS, size=4, replace=False):
-            events.insert(
-                Event(event="rate", entity_type="user", entity_id=f"u{u}",
-                      target_entity_type="item", target_entity_id=f"i{i}",
-                      properties=DataMap({"rating": 5.0})), app_id)
-    variant = {
-        "id": "recompile",
-        "engineFactory":
-            "predictionio_tpu.templates.recommendation.engine_factory",
-        "datasource": {"params": {"app_name": "RecompileApp"}},
-        "algorithms": [{"name": "als",
-                        "params": {"rank": 5, "num_iterations": 2,
-                                   "lambda_": 0.05, "seed": 3}}],
-    }
-    outcome = run_train(variant=variant, storage=storage)
-    assert outcome.status == "COMPLETED"
-    return outcome
+    from tests.rec_engine import train_rec
+
+    return train_rec(storage, tmp_path, monkeypatch, n_users=N_USERS,
+                     n_items=N_ITEMS, app_name="RecompileApp")
 
 
 class TestServingRecompilePin:
@@ -280,6 +261,54 @@ class TestServingRecompilePin:
         assert result.item_scores
         assert "_serve_recommend" in rec.compiles_by_fn(), \
             rec.compiles_by_fn()
+        rec.reset()
+
+
+class TestBatchedRecompileSpan:
+    def test_off_menu_width_shows_xla_compile_inside_the_dispatch(
+            self, storage, tmp_path, monkeypatch):
+        """The batcher binds one ambient trace around ``query_batch``,
+        so the sentinel's ``xla_compile`` span — recorded on whatever
+        trace is active where the compile happens — reaches every
+        request that rode the compiling dispatch (it was silently lost
+        on the batched path before: the dispatcher thread had no
+        ambient trace)."""
+        import time
+        from concurrent.futures import Future
+
+        from predictionio_tpu.serving.batcher import _Pending
+        from predictionio_tpu.templates.recommendation import Query
+        from tests.rec_engine import post_query, start_rec_server
+
+        _train_rec_model(storage, tmp_path, monkeypatch)
+        rec = recorder()
+        rec.reset()
+        server = start_rec_server(storage, tracing=True)
+        try:
+            # the first answered query ends serving warm-up
+            assert post_query(server.port, {"user": "u1", "num": 4})[0] == 200
+            # an eval-scale batch (> BATCH_WIDTHS[-1]) handed to the
+            # dispatcher as the queue would: off the compiled menu, and
+            # not the width the pin test above left in the jit cache
+            now = time.perf_counter()
+            entries = [_Pending(Query(user=f"u{j}", num=4), Future(), None,
+                                None, None, now, Trace("queries.json"))
+                       for j in range(N_USERS - 7)]
+            server.service.batcher._finish(entries, now)
+        finally:
+            server.stop()
+        assert rec.totals()[2] == 1, rec.recompile_table()
+        for e in entries:
+            assert e.fut.result(timeout=0).item_scores
+            spans = {name: (parent, sid, start, start + dur)
+                     for name, parent, sid, start, dur in e.trace.spans()}
+            _, dispatch_id, d_start, d_end = spans["batcher.device_dispatch"]
+            parent, _, c_start, c_end = spans["xla_compile"]
+            assert parent == dispatch_id
+            assert d_start <= c_start and c_end <= d_end
+            # ... and names the phase that paid it: the launch
+            _, _, e_start, e_end = spans["dispatch.enqueue"]
+            assert e_start <= c_start and c_end <= e_end
         rec.reset()
 
 

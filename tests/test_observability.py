@@ -270,7 +270,7 @@ class TestTrace:
             tr.finish()
             log.record(tr)
         snap = log.snapshot()
-        assert len(snap) == 4 and log.recorded == 10
+        assert len(snap) == 4
         assert snap[0]["name"] == "t9"   # newest first
 
 
@@ -582,6 +582,105 @@ class TestEngineServerObservability:
             assert doc == {"tracing": False, "traces": []}
         finally:
             server.stop()
+
+
+# ---------------------------------------------------------------------------
+# the dispatch split (PR 23): phases of batcher.device_dispatch recorded
+# where the work happens, through the REAL recommendation template
+# ---------------------------------------------------------------------------
+
+def _end(span_doc):
+    return span_doc["startMs"] + span_doc["durationMs"]
+
+
+class TestDispatchSpans:
+    #: /traces.json rounds to the microsecond
+    TOL_MS = 0.01
+
+    def test_traced_query_carries_the_dispatch_phases(
+            self, storage, tmp_path, monkeypatch):
+        from tests.rec_engine import (DISPATCH_PHASES, post_query,
+                                      start_rec_server, trace_of, train_rec)
+
+        train_rec(storage, tmp_path, monkeypatch)
+        server = start_rec_server(storage, tracing=True)
+        try:
+            status, body, headers = post_query(
+                server.port, {"user": "u1", "num": 3})
+            assert status == 200 and len(body["itemScores"]) == 3
+            spans = trace_of(server.port, headers["X-PIO-Trace-Id"])["spans"]
+        finally:
+            server.stop()
+        by_name = {s["name"]: s for s in spans}
+        assert len(by_name) == len(spans), "a span was recorded twice"
+        dd = by_name["batcher.device_dispatch"]
+        # the six phases: children of THIS request's dispatch span, in
+        # the order they ran, each inside the parent's interval
+        phases = [by_name[name] for name in DISPATCH_PHASES]
+        assert [s["name"] for s in spans
+                if s["name"].startswith("dispatch.")] == list(DISPATCH_PHASES)
+        for before, after in zip(phases, phases[1:]):
+            assert _end(before) <= after["startMs"] + self.TOL_MS
+        for s in phases:
+            assert s["parentId"] == dd["spanId"]
+            assert s["startMs"] >= dd["startMs"] - self.TOL_MS
+            assert _end(s) <= _end(dd) + self.TOL_MS
+        # batcher.hold: the tail of the queue wait, ending where it ends
+        qw, hold = by_name["batcher.queue_wait"], by_name["batcher.hold"]
+        assert hold["parentId"] == qw["spanId"]
+        assert hold["startMs"] >= qw["startMs"] - self.TOL_MS
+        assert _end(hold) == pytest.approx(_end(qw), abs=self.TOL_MS)
+        assert _end(qw) == pytest.approx(dd["startMs"], abs=self.TOL_MS)
+        # batcher.wake: from the dispatch's end to the handler running
+        wake = by_name["batcher.wake"]
+        assert wake["startMs"] == pytest.approx(_end(dd), abs=self.TOL_MS)
+        assert _end(wake) <= by_name["encode"]["startMs"] + self.TOL_MS
+        # respond: after encode, the last thing a traced query records
+        assert by_name["respond"]["startMs"] >= _end(by_name["encode"]) \
+            - self.TOL_MS
+        assert spans[-1]["name"] == "respond"
+
+    def test_tracing_off_binds_nothing_and_adds_no_device_sync(
+            self, storage, tmp_path, monkeypatch):
+        """With ``ServerConfig.tracing`` false the dispatcher allocates
+        no Trace, ``batch_predict`` makes no ``block_until_ready`` call
+        (the path is the untraced one, to the sync), and /traces.json
+        stays empty."""
+        import jax
+
+        from predictionio_tpu.serving import batcher as batcher_mod
+        from tests.rec_engine import post_query, start_rec_server, train_rec
+
+        train_rec(storage, tmp_path, monkeypatch)
+        calls = []
+        real_block = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: (calls.append("block_until_ready"), real_block(x))[1])
+        monkeypatch.setattr(
+            batcher_mod, "Trace",
+            lambda *a, **k: calls.append("Trace") or Trace(*a, **k))
+        server = start_rec_server(storage, tracing=False)
+        try:
+            for u in ("u1", "u2", "nobody"):
+                status, _, headers = post_query(
+                    server.port, {"user": u, "num": 3})
+                assert status == 200
+                assert "X-PIO-Trace-Id" not in headers
+            assert server.service.serving_stats.count("dispatches") >= 1
+            _, raw, _ = _http(server.port, "GET", "/traces.json")
+            assert json.loads(raw) == {"tracing": False, "traces": []}
+        finally:
+            server.stop()
+        assert calls == []
+        # the spies do see the traced path (the test would otherwise
+        # pass on a renamed call)
+        server = start_rec_server(storage, tracing=True)
+        try:
+            assert post_query(server.port, {"user": "u1", "num": 3})[0] == 200
+        finally:
+            server.stop()
+        assert sorted(set(calls)) == ["Trace", "block_until_ready"]
 
 
 # ---------------------------------------------------------------------------

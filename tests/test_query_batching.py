@@ -360,3 +360,60 @@ class TestResultCacheHTTP:
         assert body["value"] == 30                      # NOT the stale 6
         stats = _get(server.port, "/stats.json")
         assert stats["serving"]["cacheInvalidations"] == 1
+
+
+class TestCoalescedDispatchSpans:
+    def test_each_coalesced_query_gets_its_own_copy_of_the_phases(
+            self, storage, tmp_path, monkeypatch):
+        """Two traced queries in ONE dispatch: the dispatcher records
+        the phases once, on its own per-dispatch trace, and copies them
+        onto each request's trace under that request's own
+        batcher.device_dispatch span (per-query weighting, the
+        convention batcher.queue_wait already follows)."""
+        from tests.rec_engine import (DISPATCH_PHASES, post_query,
+                                      start_rec_server, trace_of, train_rec)
+
+        train_rec(storage, tmp_path, monkeypatch)
+        # a fixed 300 ms door: a barrier-fired pair always coalesces
+        server = start_rec_server(storage, tracing=True, batch_policy="fixed",
+                                  batch_max=8, batch_wait_ms=300.0)
+        try:
+            port = server.port
+            assert _post(port, {"user": "u3", "num": 2})[0] == 200  # compile
+            before = server.service.serving_stats.count("dispatches")
+            users = ["u1", "u2"]
+            trace_ids: dict[str, str] = {}
+            barrier = threading.Barrier(len(users))
+
+            def go(user):
+                barrier.wait()
+                status, _, headers = post_query(port,
+                                                {"user": user, "num": 2})
+                assert status == 200
+                trace_ids[user] = headers["X-PIO-Trace-Id"]
+
+            threads = [threading.Thread(target=go, args=(u,)) for u in users]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert server.service.serving_stats.count("dispatches") \
+                == before + 1
+            traces = [trace_of(port, trace_ids[u]) for u in users]
+        finally:
+            server.stop()
+        absolute = []
+        for trace in traces:
+            spans = {s["name"]: s for s in trace["spans"]}
+            dd = spans["batcher.device_dispatch"]
+            for name in DISPATCH_PHASES:
+                assert spans[name]["parentId"] == dd["spanId"]
+            for name in ("batcher.hold", "batcher.wake", "respond"):
+                assert name in spans
+            absolute.append(
+                [trace["startTime"] * 1e3 + spans[n]["startMs"]
+                 for n in DISPATCH_PHASES])
+        # own copies (distinct span ids) of the SAME intervals
+        ids = [{s["spanId"] for s in t["spans"]} for t in traces]
+        assert not ids[0] & ids[1]
+        assert absolute[0] == pytest.approx(absolute[1], abs=1.0)
