@@ -201,6 +201,14 @@ def _serve_similar(item_f, packed, allow, k):
         [jax.lax.bitcast_convert_type(vals[0], jnp.int32), idxs[0]])
 
 
+@instrumented_jit
+def _take_rows(table, ixs):
+    """``table[ixs]`` as one device program: eager indexing launches
+    seven (index wrap, broadcasts, the gather), 3-4 ms of host work a
+    dispatch on the chip (PERF.md, PR 23)."""
+    return table[ixs]
+
+
 @dataclasses.dataclass
 class ALSModel:
     """Factors + id maps + seen lists; device-resident while serving."""
@@ -585,24 +593,30 @@ class ALSModel:
     def batch_topk(self, uixs: np.ndarray, seen_cols, seen_mask, allow,
                    k: int) -> tuple:
         """Batched masked top-k over dense user indices — the
-        batch_predict hot path shared by the templates. Dispatches to
+        recommendation template's batch_predict hot path. Dispatches to
         the configured retrieval: brute routes through the
-        flat/chunked-scan dispatcher (ops/topk.recommend_topk_fused),
-        ann through the IVF probe + exact-rescore kernel (ops/ann) —
-        one jitted dispatch either way. ``allow=None`` uses the
+        flat/chunked-scan dispatcher (ops/topk.recommend_topk_fused_rows),
+        whose program gathers the users' rows itself from the table and
+        the index array — ONE device launch a dispatch; ann (the IVF
+        probe + exact-rescore kernel, ops/ann) and the deployed-sharded
+        merge take vectors, which one small jitted gather
+        (:func:`_take_rows`) hands them. ``allow=None`` uses the
         device-cached all-ones vector."""
         # dispatch.gather / dispatch.enqueue: ambient spans on the
         # batcher's per-dispatch trace (no-ops with tracing off). Both
         # time the HOST side only — upload + launch return before the
-        # device finishes; batch_predict's dispatch.device_wait awaits it
+        # device finishes; batch_predict's dispatch.device_wait awaits it.
+        # gather holds a launch on the ann / sharded branches only
         with span("dispatch.gather"):
-            # an eager gather: a device launch of its own before the
-            # top-k program
-            uv = self.user_factors[jnp.asarray(np.asarray(uixs,
-                                                          dtype=np.int32))]
+            uixs = np.asarray(uixs, dtype=np.int32)
             allow_v = self._allow_or_default(allow)
+            ann = self._ann_active()
+            mesh = None if ann else self._serving_mesh()
+            sharded = mesh is not None and allow_v.ndim == 1
+            if ann or sharded:
+                uv = _take_rows(self.user_factors, uixs)
         with span("dispatch.enqueue"):
-            if self._ann_active():
+            if ann:
                 centroids, flat_items, flat_vecs, cell_offset, nprobe, \
                     rescore = self._ann_args()
                 vals, idxs = ann_ops.ann_topk(
@@ -613,8 +627,7 @@ class ALSModel:
                     self.ann_index.shortlist_width(nprobe, rescore),
                     int(uv.shape[0]))
                 return vals, idxs
-            mesh = self._serving_mesh()
-            if mesh is not None and allow_v.ndim == 1:
+            if sharded:
                 # deployed-sharded dispatch (docs/parallelism.md): local
                 # top-k per model shard, candidate all-gather, global merge
                 return topk_ops.recommend_topk_sharded(
@@ -622,10 +635,11 @@ class ALSModel:
                     jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
                     jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
                     allow_v, k, mesh)
-            return topk_ops.recommend_topk_fused(
-                uv, self.item_factors,
+            return topk_ops.recommend_topk_fused_rows(
+                self.user_factors, uixs, self.item_factors,
                 # NumPy stays NumPy on purpose: the dispatcher's host-side
-                # _trim_seen can only right-size concrete host arrays
+                # _trim_seen can only right-size concrete host arrays, and
+                # jit uploads them, with the indices, in the one launch
                 seen_cols, seen_mask, allow_v, k)
 
     def predict_rating(self, user_id: str, item_id: str) -> float | None:

@@ -61,6 +61,23 @@ def recommend_topk(
     return jax.lax.top_k(scores, min(k, scores.shape[-1]))
 
 
+@partial(instrumented_jit, static_argnames=("k",))
+def recommend_topk_rows(
+    user_table: jax.Array,   # (U, K) the whole user factor table
+    uixs: jax.Array,         # (B,) int32 rows of it, one per query
+    item_f: jax.Array,
+    seen_cols: jax.Array,
+    seen_mask: jax.Array,
+    allow: jax.Array,
+    k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`recommend_topk` over ``user_table[uixs]``, the rows
+    gathered inside the program: one device launch per dispatch, where
+    an eager gather ahead of it costs several (PERF.md, PR 25)."""
+    return recommend_topk(user_table[uixs], item_f, seen_cols, seen_mask,
+                          allow, k)
+
+
 @partial(instrumented_jit, static_argnames=("k", "chunk"))
 def recommend_topk_chunked(
     user_vecs: jax.Array,    # (B, K)
@@ -137,6 +154,23 @@ def recommend_topk_chunked(
     )
     (v, i), _ = jax.lax.scan(body, init, (starts, valid_from))
     return v, i
+
+
+@partial(instrumented_jit, static_argnames=("k", "chunk"))
+def recommend_topk_chunked_rows(
+    user_table: jax.Array,   # (U, K)
+    uixs: jax.Array,         # (B,) int32
+    item_f: jax.Array,
+    seen_cols: jax.Array,
+    seen_mask: jax.Array,
+    allow: jax.Array,
+    k: int,
+    chunk: int = 1 << 18,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`recommend_topk_chunked` over ``user_table[uixs]``, the
+    rows gathered inside the program."""
+    return recommend_topk_chunked(user_table[uixs], item_f, seen_cols,
+                                  seen_mask, allow, k, chunk)
 
 
 #: static seen-array widths shared by batch_predict's menu — a small
@@ -221,6 +255,13 @@ def _trim_seen(seen_cols, seen_mask):
     return seen_cols, seen_mask
 
 
+def _chunked_wins(allow, item_f, batch: int) -> bool:
+    """Inside the envelope where the chunked scan beats the flat path
+    (1-D ``allow`` only: the chunked path takes no per-query rules)."""
+    return allow.ndim == 1 and item_f.shape[0] >= _MIN_ITEMS \
+        and batch >= _MIN_BATCH
+
+
 def recommend_topk_fused(
     user_vecs: jax.Array,    # (B, K)
     item_f: jax.Array,       # (I, K)
@@ -233,19 +274,43 @@ def recommend_topk_fused(
     formulations — flat materialize+top_k (:func:`recommend_topk`, best
     for small catalogs and B=1 serving) and the chunked-scan merge
     (:func:`recommend_topk_chunked`, O(B x chunk) memory, faster from
-    ~1M items with batched queries).
+    ~1M items with batched queries). Takes the query vectors;
+    :func:`recommend_topk_fused_rows` takes the user table and row
+    indices and makes the same choice.
 
     A pallas streaming-select kernel used to sit behind this dispatch;
     it was deleted after re-measurement with the forcing protocol
     (bench.py header): 168ms vs the flat path's 8ms at B=32 x I=1M and
     188ms vs the chunked path's 73ms at B=256 x I=2M — its per-tile VPU
     selection loop loses to ``lax.top_k`` at every envelope point."""
-    if allow.ndim == 1 and item_f.shape[0] >= _MIN_ITEMS \
-            and user_vecs.shape[0] >= _MIN_BATCH:
+    if _chunked_wins(allow, item_f, user_vecs.shape[0]):
         seen_cols, seen_mask = _trim_seen(seen_cols, seen_mask)
         return recommend_topk_chunked(
             user_vecs, item_f, seen_cols, seen_mask, allow, k)
     return recommend_topk(user_vecs, item_f, seen_cols, seen_mask, allow, k)
+
+
+def recommend_topk_fused_rows(
+    user_table: jax.Array,   # (U, K)
+    uixs,                    # (B,) int32 rows of it; NumPy is fine
+    item_f: jax.Array,       # (I, K)
+    seen_cols,               # (B, S) int32, padded
+    seen_mask,               # (B, S) 1=real, 0=pad
+    allow: jax.Array,        # (I,) or (B, I)
+    k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`recommend_topk_fused` for callers that hold row indices,
+    not vectors: the same choice between flat and chunked, and the
+    chosen program gathers ``user_table[uixs]`` itself, so a dispatch
+    is ONE device launch (``recommend_topk_rows`` or
+    ``recommend_topk_chunked_rows``). ``jit`` uploads host index and
+    seen arrays with the call; ``_trim_seen`` stays on the host."""
+    if _chunked_wins(allow, item_f, uixs.shape[0]):
+        seen_cols, seen_mask = _trim_seen(seen_cols, seen_mask)
+        return recommend_topk_chunked_rows(
+            user_table, uixs, item_f, seen_cols, seen_mask, allow, k)
+    return recommend_topk_rows(
+        user_table, uixs, item_f, seen_cols, seen_mask, allow, k)
 
 
 def recommend_topk_sharded(

@@ -385,6 +385,29 @@ class TestModelIntegration:
         assert calls == [(m.ann_index.shortlist_width(
             m.ann_index.clamp_nprobe(0)), 4)]
 
+    @pytest.mark.parametrize("B", [1, 4, 16])
+    def test_batch_topk_ann_equals_vectors_through_ann_topk(self, B):
+        """The ANN branch gets its query vectors from one jitted gather
+        (``models/als._take_rows``): the same answer as the kernel on
+        ``user_factors[uixs]``."""
+        m = _als_model(seed=26)
+        m.configure_retrieval("ann")
+        rng = np.random.default_rng(B)
+        uixs = rng.integers(0, 32, B).astype(np.int32)
+        cols = rng.integers(0, 2048, (B, 8)).astype(np.int32)
+        mask = (rng.random((B, 8)) < 0.5).astype(np.float32)
+        got = m.batch_topk(uixs, cols, mask, None, 10)
+        centroids, flat_items, flat_vecs, cell_offset, nprobe, rescore = \
+            m._ann_args()
+        want = ann_ops.ann_topk(
+            m.user_factors[uixs], m.item_factors, centroids, flat_items,
+            flat_vecs, cell_offset, jnp.asarray(cols), jnp.asarray(mask),
+            m._allow_or_default(None), 10, nprobe, rescore)
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+
     def test_small_catalog_degrades_to_brute(self, caplog):
         m = _als_model(n_items=128)
         m.configure_retrieval("ann")

@@ -249,7 +249,7 @@ class TestServingRecompilePin:
         # ... and the family is live on a rendered registry scrape
         text = render_metrics(list(compile_metrics_collector()()))
         assert "pio_serving_recompile_total 1" in text
-        assert 'fn="recommend_topk"' in text
+        assert 'fn="recommend_topk_rows"' in text
 
         # the NON-batched single-query path is instrumented too: one
         # predict routes through models/als._serve_recommend (the
@@ -262,6 +262,62 @@ class TestServingRecompilePin:
         assert "_serve_recommend" in rec.compiles_by_fn(), \
             rec.compiles_by_fn()
         rec.reset()
+
+
+class TestOneLaunchPerDispatch:
+    def test_new_batch_width_compiles_exactly_the_topk_program(
+            self, storage, tmp_path, monkeypatch):
+        """A batched dispatch is ONE device program: through
+        ``DeployedEngine.query_batch`` the first dispatch at a batch
+        width not yet seen causes exactly one backend compile, the
+        row-taking ``recommend_topk*`` program, and a second at that
+        width none. The recorder sees only ``instrumented_jit``
+        functions, so the count is of jax's own backend-compile event:
+        an eager user-row gather ahead of the program shows there as
+        further compiles (``gather``, ``less``, ``select_n``, ...) at
+        each new shape, and fails this."""
+        import jax.monitoring
+        from jax._src import monitoring as jax_monitoring
+        from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+        from predictionio_tpu.templates.recommendation import Query
+        from predictionio_tpu.workflow.deploy import load_deployed_engine
+        from tests.rec_engine import train_rec
+
+        # table shapes no other test of this process serves: the jit
+        # caches are the process's
+        train_rec(storage, tmp_path, monkeypatch, n_users=31, n_items=23,
+                  app_name="OneLaunchApp")
+        deployed = load_deployed_engine(storage=storage)
+        backend_compiles = []
+
+        def on_duration(name, seconds, **kwargs):
+            if name == BACKEND_COMPILE_EVENT:
+                backend_compiles.append(seconds)
+
+        def dispatch(n):
+            del backend_compiles[:]
+            answers = deployed.query_batch(
+                [Query(user=f"u{j}", num=4) for j in range(n)])
+            assert len(answers) == n
+            assert all(a.item_scores for a in answers)
+            return len(backend_compiles)
+
+        rec = recorder()
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            # width 1: whatever is built once a model (the cached allow
+            # vector) is built here
+            dispatch(1)
+            rec.reset()
+            assert dispatch(3) == 1, rec.recompile_table()      # padB 4
+            assert rec.compiles_by_fn() == {"recommend_topk_rows": 1}
+            assert dispatch(4) == 0
+            assert dispatch(3) == 0
+            assert rec.compiles_by_fn() == {"recommend_topk_rows": 1}
+        finally:
+            jax_monitoring.unregister_event_duration_listener(on_duration)
+            rec.reset()
 
 
 class TestBatchedRecompileSpan:

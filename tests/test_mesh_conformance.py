@@ -205,6 +205,46 @@ class TestServingDispatch:
         np.testing.assert_allclose(np.asarray(va), np.asarray(vb),
                                    atol=1e-5)
 
+    @pytest.mark.parametrize("user_table", ["replicated", "sharded"])
+    @pytest.mark.parametrize("B", [1, 3, 8])
+    def test_batch_topk_sharded_equals_vectors_through_the_merge(
+            self, B, user_table):
+        """The deployed-sharded branch gets its query vectors from one
+        jitted gather (``models/als._take_rows``), whether the user
+        table is row-sharded too or not: the same answer as the
+        distributed merge on ``user_factors[uixs]``."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from predictionio_tpu.models.als import ALSModel
+        from predictionio_tpu.utils.bimap import EntityIdIxMap
+
+        mesh = _mesh((1, 8))
+        rng = np.random.default_rng(50 + B)
+        U, I, K = 40, 64, 8
+        rows = NamedSharding(mesh, P("model", None))
+        users = jnp.asarray(rng.standard_normal((U, K)).astype(np.float32))
+        model = ALSModel(
+            rank=K,
+            user_factors=(jax.device_put(users, rows)
+                          if user_table == "sharded" else users),
+            item_factors=jax.device_put(
+                rng.standard_normal((I, K)).astype(np.float32), rows),
+            user_ids=EntityIdIxMap.from_ids([f"u{i}" for i in range(U)]),
+            item_ids=EntityIdIxMap.from_ids([f"i{i}" for i in range(I)]),
+            seen_by_user={})
+        assert model.factor_shard_ways == 8
+        uixs = rng.integers(0, U, B).astype(np.int32)
+        cols = rng.integers(0, I, (B, 8)).astype(np.int32)
+        mask = (rng.random((B, 8)) < 0.5).astype(np.float32)
+        got = model.batch_topk(uixs, cols, mask, None, 12)
+        want = recommend_topk_sharded(
+            users[uixs], model.item_factors, jnp.asarray(cols),
+            jnp.asarray(mask), jnp.ones((I,), jnp.float32), 12, mesh)
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+
     def test_env_resolution(self, monkeypatch):
         """PIO_TRAIN_SHARD_FACTORS: 1 forces on, 0 forces off, unset
         defers to the engine param — resolve_shard_factors is the one

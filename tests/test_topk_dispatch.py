@@ -18,7 +18,10 @@ from predictionio_tpu.ops.topk import (
     _trim_seen,
     recommend_topk,
     recommend_topk_chunked,
+    recommend_topk_chunked_rows,
     recommend_topk_fused,
+    recommend_topk_fused_rows,
+    recommend_topk_rows,
 )
 
 
@@ -52,6 +55,103 @@ def test_chunked_matches_flat_on_finite_slots():
     np.testing.assert_allclose(cv[finite], fv[finite], rtol=1e-6)
     # sentinel slots never collide with real item indices
     assert (ci[~np.isfinite(cv)] >= 5000).all()
+
+
+def _rows_setup(B, padded, seed=0):
+    """A user table, B int32 rows of it and the other arguments as
+    ``batch_predict`` hands them over: host arrays, a seen width from
+    the menu; ``padded`` repeats row 0 in the batch's second half, as
+    ``_pad_batch`` fills a batch up to its menu width."""
+    U, I, K, S = 300, 5000, 8, _SEEN_WIDTHS[1]
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(rng.standard_normal((U, K)).astype(np.float32))
+    uixs = rng.integers(0, U, B).astype(np.int32)
+    cols = rng.integers(0, I, (B, S)).astype(np.int32)
+    mask = (rng.random((B, S)) < 0.5).astype(np.float32)
+    if padded:
+        uixs[B // 2:] = uixs[0]
+        cols[B // 2:] = 0
+        mask[B // 2:] = 0.0
+    itf = jnp.asarray(rng.standard_normal((I, K)).astype(np.float32))
+    allow = jnp.asarray((rng.random(I) < 0.9).astype(np.float32))
+    return table, uixs, itf, cols, mask, allow
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["full", "padded"])
+@pytest.mark.parametrize("B", [1, 4, 16, 32])
+@pytest.mark.parametrize("form", ["flat", "chunked"])
+def test_row_taking_program_equals_vector_program(form, B, padded):
+    """``recommend_topk*_rows(table, uixs, ...)`` is
+    ``recommend_topk*(table[uixs], ...)``: the same indices, and on the
+    CPU the same values bit for bit."""
+    table, uixs, itf, cols, mask, allow = _rows_setup(B, padded, seed=B)
+    if form == "flat":
+        want = recommend_topk(table[uixs], itf, cols, mask, allow, 10)
+        got = recommend_topk_rows(table, uixs, itf, cols, mask, allow, 10)
+    else:
+        want = recommend_topk_chunked(table[uixs], itf, cols, mask, allow,
+                                      10, chunk=1024)
+        got = recommend_topk_chunked_rows(table, uixs, itf, cols, mask,
+                                          allow, 10, chunk=1024)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
+@pytest.mark.parametrize("B,form", [(4, "flat"), (32, "chunked")])
+def test_fused_rows_makes_the_fused_choice(monkeypatch, B, form):
+    """The row-taking dispatcher picks flat or chunked from what
+    ``recommend_topk_fused`` looks at, trims the seen pad on the host
+    for the chunked program as it does, and answers the same."""
+    import predictionio_tpu.ops.topk as t
+
+    monkeypatch.setattr(t, "_MIN_ITEMS", 100)
+    table, uixs, itf, cols, mask, allow = _rows_setup(B, False, seed=9)
+    wide_cols = np.zeros((B, 40), np.int32)
+    wide_mask = np.zeros((B, 40), np.float32)
+    wide_cols[:, :32], wide_mask[:, :32] = cols, mask
+    calls = []
+    for name in ("recommend_topk_rows", "recommend_topk_chunked_rows"):
+        fn = getattr(t, name)
+        monkeypatch.setattr(
+            t, name, lambda *a, _fn=fn, _name=name:
+            calls.append((_name, a[4].shape)) or _fn(*a))
+    got = t.recommend_topk_fused_rows(table, uixs, itf, wide_cols,
+                                      wide_mask, allow, 10)
+    want = recommend_topk_fused(table[uixs], itf, wide_cols, wide_mask,
+                                allow, 10)
+    assert calls == [("recommend_topk_rows", (B, 40)) if form == "flat"
+                     else ("recommend_topk_chunked_rows", (B, 32))]
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    # per-query business rules (2-D allow) stay on the flat program
+    calls.clear()
+    t.recommend_topk_fused_rows(table, uixs, itf, cols, mask,
+                                jnp.ones((B, itf.shape[0]), jnp.float32), 10)
+    assert [c[0] for c in calls] == ["recommend_topk_rows"]
+
+
+@pytest.mark.parametrize("B", [1, 4, 16])
+def test_batch_topk_brute_equals_vectors_through_fused(B):
+    """``ALSModel.batch_topk`` on the brute branch hands the table and
+    the indices to the row-taking dispatcher: what it answers is what
+    the eager gather + ``recommend_topk_fused`` answered."""
+    from predictionio_tpu.models.als import ALSModel
+    from predictionio_tpu.utils.bimap import EntityIdIxMap
+
+    table, uixs, itf, cols, mask, _ = _rows_setup(B, B > 1, seed=40 + B)
+    model = ALSModel(
+        rank=int(table.shape[1]), user_factors=table, item_factors=itf,
+        user_ids=EntityIdIxMap.from_ids(
+            [f"u{i}" for i in range(table.shape[0])]),
+        item_ids=EntityIdIxMap.from_ids(
+            [f"i{i}" for i in range(itf.shape[0])]),
+        seen_by_user={})
+    got = model.batch_topk(uixs, cols, mask, None, 10)
+    want = recommend_topk_fused(
+        table[uixs], itf, cols, mask,
+        jnp.ones((itf.shape[0],), jnp.float32), 10)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
 
 
 def test_trim_seen_picks_menu_width():
