@@ -570,6 +570,12 @@ class EngineService:
                 getattr(self.deployed, "models", ())):
             if hasattr(target, "set_ann_observer"):
                 target.set_ann_observer(self.serving_stats.record_ann)
+        # the session engine's models report programs and tokens the
+        # same way (pio_serving_seq_* on /metrics, seq* on /stats.json)
+        for model in getattr(self.deployed, "models", ()):
+            if hasattr(model, "set_dispatch_observer"):
+                model.set_dispatch_observer(
+                    self.serving_stats.record_seq_dispatch)
 
     def _missing_index_targets(self) -> list:
         """ANN-capable deployed models WITHOUT a ready index — the
@@ -1293,8 +1299,13 @@ class _Handler(BaseHTTPRequestHandler):
     # ...and a read timeout, or every idle persistent connection pins
     # its handler thread (and fd) for the life of the process —
     # handle_one_request treats the timeout as close_connection, so an
-    # idle client is simply hung up on and reconnects transparently
-    timeout = 30
+    # idle client is simply hung up on and reconnects transparently.
+    # 75 s (nginx's keepalive_timeout): at 30 a pooled client of the
+    # session engine (half a second a query, under one query a second
+    # over 16 connections) found the connection it picked hung up on a
+    # fifth of the time, and a client that does not retry counts that
+    # as a failed request (PERF.md section 6, PR 27)
+    timeout = 75
 
     # buffer the response: the stdlib default (wbufsize=0) issues one
     # write() syscall PER HEADER LINE, and with Nagle enabled those

@@ -50,6 +50,9 @@ class ServingStats:
         "cache_expirations", "cache_invalidations",
         "cache_user_invalidations",
         "ann_queries", "ann_rescored",
+        # the session engine's dispatches (templates/sessionrec)
+        "seq_programs", "seq_tokens", "seq_padded_tokens",
+        "seq_split_dispatches",
     )
 
     def __init__(self):
@@ -98,6 +101,19 @@ class ServingStats:
             self._counts["ann_queries"] += queries
             self._counts["ann_rescored"] += shortlist_width * queries
             self._ann_hist[shortlist_width] += queries
+
+    def record_seq_dispatch(self, programs: int, tokens: int,
+                            padded_tokens: int, split: int) -> None:
+        """One ``batch_predict`` of the session engine: the device
+        programs it launched, the events of the histories it scored,
+        the tokens the programs ran over (each history padded to
+        ``max_len``), and whether the token budget split it into more
+        than one program (the SeqRecEngineModel observer hook)."""
+        with self._lock:
+            self._counts["seq_programs"] += programs
+            self._counts["seq_tokens"] += tokens
+            self._counts["seq_padded_tokens"] += padded_tokens
+            self._counts["seq_split_dispatches"] += split
 
     def ann_histogram(self) -> dict[int, int]:
         """Shortlist width -> query count, read under the lock."""

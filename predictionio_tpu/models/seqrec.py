@@ -5,6 +5,13 @@ of the reference's e2 MarkovChain (e2/.../engine/MarkovChain.scala:26-84,
 top-N transition model): where MarkovChain keeps first-order transition
 counts, this trains a causal transformer over full session histories.
 
+The stack is built from a configuration: ``SeqRecConfig.block`` names a
+kind in ``BLOCKS``. "sasrec" is the pre-LN multi-head block described
+below; "brumby" is the Brumby-14B-Base block (RMSNorm, grouped
+key/value heads with QK-norm and RoPE, gated power retention from
+ops/retention.py, SwiGLU, an untied head), whose plain float32
+reference is benchmarks/reference/brumby_jnp.py.
+
 TPU-first design:
 - matmuls run in bf16 on the MXU (params and softmax/LN statistics stay
   f32); logits against the tied item-embedding table accumulate f32.
@@ -34,6 +41,7 @@ from predictionio_tpu.ops.attention import (
     full_attention,
     ring_attention,
 )
+from predictionio_tpu.ops.retention import power_retention
 
 logger = logging.getLogger(__name__)
 
@@ -55,10 +63,65 @@ class SeqRecConfig:
     #: trading ~30% FLOPs for O(layers) less HBM — the long-context
     #: training knob alongside the "seq" mesh axis
     remat: bool = False
+    #: the kind of block the stack is built from (``BLOCKS``): "sasrec"
+    #: is the pre-LN multi-head block with a learned position table and
+    #: a tied head; "brumby" is the Brumby-14B-Base block (RMSNorm,
+    #: grouped key/value heads, QK-norm, RoPE, gated power retention,
+    #: SwiGLU). The fields below are the second kind's widths; 0 means
+    #: "as the first kind derives it"
+    block: str = "sasrec"
+    n_kv_heads: int = 0         # 0: one key/value head per query head
+    head_dim: int = 0           # 0: d_model // n_heads
+    d_ff: int = 0               # 0: mlp_mult * d_model
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    retention_degree: int = 2
+    #: added inside the gate's log-sigmoid: ln 999, so that a zero
+    #: projection gives a gate of 0.999 and the state remembers
+    gate_init_logit: float = 6.906768
+    tie_embeddings: bool = True
+    #: the type serving holds the weights in on the device
+    #: (templates/sessionrec._as_device_tree); training keeps float32
+    param_dtype: Any = jnp.float32
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def hd(self) -> int:
+        return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def ff(self) -> int:
+        return self.d_ff or self.mlp_mult * self.d_model
 
 
-def init_params(key: jax.Array, cfg: SeqRecConfig) -> dict:
-    """f32 parameter pytree; compute casts to cfg.dtype per-op."""
+def activation_bytes_per_token(cfg: SeqRecConfig) -> int:
+    """What one token of a serving program holds at the program's peak,
+    estimated from the widths: the residual stream twice and the widest
+    layer's two projections (SwiGLU's gate and up; twice the MLP's
+    hidden for SASRec), in cfg.dtype, and for SASRec one float32 row of
+    attention logits per head. The compiled brumby program at the
+    published widths holds 91 KB a token; this says 90."""
+    per = 2 * (cfg.d_model + cfg.ff) * jnp.dtype(cfg.dtype).itemsize
+    if cfg.block == "sasrec":
+        per += 4 * cfg.n_heads * cfg.max_len
+    return per
+
+
+def init_params(key: jax.Array, cfg: SeqRecConfig,
+                dtype: Any = jnp.float32) -> dict:
+    """Parameter pytree of ``cfg.block``'s kind in ``dtype`` (float32
+    for training; compute casts to cfg.dtype per-op). Jittable: a
+    serving-sized tree is drawn on the device."""
+    if cfg.block not in BLOCKS:
+        raise ValueError(f"unknown block kind {cfg.block!r} "
+                         f"(have {sorted(BLOCKS)})")
+    return BLOCKS[cfg.block].init(key, cfg, dtype)
+
+
+def _init_sasrec(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
     keys = jax.random.split(key, 3 + cfg.n_layers)
     d, h = cfg.d_model, cfg.mlp_mult * cfg.d_model
     scale = 1.0 / math.sqrt(d)
@@ -86,6 +149,44 @@ def init_params(key: jax.Array, cfg: SeqRecConfig) -> dict:
             "w2": dense(lk[3], h, d),
             "b2": jnp.zeros((d,)),
         })
+    return jax.tree.map(lambda a: a.astype(dtype), params)
+
+
+def _init_brumby(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
+    """Every matrix normal / sqrt(fan-in), norm weights one, the
+    embedding at SASRec's scale; drawn in float32 and rounded once."""
+    d, ff, hd = cfg.d_model, cfg.ff, cfg.hd
+    H, G = cfg.n_heads, cfg.kv_heads
+    keys = jax.random.split(key, 2 + cfg.n_layers)
+
+    def dense(k, m, n):
+        return (jax.random.normal(k, (m, n), dtype=jnp.float32)
+                / math.sqrt(m)).astype(dtype)
+
+    def table(k):
+        return (jax.random.normal(k, (cfg.vocab, d), dtype=jnp.float32)
+                / math.sqrt(d)).astype(dtype)
+
+    params = {"item_emb": table(keys[0]),
+              "out_norm": jnp.ones((d,), dtype), "layers": []}
+    if not cfg.tie_embeddings:
+        params["head"] = table(keys[1])
+    for i in range(cfg.n_layers):
+        lk = jax.random.split(keys[2 + i], 8)
+        params["layers"].append({
+            "in_norm": jnp.ones((d,), dtype),
+            "post_norm": jnp.ones((d,), dtype),
+            "q_norm": jnp.ones((hd,), dtype),
+            "k_norm": jnp.ones((hd,), dtype),
+            "wq": dense(lk[0], d, H * hd),
+            "wk": dense(lk[1], d, G * hd),
+            "wv": dense(lk[2], d, G * hd),
+            "wg": dense(lk[3], d, G),
+            "wo": dense(lk[4], H * hd, d),
+            "w_gate": dense(lk[5], d, ff),
+            "w_up": dense(lk[6], d, ff),
+            "w_down": dense(lk[7], ff, d),
+        })
     return params
 
 
@@ -104,8 +205,14 @@ def forward(
     seq_axis: str = "seq",
     inference: bool = False,
 ) -> jax.Array:
-    """Hidden states (B, S, D) in cfg.dtype. When ``mesh`` has a
-    ``seq_axis``, attention runs as ring attention over it.
+    """Hidden states (B, S, D) in cfg.dtype from ``cfg.block``'s stack."""
+    return BLOCKS[cfg.block].forward(params, seqs, cfg, mesh, seq_axis,
+                                     inference)
+
+
+def _forward_sasrec(params, seqs, cfg, mesh, seq_axis, inference):
+    """When ``mesh`` has a ``seq_axis``, attention runs as ring
+    attention over it.
 
     ``inference=True`` routes single-device attention through
     ops/pallas_attention.flash_attention, which since the round-5
@@ -170,10 +277,88 @@ def forward(
     return _ln(x, params["out_ln"]["g"], params["out_ln"]["b"])
 
 
+def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate (B, S, heads, d) by position 0..S-1: pairs (i, i + d/2),
+    frequency theta**(-2i/d), over all d dimensions; float32."""
+    S, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
+    """The Brumby-14B-Base stack. Positions come from RoPE and the mixing
+    is causal, so right padding changes nothing before it and needs no
+    mask. One program on one device (or batch-sharded by the caller's
+    jit): retention has no sequence-parallel form here yet."""
+    if mesh is not None and seq_axis in mesh.shape and \
+            int(mesh.shape[seq_axis]) > 1:
+        raise NotImplementedError(
+            "the brumby block has no sequence-parallel form: use a mesh "
+            f"without a {seq_axis!r} axis")
+    B, S = seqs.shape
+    H, G, hd, dt = cfg.n_heads, cfg.kv_heads, cfg.hd, cfg.dtype
+    f32 = jnp.float32
+
+    def block(x, layer):
+        h = _rms(x, layer["in_norm"], cfg.rms_eps)
+        q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
+        k = (h @ layer["wk"].astype(dt)).reshape(B, S, G, hd)
+        v = (h @ layer["wv"].astype(dt)).reshape(B, S, G, hd)
+        q = _rope(_rms(q, layer["q_norm"], cfg.rms_eps), cfg.rope_theta)
+        k = _rope(_rms(k, layer["k_norm"], cfg.rms_eps), cfg.rope_theta)
+        log_g = jax.nn.log_sigmoid(
+            jnp.einsum("bsd,dg->bsg", h, layer["wg"].astype(dt),
+                       preferred_element_type=f32) + cfg.gate_init_logit)
+        y = power_retention(q.astype(dt), k.astype(dt), v, log_g,
+                            degree=cfg.retention_degree)
+        x = x + y.reshape(B, S, H * hd) @ layer["wo"].astype(dt)
+        with jax.named_scope("swiglu"):
+            h = _rms(x, layer["post_norm"], cfg.rms_eps)
+            gate = (h @ layer["w_gate"].astype(dt)).astype(f32)
+            up = (h @ layer["w_up"].astype(dt)).astype(f32)
+            return x + (jax.nn.silu(gate) * up).astype(dt) \
+                @ layer["w_down"].astype(dt)
+
+    if cfg.remat:
+        block = jax.checkpoint(block)
+    x = params["item_emb"][seqs].astype(dt)
+    for layer in params["layers"]:
+        x = block(x, layer)
+    return _rms(x, params["out_norm"], cfg.rms_eps)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockKind:
+    init: Any       # (key, cfg, dtype) -> parameter pytree
+    forward: Any    # (params, seqs, cfg, mesh, seq_axis, inference) -> hidden
+
+
+#: the block kinds a configuration can name (``SeqRecConfig.block``)
+BLOCKS = {"sasrec": BlockKind(_init_sasrec, _forward_sasrec),
+          "brumby": BlockKind(_init_brumby, _forward_brumby)}
+
+
+def head_table(params: Mapping) -> jax.Array:
+    """(V, D) output projection: the untied head where the stack has
+    one, else the item-embedding table."""
+    return params["head"] if "head" in params else params["item_emb"]
+
+
 def logits_from_hidden(params: Mapping, h: jax.Array) -> jax.Array:
-    """Tied-weight output projection, f32 accumulation: (B, S, V)."""
-    return jnp.einsum("bsd,vd->bsv", h,
-                      params["item_emb"].astype(h.dtype),
+    """Output projection (tied unless the stack has a head), f32
+    accumulation: (B, S, V)."""
+    return jnp.einsum("bsd,vd->bsv", h, head_table(params).astype(h.dtype),
                       preferred_element_type=jnp.float32)
 
 
@@ -218,7 +403,7 @@ def next_item_loss(
     seq_sharded = mesh is not None and "seq" in mesh.shape \
         and int(mesh.shape["seq"]) > 1
     tile = None if seq_sharded else _pick_loss_tile(
-        h.shape[0], h.shape[1], params["item_emb"].shape[0])
+        h.shape[0], h.shape[1], head_table(params).shape[0])
     tmask = (targets != PAD).astype(jnp.float32)
     if tile is None:
         logits = logits_from_hidden(params, h)         # (B, S, V) f32
@@ -494,7 +679,7 @@ def predict_topk_batch(
     last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
     h = forward(params, history, cfg, inference=True)
     hl = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-    logits = jnp.einsum("bd,vd->bv", hl, params["item_emb"].astype(h.dtype),
+    logits = jnp.einsum("bd,vd->bv", hl, head_table(params).astype(h.dtype),
                         preferred_element_type=jnp.float32)
     logits = logits + vocab_masks
     return jax.lax.top_k(logits, k)

@@ -1,0 +1,197 @@
+"""Gated power retention: linear-cost causal mixing with a carried state.
+
+For query head ``a`` over key/value head ``b = a // (H // G)`` and
+positions ``t >= i``::
+
+    w[t, i] = exp(sum_{j=i+1..t} log_g[j, b]) * (q[t, a] . k[i, b] / sqrt(d))**2
+    y[t, a] = sum_i w[t, i] v[i, b] / (sum_i w[t, i] + eps)
+
+The degree-2 kernel is an inner product of features:
+``(q.k)**2 = phi(q).phi(k)`` with ``phi(u)`` the products ``u_i u_j``, so
+the sum over the past folds into a state ``S = sum_i decay * phi(k_i)
+v_i^T`` of fixed size and the cost per token does not grow with the
+history. The program is chunked: inside a chunk the quadratic form (a
+``C x C`` block of weights per head), between chunks the state, carried
+by ``lax.scan`` in float32. The normaliser needs no ``phi``:
+``sum_i decay * (q.k_i)**2 = q^T (sum_i decay * k_i k_i^T) q``, a
+``d x d`` state per key/value head.
+
+Matrix products take bfloat16 operands and accumulate in float32; the
+state, the normaliser, the gates' cumulative sums and every weight are
+float32. Everything is ``jax.numpy``, so JAX's autodiff differentiates
+it. Padding after a history's last event cannot change an earlier
+position: the mixing is causal and chunks are scanned in order.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+#: ``phi`` keeps the upper block triangle of ``u u^T`` in blocks of this
+#: many coordinates (diagonal blocks whole, the others doubled): at
+#: d = 128 that is 9,216 features where the full square has 16,384 and
+#: the monomials alone 8,256
+_PHI_BLOCK = 16
+_CHUNK_MAX = 256
+
+
+def pick_chunk(seq_len: int) -> int:
+    """The chunk length for a sequence: the largest power of two up to
+    ``_CHUNK_MAX`` that does not exceed it. A chunk costs ``C`` weights
+    per token inside it and one state update per ``C`` tokens."""
+    c = 1
+    while c * 2 <= min(seq_len, _CHUNK_MAX):
+        c *= 2
+    return c
+
+
+def phi_width(d: int) -> int:
+    b = _phi_block(d)
+    return sum(b * (d - i0) for i0 in range(0, d, b))
+
+
+def _phi_block(d: int) -> int:
+    return _PHI_BLOCK if d % _PHI_BLOCK == 0 else d
+
+
+def _phi_blocks(d: int):
+    """(first coordinate, block, width to the end, offset among the
+    features) of each block of ``phi``."""
+    b, offset = _phi_block(d), 0
+    for i0 in range(0, d, b):
+        yield i0, b, d - i0, offset
+        offset += b * (d - i0)
+
+
+def _coef(width: int, block: int, off_diagonal: float, scale: float):
+    coef = np.full((width, 1), off_diagonal * scale, np.float32)
+    coef[:block] = scale
+    return coef
+
+
+def _phi_keys(k: jax.Array) -> jax.Array:
+    """(..., C, d) -> (..., phi_width(d), C) bfloat16, features before
+    positions: for each block of coordinates its products with itself
+    and with every later coordinate, taken in float32 and rounded once.
+    ``phi(q) . phi(k) == (q.k)**2`` when the query side doubles the
+    products across blocks (:func:`_read_state`)."""
+    kt = jnp.swapaxes(k.astype(jnp.float32), -1, -2)            # (..., d, C)
+    parts = [
+        (kt[..., i0:i0 + b, None, :] * kt[..., None, i0:, :])
+        .astype(jnp.bfloat16).reshape(*kt.shape[:-2], b * w, kt.shape[-1])
+        for i0, b, w, _ in _phi_blocks(kt.shape[-2])]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-2)
+
+
+def _read_state(q: jax.Array, state: jax.Array, scale: float) -> jax.Array:
+    """``scale * phi(q)^T state``: q (B, G, R, C, d), state (B, G, F, e)
+    bfloat16 -> (B, G, R, C, e) float32. One product per block of
+    ``phi``, its features formed where the product is taken: ``phi(q)``
+    as one array would cross memory once more than everything else in
+    the layer together."""
+    B, G, _, _, d = q.shape
+    qt = jnp.swapaxes(q.astype(jnp.float32), -1, -2)            # (..., d, C)
+    out = 0.0
+    for i0, b, w, offset in _phi_blocks(d):
+        feats = (qt[..., i0:i0 + b, None, :]
+                 * (qt[..., i0:, :] * _coef(w, b, 2.0, scale))[..., None, :, :])
+        out = out + jnp.einsum(
+            "bgriwt,bgiwe->bgrte", feats.astype(jnp.bfloat16),
+            state[:, :, offset:offset + b * w].reshape(B, G, b, w, -1),
+            preferred_element_type=jnp.float32)
+    return out
+
+
+def power_retention(
+    q: jax.Array,        # (B, S, H, d)
+    k: jax.Array,        # (B, S, G, d), H a multiple of G
+    v: jax.Array,        # (B, S, G, d)
+    log_g: jax.Array,    # (B, S, G) log of the gate in (0, 1]
+    *,
+    degree: int = 2,
+    chunk: int | None = None,
+    eps: float = 1e-6,
+    _state_dtype=jnp.float32,
+) -> jax.Array:
+    """The mixing above; returns (B, S, H, d) in ``q.dtype``.
+
+    ``chunk`` is chosen from the sequence length when not given (tests
+    pass small ones). ``_state_dtype`` exists for one test, which shows
+    that a state accumulated in bfloat16 is caught."""
+    if degree != 2:
+        raise NotImplementedError(
+            f"power retention of degree {degree}: only degree 2 has a "
+            "feature map here")
+    with jax.named_scope("power_retention"):
+        return _power_retention(q, k, v, log_g, chunk, eps, _state_dtype)
+
+
+def _power_retention(q, k, v, log_g, chunk, eps, state_dtype):
+    B, S, H, d = q.shape
+    G = k.shape[2]
+    if H % G:
+        raise ValueError(f"{H} query heads over {G} key/value heads")
+    R = H // G
+    C = chunk or pick_chunk(S)
+    pad = (-S) % C
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+        log_g = jnp.pad(log_g, ((0, 0), (0, pad), (0, 0)))
+    n = (S + pad) // C
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    # chunk-major, heads before positions: (n, B, G, [R,] C, d)
+    qc = q.reshape(B, n, C, G, R, d).transpose(1, 0, 3, 4, 2, 5).astype(bf16)
+    kc = k.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
+    vc = v.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
+    gc = log_g.astype(f32).reshape(B, n, C, G).transpose(1, 0, 3, 2)
+    causal = np.tril(np.ones((C, C), bool))
+    inv_d = 1.0 / d
+    hi = lax.Precision.HIGHEST
+
+    def step(carry, xs):
+        state, norm = carry            # (B, G, D, d), (B, G, d, d)
+        qi, ki, vi, gi = xs
+        cum = jnp.cumsum(gi, axis=-1)                       # (B, G, C)
+        total = cum[..., -1]
+        # inside the chunk: the quadratic form
+        s = jnp.einsum("bgrtd,bgsd->bgrts", qi, ki,
+                       preferred_element_type=f32)
+        decay = cum[..., :, None] - cum[..., None, :]       # (B, G, C, C)
+        decay = jnp.exp(jnp.where(causal, decay, -jnp.inf))
+        # rounded once, for the numerator and the normaliser alike: the
+        # ratio is then an average of v under slightly other weights
+        w = (s * s * (inv_d * decay[:, :, None])).astype(bf16)
+        den = jnp.sum(w.astype(f32), axis=-1)               # (B, G, R, C)
+        num = jnp.einsum("bgrts,bgse->bgrte", w, vi,
+                         preferred_element_type=f32)
+        # from the chunks before: the state
+        carried = jnp.exp(cum)[:, :, None]                  # (B, G, 1, C)
+        num_s = _read_state(qi, state.astype(bf16), inv_d)
+        q32 = qi.astype(f32)
+        den_s = jnp.sum(jnp.einsum("bgrtd,bgde->bgrte", q32,
+                                   norm.astype(f32), precision=hi) * q32,
+                        axis=-1)
+        num = num + carried[..., None] * num_s
+        den = den + carried * den_s
+        y = num / (den[..., None] + eps)
+        # the state after this chunk
+        left = jnp.exp(total[..., None] - cum)              # (B, G, C)
+        keep = jnp.exp(total)[..., None, None]
+        k32 = ki.astype(f32)
+        state = keep * state.astype(f32) + jnp.einsum(
+            "bgfs,bgse->bgfe", _phi_keys(ki),
+            (vi.astype(f32) * left[..., None]).astype(bf16),
+            preferred_element_type=f32)
+        norm = keep * norm.astype(f32) + inv_d * jnp.einsum(
+            "bgsd,bgse->bgde", k32 * left[..., None], k32, precision=hi)
+        return (state.astype(state_dtype), norm.astype(state_dtype)), y
+
+    init = (jnp.zeros((B, G, phi_width(d), d), state_dtype),
+            jnp.zeros((B, G, d, d), state_dtype))
+    _, y = lax.scan(step, init, (qc, kc, vc, gc))           # (n, B, G, R, C, d)
+    y = y.transpose(1, 0, 4, 2, 3, 5).reshape(B, n * C, H, d)
+    return y[:, :S].astype(q.dtype)

@@ -20,7 +20,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
-import jax
 import numpy as np
 
 from predictionio_tpu.controller import (
@@ -39,13 +38,14 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.controller.base import PersistentModelManifest
 from predictionio_tpu.models.als import ALSModel, build_allow_vector
-from predictionio_tpu.obs.trace import active_trace, span
+from predictionio_tpu.obs.trace import span
 from predictionio_tpu.ops import topk as topk_ops
 from predictionio_tpu.ops.als import (
     RatingsCOO,
     als_train,
     resolve_shard_factors,
 )
+from predictionio_tpu.serving.dispatch_phases import await_and_fetch
 from predictionio_tpu.utils.bimap import EntityIdIxMap
 
 
@@ -362,16 +362,7 @@ class ALSAlgorithm(ShardedAlgorithm):
         # NumPy so the brute dispatcher's host-side _trim_seen can
         # right-size them
         vals, idxs = model.batch_topk(uixs, cols, mask, None, k)
-        if active_trace() is not None:
-            # traced dispatches only: split the wait for the device
-            # from the copy back; untraced, the first np.asarray below
-            # is the one sync, as it always was
-            with span("dispatch.device_wait"):
-                jax.block_until_ready((vals, idxs))
-        B = len(known)
-        with span("dispatch.fetch"):
-            vals = np.asarray(vals)[:B]
-            idxs = np.asarray(idxs)[:B]
+        vals, idxs = await_and_fetch((vals, idxs))
         with span("dispatch.results"):
             inv = model.item_ids.inverse
             for j, (qi, _, num) in enumerate(known):

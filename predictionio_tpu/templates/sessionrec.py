@@ -45,6 +45,9 @@ from predictionio_tpu.controller import (
     SanityCheck,
 )
 from predictionio_tpu.models import seqrec
+from predictionio_tpu.obs.trace import span
+from predictionio_tpu.ops.topk import serving_k
+from predictionio_tpu.serving.dispatch_phases import await_and_fetch
 from predictionio_tpu.utils.bimap import BiMap
 
 _NEG = np.float32(-1e30)
@@ -162,23 +165,61 @@ class AlgorithmParams(Params):
     # N epochs to checkpoint_dir; a re-run resumes from the last one
     checkpoint_dir: str = ""
     checkpoint_every: int = 0
+    # the block the stack is built from (models/seqrec.BLOCKS) and, for
+    # "brumby", its widths; 0 keeps what the SASRec block derives
+    backbone: str = "sasrec"
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    d_ff: int = 0
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    retention_degree: int = 2
+    tie_embeddings: bool = True
+    # the type serving holds the weights in on the device
+    param_dtype: str = "float32"
+
+    def seqrec_config(self, vocab: int) -> seqrec.SeqRecConfig:
+        import jax.numpy as jnp
+
+        return seqrec.SeqRecConfig(
+            vocab=vocab, max_len=self.max_len, d_model=self.d_model,
+            n_heads=self.n_heads, n_layers=self.n_layers, remat=self.remat,
+            block=self.backbone, n_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim, d_ff=self.d_ff,
+            rope_theta=self.rope_theta, rms_eps=self.rms_eps,
+            retention_degree=self.retention_degree,
+            tie_embeddings=self.tie_embeddings,
+            param_dtype=jnp.dtype(self.param_dtype))
 
 
 @dataclasses.dataclass
 class SeqRecEngineModel:
-    params: dict            # transformer weights (host numpy pytree)
+    params: dict            # stack weights (host numpy pytree)
     cfg: seqrec.SeqRecConfig
     item_index: BiMap       # item id string -> dense index (1-based)
-    histories: dict         # user -> [dense item indices] (serving state)
+    histories: dict         # user -> int32 array of dense item indices
     # device-resident weight cache, populated on first predict; never
     # serialized (recreated after checkpoint load / reload)
     device_tree: Any = dataclasses.field(default=None, repr=False,
                                          compare=False)
+    # called once per batch_predict with (device programs launched,
+    # real tokens, padded tokens, 1 if the token budget split it): the
+    # engine server points it at ServingStats.record_seq_dispatch
+    dispatch_observer: Any = dataclasses.field(default=None, repr=False,
+                                               compare=False)
+    # token_budget()'s answer for this model on this device (0: not
+    # worked out yet); never serialized
+    budget: int = dataclasses.field(default=0, repr=False, compare=False)
 
     def __getstate__(self):
         state = self.__dict__.copy()
         state["device_tree"] = None
+        state["dispatch_observer"] = None
+        state["budget"] = 0
         return state
+
+    def set_dispatch_observer(self, observer) -> None:
+        self.dispatch_observer = observer
 
 
 class SeqRecAlgorithm(HostModelAlgorithm):
@@ -193,16 +234,10 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         # dense ids start at 1: index 0 is the PAD token
         item_index = BiMap({item: i + 1 for i, item in enumerate(items)})
         dense = {
-            u: [item_index[i] for i in seq] for u, seq in pd.sequences.items()
+            u: np.asarray([item_index[i] for i in seq], np.int32)
+            for u, seq in pd.sequences.items()
         }
-        cfg = seqrec.SeqRecConfig(
-            vocab=len(items) + 1,
-            max_len=p.max_len,
-            d_model=p.d_model,
-            n_heads=p.n_heads,
-            n_layers=p.n_layers,
-            remat=p.remat,
-        )
+        cfg = p.seqrec_config(vocab=len(items) + 1)
         mesh = ctx.mesh_if_parallel if p.use_mesh else None
         if mesh is not None and "seq" in mesh.shape and \
                 p.max_len % int(mesh.shape["seq"]):
@@ -211,7 +246,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                 f"axis size ({int(mesh.shape['seq'])})"
             )
         weights = seqrec.train(
-            list(dense.values()), cfg,
+            [seq.tolist() for seq in dense.values()], cfg,
             epochs=p.epochs, batch_size=p.batch_size, lr=p.lr,
             seed=p.seed, mesh=mesh,
             checkpoint_dir=p.checkpoint_dir or None,
@@ -229,13 +264,12 @@ class SeqRecAlgorithm(HostModelAlgorithm):
     # -- serving ------------------------------------------------------------
 
     def _history_for(self, model: SeqRecEngineModel, query: Query):
+        """int32 dense item indices, oldest first (empty: nothing known)."""
         if query.items:
-            return [
-                model.item_index.get(i)
-                for i in query.items
-                if model.item_index.get(i) is not None
-            ]
-        return model.histories.get(query.user, [])
+            return np.asarray(
+                [ix for ix in map(model.item_index.get, query.items)
+                 if ix is not None], np.int32)
+        return np.asarray(model.histories.get(query.user, ()), np.int32)
 
     def predict(self, model: SeqRecEngineModel, query: Query) -> PredictedResult:
         # single-query serving is the B=1 case of the batched path —
@@ -243,78 +277,132 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         return self.batch_predict(model, [(0, query)])[0][1]
 
     def batch_predict(self, model: SeqRecEngineModel, queries):
-        """Batched eval path: power-of-two batch buckets through one
-        jitted forward (seqrec.predict_topk_batch with per-query masks)
-        instead of |queries| B=1 calls — the Engine.eval hot path."""
-        import jax.numpy as jnp
+        """Whatever the batcher (or ``Engine.eval``) hands over, scored
+        in device programs of at most ``token_budget`` tokens each:
+        power-of-two batch buckets of ``max_len``-long histories through
+        one jitted forward (seqrec.predict_topk_batch with per-query
+        masks), as few programs as the budget allows.
 
+        Phases are the ambient ``dispatch.*`` spans the recommendation
+        template records (no-ops unless the batcher bound its
+        per-dispatch trace): prepare (routing, the histories looked
+        up), gather (the padded history matrix and the seen masks the
+        programs take), then per program enqueue -> device_wait ->
+        fetch -> results."""
         S = model.cfg.max_len
-        base_mask = np.zeros((model.cfg.vocab,), np.float32)
-        base_mask[seqrec.PAD] = _NEG
-        prepared, out = [], []
-        for i, q in queries:
-            history = self._history_for(model, q)
-            if not history:
-                out.append((i, PredictedResult()))
-                continue
-            tail = history[-S:]
-            hist = np.zeros((S,), np.int32)
-            hist[: len(tail)] = tail
-            mask = base_mask.copy()
-            for dense_id in tail:               # don't repeat the session
-                mask[dense_id] = _NEG
-            for item in q.black_list:
-                di = model.item_index.get(item)
-                if di is not None:
-                    mask[di] = _NEG
-            prepared.append((i, q, hist, mask))
-        if not prepared:
-            return out
-
-        # menu-ized STATIC top-k width (ops/topk.serving_k: client-
-        # controlled num must not retrace predict_topk_batch; results
-        # trim per query below)
-        from predictionio_tpu.ops.topk import serving_k
-
-        k = serving_k(max(q.num for _, q, _, _ in prepared),
-                      model.cfg.vocab - 1)
+        with span("dispatch.prepare"):
+            out, rows, hist = [], [], []
+            for i, q in queries:
+                history = self._history_for(model, q)
+                if history.size == 0:
+                    out.append((i, PredictedResult()))
+                    continue
+                rows.append((i, q))
+                hist.append(history[-S:])
+            if not rows:
+                return out
+            # menu-ized STATIC top-k width (ops/topk.serving_k: client-
+            # controlled num must not retrace predict_topk_batch;
+            # results trim per query below)
+            k = serving_k(max(q.num for _, q in rows), model.cfg.vocab - 1)
+            tree = _as_device_tree(model)
+            widest = max(1, min(token_budget(model) // S, _MAX_BUCKET))
+        with span("dispatch.gather"):
+            lengths = np.fromiter(map(len, hist), np.int64, len(hist))
+            padded = np.zeros((len(rows), S), np.int32)
+            padded[np.arange(S)[None, :] < lengths[:, None]] = \
+                np.concatenate(hist)
+            # the session's own items and PAD never come back
+            # (padded's zeros are PAD itself)
+            masks = np.zeros((len(rows), model.cfg.vocab), np.float32)
+            np.put_along_axis(masks, padded, _NEG, axis=1)
+            masks[:, seqrec.PAD] = _NEG
+            for r, (_, q) in enumerate(rows):
+                for item in q.black_list:
+                    di = model.item_index.get(item)
+                    if di is not None:
+                        masks[r, di] = _NEG
         inv = model.item_index.inverse
-        pos = 0
-        while pos < len(prepared):
-            remaining = len(prepared) - pos
+        programs = pos = 0
+        while pos < len(rows):
             bucket = 1
-            while bucket * 2 <= min(remaining, 256):
+            while bucket * 2 <= min(len(rows) - pos, widest):
                 bucket *= 2
-            chunk = prepared[pos : pos + bucket]
+            part = slice(pos, pos + bucket)
             pos += bucket
-            scores, ids = seqrec.predict_topk_batch(
-                _as_device_tree(model),
-                jnp.asarray(np.stack([h for _, _, h, _ in chunk])),
-                k, model.cfg,
-                jnp.asarray(np.stack([m for _, _, _, m in chunk])),
-            )
-            for (i, q, _, _), svals, sids in zip(
-                    chunk, np.asarray(scores), np.asarray(ids)):
-                items = []
-                for v, ix in zip(svals[: q.num], sids[: q.num]):
-                    if v <= _NEG / 2:
-                        continue
-                    item = inv.get(int(ix))
-                    if item is not None:
-                        items.append(ItemScore(item=item, score=float(v)))
-                out.append((i, PredictedResult(item_scores=tuple(items))))
+            programs += 1
+            with span("dispatch.enqueue"):
+                scores, ids = seqrec.predict_topk_batch(
+                    tree, padded[part], k, model.cfg, masks[part])
+            scores, ids = await_and_fetch((scores, ids))
+            with span("dispatch.results"):
+                for (i, q), svals, sids in zip(rows[part], scores, ids):
+                    items = []
+                    for v, ix in zip(svals[: q.num], sids[: q.num]):
+                        if v <= _NEG / 2:
+                            continue
+                        item = inv.get(int(ix))
+                        if item is not None:
+                            items.append(ItemScore(item=item, score=float(v)))
+                    out.append((i, PredictedResult(item_scores=tuple(items))))
+        if model.dispatch_observer is not None:
+            model.dispatch_observer(
+                programs, int(lengths.sum()), len(rows) * S,
+                int(len(rows) > widest))
         return out
+
+
+#: the widest batch bucket, whatever the token budget allows
+_MAX_BUCKET = 256
+#: what a program's activations may take of the device's memory; the
+#: rest is the weights, the logits and masks, and room for the next
+#: program's arguments while this one runs
+_ACTIVATION_SHARE = 0.25
+#: assumed where the backend does not say (the CPU): one v5e chip
+_DEFAULT_DEVICE_BYTES = 16e9
+
+
+def _device_memory_bytes() -> float:
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return float(stats.get("bytes_limit", _DEFAULT_DEVICE_BYTES))
+
+
+def token_budget(model: SeqRecEngineModel) -> int:
+    """The most tokens (queries x max_len) one device program may hold:
+    a power of two, from the stack's widths (seqrec.activation_bytes_
+    per_token) and what the device has left beside the weights. At
+    least one history always goes. Worked out once per model."""
+    if not model.budget:
+        import jax
+
+        limit = _device_memory_bytes()
+        weights = sum(a.nbytes
+                      for a in jax.tree.leaves(_as_device_tree(model)))
+        room = min(limit * _ACTIVATION_SHARE, 0.9 * limit - weights)
+        tokens = int(max(room, 0)
+                     // seqrec.activation_bytes_per_token(model.cfg))
+        model.budget = max(1 << max(tokens, 1).bit_length() - 1,
+                           model.cfg.max_len)
+    return model.budget
 
 
 def _as_device_tree(model: SeqRecEngineModel):
     """Device-put the weight pytree once per model instance (serving keeps
-    models HBM-resident between requests — SURVEY.md §7 stage 7). Cached
-    on the model object itself, so a hot-swap (/reload) naturally drops
-    the old device weights with the old model."""
+    models HBM-resident between requests — SURVEY.md §7 stage 7), in
+    ``cfg.param_dtype``: a bfloat16 stack is rounded on the host and
+    only that copy reaches the device. Cached on the model object
+    itself, so a hot-swap (/reload) naturally drops the old device
+    weights with the old model."""
     if model.device_tree is None:
         import jax
 
-        model.device_tree = jax.tree.map(jax.device_put, dict(model.params))
+        dtype = model.cfg.param_dtype
+        model.device_tree = jax.tree.map(
+            lambda a: jax.device_put(a if a.dtype == dtype
+                                     else np.asarray(a).astype(dtype)),
+            dict(model.params))
     return model.device_tree
 
 
