@@ -61,6 +61,9 @@ PORT = 18431
 #: bf16 (8 bits of mantissa), so they may differ by an ulp of the value:
 #: |diff| <= FLASH_TOL * (1 + |reference|)
 FLASH_TOL = 2e-2
+#: the fused state pass against the jax.numpy step: bfloat16 outputs a
+#: last bit apart (2^-8 of the value) where a float32 sum fell otherwise
+RETENTION_TOL = 1e-2
 
 
 class SmokeFailure(Exception):
@@ -129,7 +132,9 @@ def seed_child(n: int, app_id: int) -> int:
 def kernels_child() -> int:
     """Runs in a child: flash_attention COMPILED vs full_attention at
     the envelope ends, sessionrec serving shape (d_model 256, 4 heads ->
-    D = 64, bf16, causal)."""
+    D = 64, bf16, causal); then power retention with its state pass in
+    the fused kernel vs the jax.numpy step, at the smallest eligible
+    shape and at the session cell's."""
     from predictionio_tpu.utils.accelerator import start_compute
 
     start_compute()
@@ -163,7 +168,50 @@ def kernels_child() -> int:
         ok &= good
         print(f"flash S={s}: max|diff|={err:.3e} finite={finite} "
               f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
-    return 0 if ok else 1
+    return 0 if ok and retention_kernel_ok() else 1
+
+
+def retention_kernel_ok() -> bool:
+    """Both ends of ``pallas_retention.in_envelope`` that anything runs:
+    one head of 128 over two chunks of 128, and the session cell's 40
+    and 8 heads over three chunks of 256 (the carried state read twice).
+    The two paths round the same products and differ by the order of
+    float32 sums: a last bit of bfloat16 here and there."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import retention
+
+    ok = True
+    for name, (s, h, g, chunk) in (("smallest", (256, 1, 1, 128)),
+                                   ("cell", (768, 40, 8, 256))):
+        if not retention.fuses_state_pass(128, h // g, chunk, inference=True):
+            print(f"retention {name}: the rule does not choose the kernel")
+            return False
+        keys = jax.random.split(jax.random.PRNGKey(s), 4)
+        q, k, v = (jax.random.normal(key, (1, s, n, 128), jnp.bfloat16)
+                   for key, n in zip(keys, (h, g, g)))
+        log_g = jax.nn.log_sigmoid(
+            6.9 + jax.random.normal(keys[3], (1, s, g), jnp.float32))
+        fused, plain = (
+            jax.jit(lambda *a, inference=inference: retention.power_retention(
+                *a, chunk=chunk, inference=inference))
+            for inference in (True, False))
+        if "tpu_custom_call" not in fused.lower(q, k, v, log_g).as_text():
+            print(f"retention {name}: no Mosaic custom call in the program")
+            return False
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(fused(q, k, v, log_g)).astype(jnp.float32)
+        t1 = time.perf_counter()
+        want = plain(q, k, v, log_g).astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(got - want)))
+        good = bool(jnp.all(jnp.isfinite(got))) and bool(jnp.all(
+            jnp.abs(got - want) <= RETENTION_TOL * (1 + jnp.abs(want))))
+        ok &= good
+        print(f"retention {name} S={s} H={h} G={g} chunk={chunk}: "
+              f"max|diff|={err:.3e} first_call_s={t1 - t0:.2f} "
+              f"{'ok' if good else 'MISMATCH'}")
+    return ok
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +496,7 @@ def main() -> int:
     # 5. kernels
     out, kern_s = run("kernels", me + ["_kernels"], 900)
     for line in out.splitlines():
-        if line.startswith("flash "):
+        if line.startswith(("flash ", "retention ")):
             log(line)
 
     # the rule of utils/accelerator, restated so that this count checks it

@@ -52,7 +52,7 @@ class ServingStats:
         "ann_queries", "ann_rescored",
         # the session engine's dispatches (templates/sessionrec)
         "seq_programs", "seq_tokens", "seq_padded_tokens",
-        "seq_split_dispatches",
+        "seq_split_dispatches", "seq_fused_retention_programs",
     )
 
     def __init__(self):
@@ -103,17 +103,22 @@ class ServingStats:
             self._ann_hist[shortlist_width] += queries
 
     def record_seq_dispatch(self, programs: int, tokens: int,
-                            padded_tokens: int, split: int) -> None:
+                            padded_tokens: int, split: int,
+                            fused_retention_programs: int = 0) -> None:
         """One ``batch_predict`` of the session engine: the device
         programs it launched, the events of the histories it scored,
         the tokens the programs ran over (each history padded to
-        ``max_len``), and whether the token budget split it into more
-        than one program (the SeqRecEngineModel observer hook)."""
+        ``max_len``), whether the token budget split it into more
+        than one program, and how many of the programs ran retention's
+        state pass in the fused kernel (the SeqRecEngineModel observer
+        hook)."""
         with self._lock:
             self._counts["seq_programs"] += programs
             self._counts["seq_tokens"] += tokens
             self._counts["seq_padded_tokens"] += padded_tokens
             self._counts["seq_split_dispatches"] += split
+            self._counts["seq_fused_retention_programs"] += \
+                fused_retention_programs
 
     def ann_histogram(self) -> dict[int, int]:
         """Shortlist width -> query count, read under the lock."""

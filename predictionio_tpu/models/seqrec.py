@@ -41,7 +41,8 @@ from predictionio_tpu.ops.attention import (
     full_attention,
     ring_attention,
 )
-from predictionio_tpu.ops.retention import power_retention
+from predictionio_tpu.ops.retention import (
+    fuses_state_pass, pick_chunk, power_retention)
 
 logger = logging.getLogger(__name__)
 
@@ -321,7 +322,7 @@ def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
             jnp.einsum("bsd,dg->bsg", h, layer["wg"].astype(dt),
                        preferred_element_type=f32) + cfg.gate_init_logit)
         y = power_retention(q.astype(dt), k.astype(dt), v, log_g,
-                            degree=cfg.retention_degree)
+                            degree=cfg.retention_degree, inference=inference)
         x = x + y.reshape(B, S, H * hd) @ layer["wo"].astype(dt)
         with jax.named_scope("swiglu"):
             h = _rms(x, layer["post_norm"], cfg.rms_eps)
@@ -336,6 +337,16 @@ def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
     for layer in params["layers"]:
         x = block(x, layer)
     return _rms(x, params["out_norm"], cfg.rms_eps)
+
+
+def fuses_retention(cfg: SeqRecConfig, seq_len: int) -> bool:
+    """Whether a serving program over ``seq_len``-long histories
+    (:func:`predict_topk_batch`, ``inference=True``) runs retention's
+    state pass in the fused kernel: ``_forward_brumby``'s own rule, for
+    the counters of whoever launches the program."""
+    return cfg.block == "brumby" and fuses_state_pass(
+        cfg.hd, cfg.n_heads // cfg.kv_heads, pick_chunk(seq_len),
+        inference=True)
 
 
 @dataclasses.dataclass(frozen=True)

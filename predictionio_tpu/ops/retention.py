@@ -18,9 +18,22 @@ by ``lax.scan`` in float32. The normaliser needs no ``phi``:
 
 Matrix products take bfloat16 operands and accumulate in float32; the
 state, the normaliser, the gates' cumulative sums and every weight are
-float32. Everything is ``jax.numpy``, so JAX's autodiff differentiates
-it. Padding after a history's last event cannot change an earlier
+float32. Padding after a history's last event cannot change an earlier
 position: the mixing is causal and chunks are scanned in order.
+
+Two ways through a chunk step's state pass (the read ``phi(q)^T S`` and
+the update ``keep * S + phi(k) (v * left)``), one set of equations. The
+``jax.numpy`` one (:func:`_read_state`, :func:`_phi_keys`) is the
+definition: JAX's autodiff differentiates it, so it is the path of
+every caller that may take a gradient (training enters with
+``inference=False``), of the CPU, and of every shape outside the
+kernel's envelope. The fused one (``ops/pallas_retention.py``, forward
+only) forms the features in fast memory beside the state tile they
+meet, so that nothing as wide as the state but the state crosses HBM;
+:func:`fuses_state_pass` says when it is taken, from what the code can
+observe and with no option. Everything else of the step — the in-chunk
+quadratic form, the normaliser, the gates — and the ``lax.scan`` over
+chunks are ``jax.numpy`` on both.
 """
 
 from __future__ import annotations
@@ -29,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from predictionio_tpu.ops import pallas_attention, pallas_retention
 
 #: ``phi`` keeps the upper block triangle of ``u u^T`` in blocks of this
 #: many coordinates (diagonal blocks whole, the others doubled): at
@@ -105,6 +120,19 @@ def _read_state(q: jax.Array, state: jax.Array, scale: float) -> jax.Array:
     return out
 
 
+def fuses_state_pass(d: int, ratio: int, chunk: int, *, inference: bool,
+                     state_dtype=jnp.float32) -> bool:
+    """Whether :func:`power_retention` reads and updates its state in
+    the fused kernel (``ops/pallas_retention.py``) at head width ``d``,
+    ``ratio`` query heads per key/value head and this chunk length: on a
+    compiled TPU backend, for a caller that does not differentiate, at a
+    shape inside the kernel's envelope. Inside it there is no way back
+    to XLA: a kernel that fails to build raises."""
+    return (inference and pallas_attention._mode() == "compiled"
+            and jnp.dtype(state_dtype) == jnp.float32
+            and pallas_retention.in_envelope(d, ratio * chunk, chunk))
+
+
 def power_retention(
     q: jax.Array,        # (B, S, H, d)
     k: jax.Array,        # (B, S, G, d), H a multiple of G
@@ -114,28 +142,38 @@ def power_retention(
     degree: int = 2,
     chunk: int | None = None,
     eps: float = 1e-6,
+    inference: bool = False,
     _state_dtype=jnp.float32,
 ) -> jax.Array:
     """The mixing above; returns (B, S, H, d) in ``q.dtype``.
 
     ``chunk`` is chosen from the sequence length when not given (tests
-    pass small ones). ``_state_dtype`` exists for one test, which shows
-    that a state accumulated in bfloat16 is caught."""
+    pass small ones). ``inference`` says that the caller takes no
+    gradient: the state pass may then run in the forward-only kernel
+    (:func:`fuses_state_pass`). ``_state_dtype`` exists for one test,
+    which shows that a state accumulated in bfloat16 is caught."""
     if degree != 2:
         raise NotImplementedError(
             f"power retention of degree {degree}: only degree 2 has a "
             "feature map here")
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads over {k.shape[2]} key/value heads")
+    chunk = chunk or pick_chunk(q.shape[1])
+    kernel = "compiled" if fuses_state_pass(
+        q.shape[3], q.shape[2] // k.shape[2], chunk, inference=inference,
+        state_dtype=_state_dtype) else None
     with jax.named_scope("power_retention"):
-        return _power_retention(q, k, v, log_g, chunk, eps, _state_dtype)
+        return _power_retention(q, k, v, log_g, chunk, eps, _state_dtype,
+                                kernel)
 
 
-def _power_retention(q, k, v, log_g, chunk, eps, state_dtype):
+def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
+    """``kernel``: None for the ``jax.numpy`` state pass, ``"compiled"``
+    or ``"interpret"`` (tests, on the CPU) for the fused one."""
     B, S, H, d = q.shape
     G = k.shape[2]
-    if H % G:
-        raise ValueError(f"{H} query heads over {G} key/value heads")
     R = H // G
-    C = chunk or pick_chunk(S)
     pad = (-S) % C
     if pad:
         q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -153,7 +191,7 @@ def _power_retention(q, k, v, log_g, chunk, eps, state_dtype):
     hi = lax.Precision.HIGHEST
 
     def step(carry, xs):
-        state, norm = carry            # (B, G, D, d), (B, G, d, d)
+        state, norm = carry            # (B, G, F, d), (B, G, d, d)
         qi, ki, vi, gi = xs
         cum = jnp.cumsum(gi, axis=-1)                       # (B, G, C)
         total = cum[..., -1]
@@ -168,9 +206,23 @@ def _power_retention(q, k, v, log_g, chunk, eps, state_dtype):
         den = jnp.sum(w.astype(f32), axis=-1)               # (B, G, R, C)
         num = jnp.einsum("bgrts,bgse->bgrte", w, vi,
                          preferred_element_type=f32)
-        # from the chunks before: the state
+        # from the chunks before: the state, read and then decayed to
+        # the chunk's end with this chunk's keys and values added
         carried = jnp.exp(cum)[:, :, None]                  # (B, G, 1, C)
-        num_s = _read_state(qi, state.astype(bf16), inv_d)
+        left = jnp.exp(total[..., None] - cum)              # (B, G, C)
+        keep = jnp.exp(total)[..., None, None]
+        vl = (vi.astype(f32) * left[..., None]).astype(bf16)
+        if kernel:
+            num_s, state = pallas_retention.state_pass(
+                qi.reshape(B * G, R * C, d), ki.reshape(B * G, C, d),
+                vl.reshape(B * G, C, d), keep.reshape(B * G), state,
+                interpret=kernel == "interpret")
+            num_s = num_s.reshape(B, G, R, C, d)
+        else:
+            num_s = _read_state(qi, state.astype(bf16), inv_d)
+            state = keep * state.astype(f32) + jnp.einsum(
+                "bgfs,bgse->bgfe", _phi_keys(ki), vl,
+                preferred_element_type=f32)
         q32 = qi.astype(f32)
         den_s = jnp.sum(jnp.einsum("bgrtd,bgde->bgrte", q32,
                                    norm.astype(f32), precision=hi) * q32,
@@ -178,19 +230,15 @@ def _power_retention(q, k, v, log_g, chunk, eps, state_dtype):
         num = num + carried[..., None] * num_s
         den = den + carried * den_s
         y = num / (den[..., None] + eps)
-        # the state after this chunk
-        left = jnp.exp(total[..., None] - cum)              # (B, G, C)
-        keep = jnp.exp(total)[..., None, None]
         k32 = ki.astype(f32)
-        state = keep * state.astype(f32) + jnp.einsum(
-            "bgfs,bgse->bgfe", _phi_keys(ki),
-            (vi.astype(f32) * left[..., None]).astype(bf16),
-            preferred_element_type=f32)
         norm = keep * norm.astype(f32) + inv_d * jnp.einsum(
             "bgsd,bgse->bgde", k32 * left[..., None], k32, precision=hi)
         return (state.astype(state_dtype), norm.astype(state_dtype)), y
 
-    init = (jnp.zeros((B, G, phi_width(d), d), state_dtype),
+    # the kernel keeps its state per key/value head of the batch, the
+    # features in its own order (pallas_retention.block_pairs)
+    state_shape = (B * G,) if kernel else (B, G)
+    init = (jnp.zeros((*state_shape, phi_width(d), d), state_dtype),
             jnp.zeros((B, G, d, d), state_dtype))
     _, y = lax.scan(step, init, (qc, kc, vc, gc))           # (n, B, G, R, C, d)
     y = y.transpose(1, 0, 4, 2, 3, 5).reshape(B, n * C, H, d)

@@ -203,8 +203,10 @@ class SeqRecEngineModel:
     device_tree: Any = dataclasses.field(default=None, repr=False,
                                          compare=False)
     # called once per batch_predict with (device programs launched,
-    # real tokens, padded tokens, 1 if the token budget split it): the
-    # engine server points it at ServingStats.record_seq_dispatch
+    # real tokens, padded tokens, 1 if the token budget split it) and,
+    # where any ran retention's state pass in the fused kernel, how many
+    # did: the engine server points it at
+    # ServingStats.record_seq_dispatch
     dispatch_observer: Any = dataclasses.field(default=None, repr=False,
                                                compare=False)
     # token_budget()'s answer for this model on this device (0: not
@@ -324,6 +326,9 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                         masks[r, di] = _NEG
         inv = model.item_index.inverse
         programs = pos = 0
+        # one answer for every program: the rule looks at the widths and
+        # at the history length, not at the batch
+        fused = seqrec.fuses_retention(model.cfg, S)
         while pos < len(rows):
             bucket = 1
             while bucket * 2 <= min(len(rows) - pos, widest):
@@ -346,9 +351,9 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                             items.append(ItemScore(item=item, score=float(v)))
                     out.append((i, PredictedResult(item_scores=tuple(items))))
         if model.dispatch_observer is not None:
-            model.dispatch_observer(
-                programs, int(lengths.sum()), len(rows) * S,
-                int(len(rows) > widest))
+            report = (programs, int(lengths.sum()), len(rows) * S,
+                      int(len(rows) > widest))
+            model.dispatch_observer(*report, *((programs,) if fused else ()))
         return out
 
 
