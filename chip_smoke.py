@@ -8,7 +8,7 @@ process at a time, so at no moment do two of them hold JAX):
 1. storage inside the checkout, ``pio status`` (names the device),
    ``pio app new``;
 2. ``rate`` events seeded through ``Events.insert_batch`` over the full
-   MovieLens-20M catalog (138,493 users x 26,744 items, ``bench.py``'s
+   MovieLens-20M catalog (138,493 users x 26,744 items, a
    power-law shape, every user and item present so the factor tables
    have their full width);
 3. ``pio train --profile`` of the recommendation template at rank 32,
@@ -81,7 +81,7 @@ def log(msg: str) -> None:
 def make_ratings(n: int, seed: int = 0):
     """``n`` (user, item, rating) triples: first one rating for every
     user and item (so both factor tables reach full width whatever
-    ``n``), then ``bench.py``'s power-law draw."""
+    ``n``), then a power-law draw."""
     if n < USERS:
         raise SmokeFailure(f"--events must be at least {USERS} to cover "
                            "the catalog")

@@ -1289,8 +1289,7 @@ class _Handler(BaseHTTPRequestHandler):
 
     # HTTP/1.1 keep-alive: the stdlib default (1.0) closes the socket
     # after every response, so each query paid a TCP connect + a fresh
-    # ThreadingHTTPServer thread — measured as the dominant serving
-    # cost at high concurrency (bench_serving.py). Persistent
+    # ThreadingHTTPServer thread. Persistent
     # connections make the per-request cost one read/write on a
     # long-lived thread. Requires the Content-Length header on every
     # response, which _respond always sends.

@@ -1004,10 +1004,9 @@ def _read_request(sock, buf: bytearray):
     message boundary. Raises ``_BadRequest`` (answer-and-close) on a
     malformed message, ``OSError``/``TimeoutError`` on transport death.
 
-    The stdlib ``BaseHTTPRequestHandler`` costs ~1-2ms CPU per request
-    (readline loop + email-parser headers + per-response strftime) —
-    the same measurement that drove bench_serving.py's raw-socket
-    clients. The router sits on EVERY fleet query, so its inbound hot
+    The stdlib ``BaseHTTPRequestHandler`` spends CPU on every request
+    (readline loop + email-parser headers + per-response strftime).
+    The router sits on EVERY fleet query, so its inbound hot
     path uses the same minimal single-buffer parse as its upstream
     transport; the engine server keeps the stdlib handler (its predict
     work dwarfs the parse; the router's doesn't)."""

@@ -39,9 +39,8 @@ fsync policy (``always | interval | off``): ``always`` fsyncs every
 append (every 202 is crash-durable — the honest mode for the
 durability pin), ``interval`` fsyncs at most every
 ``fsync_interval_s`` on the appending thread (bounded loss window on
-power failure, near-direct-insert throughput — the default),
-``off`` leaves it to the OS (bench/bulk loads). Measured per policy in
-``bench_ingest.py`` (BENCH_wal_r01.json).
+power failure, no fsync on the request path between intervals — the
+default), ``off`` leaves it to the OS (bulk loads).
 
 The journal is bounded honestly: past ``max_bytes`` of pending frames
 ``append`` raises :class:`WalFullError` and the server reverts to

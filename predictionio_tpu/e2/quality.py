@@ -317,9 +317,8 @@ def implicit_vs_popularity_kfold(
     seed: int = 3,
 ) -> dict[str, float]:
     """Mean MAP@k of the implicit path vs the popularity baseline over
-    ALL folds — the protocol shared by the bench's real-data keys
-    (``map10_*_real``) and the off-generator gating test, hoisted here
-    so the two cannot drift (ADVICE-style round-4 review finding)."""
+    ALL folds — the protocol of the off-generator gating test
+    (tests/test_quality_parity.py)."""
     imps, pops = [], []
     for fold in range(k_fold):
         train, test = kfold_split(ds, k_fold=k_fold, fold=fold, seed=seed)
@@ -348,8 +347,7 @@ def compare_quality(
     """Train the device-path ALS (ops/als.als_train) and the independent
     NumPy ALS-WR on the same fold; evaluate both plus the popularity
     baseline AND the implicit-feedback ranking path under the identical
-    protocol. Returns a flat metric dict (the bench harness embeds it in
-    the BENCH JSON line).
+    protocol. Returns a flat metric dict.
 
     Two quality axes, stated plainly: ``rmse_*``/``map{k}_tpu`` vs
     ``map{k}_ref`` are *parity* metrics (same estimator, two

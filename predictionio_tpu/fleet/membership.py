@@ -15,7 +15,7 @@ resilience.Clock`) and the loop can be driven synchronously
 deterministic in tests without wall-time sleeps.
 
 **Probe-starvation guard** (the 1s-probe-under-GIL-saturation pitfall,
-measured in BENCH_router_r01 and written up in the docs/fleet.md
+written up in the docs/fleet.md
 "Healthy fleet marked down under load" runbook): a probe that TIMES OUT
 against a replica whose data path is demonstrably fine — breaker
 closed, a successful forwarded exchange within the grace window — is

@@ -1,10 +1,9 @@
 """Lean upstream HTTP client for the router's forward path.
 
-``http.client`` costs milliseconds of CPU per request (header assembly
-plus the email-parser response machinery — the same measurement that
-drove bench_serving.py's raw-socket load generator), and the router
-sits on EVERY query, so its upstream hop uses the same discipline as
-the engine server's response path: pre-built single-write requests over
+``http.client`` spends CPU on every request (header assembly plus the
+email-parser response machinery), and the router sits on EVERY query,
+so its upstream hop uses the same discipline as the engine server's
+response path: pre-built single-write requests over
 pooled keep-alive sockets, and a minimal Content-Length response
 parser. The engine server always sends ``Content-Length``
 (api/engine_server._respond), which is what makes the minimal parser

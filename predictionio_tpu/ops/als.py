@@ -470,13 +470,13 @@ def half_step_flops(
     measured — the direct factorization + two triangular solves
     (``K³/3 + 2K²``, i.e. the algorithmic minimum). Pass the same
     ``solver``/``cg_steps`` the measured run used, or MFU/padding_x
-    misattribute the solve cost (ADVICE r3). Executed work also
+    misattribute the solve cost. Executed work also
     replaces real entries with padded slab entries — for the chunked
     layout over every row (inactive rows solve the identity). The
     ratio ``executed / useful`` therefore carries BOTH the layout's
-    padding overhead and the solver-vs-minimum overhead (ADVICE r2:
-    a Cholesky-priced executed figure understates executed CG solve
-    FLOPs by ~4.5x at rank 32)."""
+    padding overhead and the solver-vs-minimum overhead (a
+    Cholesky-priced executed figure would understate the CG solve's
+    executed FLOPs)."""
     if solver not in ("cg", "cholesky"):
         raise ValueError(f"solver must be 'cg' or 'cholesky', got {solver!r}")
     k = float(rank)
@@ -917,11 +917,8 @@ def _solve_slabs(
     """Per-slab batched normal-equation solve; scan bounds peak memory.
 
     ``bf16=True`` feeds the normal-equation einsums bf16 operands with
-    f32 accumulation. Measured with the forcing protocol (bench.py
-    header) on one v5e-class chip, ML-20M shapes, rank 32, chunked
-    layout: 322ms vs 393ms per iteration (~22% faster; a round-1 claim
-    that bf16 was slower came from the broken timing protocol and is
-    retracted). Factor tables diverge ~5e-3 relative from the f32 path
+    f32 accumulation (its speed against f32 is not measured on today's
+    code). Factor tables diverge ~5e-3 relative from the f32 path
     after 10 iterations — inside quality-parity tolerances but not
     bit-comparable, so f32-HIGHEST stays the default. The solve and
     regularisation stay f32. Opt in via

@@ -1,10 +1,9 @@
 """ANN maximum-inner-product retrieval: IVF-flat index + exact rescore.
 
 The serving paths in ops/topk score the FULL item table per query —
-O(catalog) forever, already past its cache cliff at 100k items on the
-bench host and hopeless at the million-item north star. This module
-adds the classic sublinear alternative (FAISS-style IVF-flat, the
-survey's "shortlist then rescore" shape):
+O(catalog) forever. This module adds the classic sublinear
+alternative (FAISS-style IVF-flat, the survey's "shortlist then
+rescore" shape):
 
 - **build** (train/persist time, host-side numpy): k-means over the
   item-factor table partitions the catalog into ``nlist`` cells; the
@@ -589,8 +588,7 @@ def ann_similar_topk(
 
 
 # ---------------------------------------------------------------------------
-# quality measurement (shared by tests/test_ann.py and bench_serving.py:
-# recall/MAP numbers in the artifact come from the same code the tests pin)
+# quality measurement (the recall/MAP code tests/test_ann.py pins)
 # ---------------------------------------------------------------------------
 
 

@@ -38,9 +38,7 @@ S        XLA (ms)    pallas (ms) [tiles]   win
 The win grows with S: the kernel's HBM traffic is O(S * D) per query
 tile against the materialized formulation's O(S^2) logits, plus the
 causal skip XLA's fused softmax cannot apply. Numerics vs XLA:
-max|diff| ~2-3e-4 (online vs materialized softmax). The bench tracks
-``flash_s4096_ms``/``xla_s4096_ms`` so a regression re-flips the
-dispatch decision on data.
+max|diff| ~2-3e-4 (online vs materialized softmax).
 
 **Auto-dispatch:** CAUSAL attention on a compiled TPU backend at
 2048 <= S <= 16384 with K/V blocks of at most 2 MiB each (the skip

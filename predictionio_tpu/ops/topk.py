@@ -221,10 +221,11 @@ def serving_k(k: int, n_max: int) -> int:
             return min(cap, n_max)
     return min(1 << (max(k, 2) - 1).bit_length(), n_max)
 
-#: catalog/batch envelope where the chunked-scan formulation beats the
-#: flat materialize+top_k (measured with the forcing protocol:
-#: B=256 x I=2M, chunked 73ms vs flat 141ms; at B=32 x I=1M the flat
-#: path wins, 8ms vs ~1ms-level noise either way)
+#: catalog/batch envelope where the chunked-scan formulation is taken
+#: over the flat materialize+top_k: large catalogs with batched
+#: queries, where the flat path's (B, I) score matrix dominates. The
+#: thresholds date from before the chip; no cell dispatches a batch
+#: this wide, so they are not measured on today's code (PERF.md §7)
 _MIN_ITEMS = 786_432
 _MIN_BATCH = 24
 
@@ -262,34 +263,6 @@ def _chunked_wins(allow, item_f, batch: int) -> bool:
         and batch >= _MIN_BATCH
 
 
-def recommend_topk_fused(
-    user_vecs: jax.Array,    # (B, K)
-    item_f: jax.Array,       # (I, K)
-    seen_cols: jax.Array,    # (B, S) int32, padded
-    seen_mask: jax.Array,    # (B, S) 1=real, 0=pad
-    allow: jax.Array,        # (I,) eligibility (0/1); (B, I) -> flat path
-    k: int,
-) -> tuple[jax.Array, jax.Array]:
-    """Top-k recommendation dispatcher: picks between the two XLA
-    formulations — flat materialize+top_k (:func:`recommend_topk`, best
-    for small catalogs and B=1 serving) and the chunked-scan merge
-    (:func:`recommend_topk_chunked`, O(B x chunk) memory, faster from
-    ~1M items with batched queries). Takes the query vectors;
-    :func:`recommend_topk_fused_rows` takes the user table and row
-    indices and makes the same choice.
-
-    A pallas streaming-select kernel used to sit behind this dispatch;
-    it was deleted after re-measurement with the forcing protocol
-    (bench.py header): 168ms vs the flat path's 8ms at B=32 x I=1M and
-    188ms vs the chunked path's 73ms at B=256 x I=2M — its per-tile VPU
-    selection loop loses to ``lax.top_k`` at every envelope point."""
-    if _chunked_wins(allow, item_f, user_vecs.shape[0]):
-        seen_cols, seen_mask = _trim_seen(seen_cols, seen_mask)
-        return recommend_topk_chunked(
-            user_vecs, item_f, seen_cols, seen_mask, allow, k)
-    return recommend_topk(user_vecs, item_f, seen_cols, seen_mask, allow, k)
-
-
 def recommend_topk_fused_rows(
     user_table: jax.Array,   # (U, K)
     uixs,                    # (B,) int32 rows of it; NumPy is fine
@@ -299,11 +272,13 @@ def recommend_topk_fused_rows(
     allow: jax.Array,        # (I,) or (B, I)
     k: int,
 ) -> tuple[jax.Array, jax.Array]:
-    """:func:`recommend_topk_fused` for callers that hold row indices,
-    not vectors: the same choice between flat and chunked, and the
-    chosen program gathers ``user_table[uixs]`` itself, so a dispatch
-    is ONE device launch (``recommend_topk_rows`` or
-    ``recommend_topk_chunked_rows``). ``jit`` uploads host index and
+    """Top-k recommendation dispatcher: picks between the two XLA
+    formulations — flat materialize+top_k (:func:`recommend_topk_rows`,
+    best for small catalogs and B=1 serving) and the chunked-scan merge
+    (:func:`recommend_topk_chunked_rows`, O(B x chunk) memory, taken
+    from ~1M items with batched queries). Callers hold row indices, not
+    vectors: the chosen program gathers ``user_table[uixs]`` itself, so
+    a dispatch is ONE device launch. ``jit`` uploads host index and
     seen arrays with the call; ``_trim_seen`` stays on the host."""
     if _chunked_wins(allow, item_f, uixs.shape[0]):
         seen_cols, seen_mask = _trim_seen(seen_cols, seen_mask)

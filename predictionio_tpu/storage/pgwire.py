@@ -208,7 +208,7 @@ class PGConnection:
         """Track ParameterStatus ('S') reports. quote_literal assumes
         standard_conforming_strings=on (doubled quotes, literal
         backslash); under =off backslashes in user data become escapes
-        — data corruption AND an injection vector (ADVICE r4) — so a
+        — data corruption AND an injection vector — so a
         server reporting off is rejected outright, at startup or on a
         mid-session SET."""
         parts = payload.split(b"\x00")
@@ -436,7 +436,7 @@ class PGConnection:
                     # per-statement result boundary: only a statement
                     # that produced a RowDescription contributes rows,
                     # so a trailing row-less statement yields [] rather
-                    # than an earlier SELECT's leftovers (ADVICE r4)
+                    # than an earlier SELECT's leftovers
                     last = rows if saw_rowdesc else []
                     rows, saw_rowdesc = [], False
                 elif tag == b"S":                      # ParameterStatus

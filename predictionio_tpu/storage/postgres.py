@@ -47,7 +47,7 @@ _OR_REPLACE = re.compile(
     re.IGNORECASE | re.DOTALL,
 )
 # explicit-id inserts into the SERIAL tables desync the sequence on
-# real PostgreSQL (a later auto-id insert then collides — ADVICE r4);
+# real PostgreSQL (a later auto-id insert then collides);
 # detect them so execute() can re-sync with setval on the same session
 _EXPLICIT_SERIAL_ID = re.compile(
     r"^\s*INSERT\s+INTO\s+(pio_meta_apps|pio_meta_channels)\s*\(\s*id\b",

@@ -128,9 +128,7 @@ def ratings_from_columns(cols, buy_rating: float):
     rows need a target entity (code compare against the batch's None
     code), ``rate`` events take their properties' ``rating`` (rows
     whose rating is missing/malformed are dropped — the row-path rule),
-    everything else is an implicit signal worth ``buy_rating``. Shared
-    by the DataSource and bench_ingest.py so the benchmark measures
-    exactly the code the train path runs."""
+    everything else is an implicit signal worth ``buy_rating``."""
     n = len(cols)
     if n == 0:
         return None

@@ -100,7 +100,7 @@ class TestBuild:
 
 
 # ---------------------------------------------------------------------------
-# quality parity vs brute force (the harness bench_serving reuses)
+# quality parity vs brute force
 # ---------------------------------------------------------------------------
 
 
@@ -110,7 +110,7 @@ class TestQualityParity:
         # probe FRACTION matches the large-catalog regime the index is
         # for (at 4096 the same default probes a thinner slice of the
         # clusters and lands ~0.97 — see the monotonicity test for that
-        # regime); the bench asserts the same thresholds at 100k and 1M
+        # regime)
         items = _factors(16384, seed=0)
         users = _factors(128, seed=1)
         idx = ann_ops.build_index(items, seed=0)

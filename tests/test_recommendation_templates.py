@@ -335,6 +335,66 @@ class TestTemplateEvaluations:
         assert len(result.engine_params_scores) == 2
 
 
+@pytest.mark.parametrize("backend", ["memory", "sqlite"])
+def test_columnar_rating_rule_equals_row_rule(backend, tmp_path):
+    """``ratings_from_columns`` over ``find_columnar`` batches yields
+    the triples a per-event loop over ``find`` yields: ``rate`` takes
+    its ``rating``, a missing or malformed one drops the row, every
+    other event is worth ``buy_rating``, no target no row."""
+    from predictionio_tpu.storage.base import EventFilter, StorageClientConfig
+    from predictionio_tpu.storage.memory import MemoryStorageClient
+    from predictionio_tpu.storage.sqlite import SQLiteStorageClient
+    from predictionio_tpu.templates.recommendation import ratings_from_columns
+
+    client = MemoryStorageClient() if backend == "memory" else \
+        SQLiteStorageClient(StorageClientConfig(
+            properties={"PATH": str(tmp_path / "scan.sqlite")}))
+    dao = client.events()
+    dao.init(1)
+    rng = np.random.default_rng(5)
+    events = []
+    for j in range(300):
+        user, item = f"u{rng.integers(20)}", f"i{rng.integers(30)}"
+        kind = rng.integers(6)
+        if kind == 0:
+            events.append(Event(event="$set", entity_type="user",
+                                entity_id=user,
+                                properties=DataMap({"segment": j % 7})))
+        elif kind == 1:
+            events.append(_event("rate", user, item))            # no rating
+        elif kind == 2:
+            events.append(_event("rate", user, item, {"rating": "n/a"}))
+        elif kind == 3:
+            events.append(_event("rate", user, item,
+                                 {"rating": float(rng.integers(1, 11)) / 2}))
+        else:
+            events.append(_event(("buy", "view")[kind - 4], user, item))
+    try:
+        for at in range(0, len(events), 50):
+            dao.insert_batch(events[at:at + 50], 1)
+        flt = EventFilter(entity_type="user")
+        want = []
+        for e in dao.find(1, None, flt):
+            if e.target_entity_id is None:
+                continue
+            rating = 4.0
+            if e.event == "rate":
+                try:
+                    rating = float(e.properties.get("rating"))
+                except (KeyError, TypeError, ValueError):
+                    continue
+            want.append((e.entity_id, e.target_entity_id, rating))
+        got = []
+        for cols in dao.find_columnar(1, None, flt, batch_size=64):
+            part = ratings_from_columns(cols, 4.0)
+            if part is not None:
+                got.extend(zip(part[0], part[1], part[2].tolist()))
+    finally:
+        client.close()
+    assert 100 < len(want) < 300
+    assert got == want
+
+
 def test_map_at_k_metric():
     """MAP@K math on hand-checked cases."""
     from predictionio_tpu.templates.recommendation import (

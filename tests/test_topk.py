@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from predictionio_tpu.ops.topk import (
     recommend_topk,
     recommend_topk_chunked,
-    recommend_topk_fused,
+    recommend_topk_fused_rows,
     similar_topk,
     topk_scores,
 )
@@ -59,8 +59,9 @@ def test_chunked_clamps_k_on_both_dispatch_arms():
 
 def test_fused_dispatcher_clamps_k():
     uv, itf, cols, mask, allow = _setup(2, 5)
-    vals, idxs = recommend_topk_fused(
-        np.asarray(uv), itf, np.asarray(cols), np.asarray(mask), allow, 40)
+    vals, idxs = recommend_topk_fused_rows(
+        uv, np.arange(2, dtype=np.int32), itf, np.asarray(cols),
+        np.asarray(mask), allow, 40)
     assert vals.shape == (2, 5)
 
 

@@ -1,9 +1,6 @@
-"""Top-k dispatch contract (ops/topk.recommend_topk_fused): flat
+"""Top-k dispatch contract (ops/topk.recommend_topk_fused_rows): flat
 materialize+top_k for small catalogs / B=1 serving, chunked-scan merge
-for big catalogs with batched queries. The pallas streaming-select
-kernel that used to sit behind this dispatch was deleted on
-measurement — ops/topk.recommend_topk_fused docstring records the
-numbers."""
+for big catalogs with batched queries."""
 
 import numpy as np
 import pytest
@@ -19,7 +16,6 @@ from predictionio_tpu.ops.topk import (
     recommend_topk,
     recommend_topk_chunked,
     recommend_topk_chunked_rows,
-    recommend_topk_fused,
     recommend_topk_fused_rows,
     recommend_topk_rows,
 )
@@ -37,7 +33,8 @@ def _setup(B, I, K=8, S=16, seed=0):
 
 def test_fused_matches_flat_small():
     uv, itf, cols, mask, allow = _setup(4, 200)
-    fv, fi = recommend_topk_fused(uv, itf, cols, mask, allow, 5)
+    fv, fi = recommend_topk_fused_rows(
+        uv, np.arange(4, dtype=np.int32), itf, cols, mask, allow, 5)
     rv, ri = recommend_topk(uv, itf, cols, mask, allow, 5)
     np.testing.assert_array_equal(np.asarray(fi), np.asarray(ri))
     np.testing.assert_allclose(np.asarray(fv), np.asarray(rv))
@@ -99,9 +96,9 @@ def test_row_taking_program_equals_vector_program(form, B, padded):
 
 @pytest.mark.parametrize("B,form", [(4, "flat"), (32, "chunked")])
 def test_fused_rows_makes_the_fused_choice(monkeypatch, B, form):
-    """The row-taking dispatcher picks flat or chunked from what
-    ``recommend_topk_fused`` looks at, trims the seen pad on the host
-    for the chunked program as it does, and answers the same."""
+    """The dispatcher picks flat or chunked from the catalog and the
+    batch, trims the seen pad on the host for the chunked program, and
+    answers what that program answers on ``table[uixs]``."""
     import predictionio_tpu.ops.topk as t
 
     monkeypatch.setattr(t, "_MIN_ITEMS", 100)
@@ -117,8 +114,12 @@ def test_fused_rows_makes_the_fused_choice(monkeypatch, B, form):
             calls.append((_name, a[4].shape)) or _fn(*a))
     got = t.recommend_topk_fused_rows(table, uixs, itf, wide_cols,
                                       wide_mask, allow, 10)
-    want = recommend_topk_fused(table[uixs], itf, wide_cols, wide_mask,
-                                allow, 10)
+    if form == "flat":
+        want = recommend_topk(table[uixs], itf, wide_cols, wide_mask,
+                              allow, 10)
+    else:
+        want = recommend_topk_chunked(
+            table[uixs], itf, *_trim_seen(wide_cols, wide_mask), allow, 10)
     assert calls == [("recommend_topk_rows", (B, 40)) if form == "flat"
                      else ("recommend_topk_chunked_rows", (B, 32))]
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
@@ -133,8 +134,8 @@ def test_fused_rows_makes_the_fused_choice(monkeypatch, B, form):
 @pytest.mark.parametrize("B", [1, 4, 16])
 def test_batch_topk_brute_equals_vectors_through_fused(B):
     """``ALSModel.batch_topk`` on the brute branch hands the table and
-    the indices to the row-taking dispatcher: what it answers is what
-    the eager gather + ``recommend_topk_fused`` answered."""
+    the indices to the dispatcher: what it answers is what the eager
+    gather + ``recommend_topk`` answers (5,000 items: the flat side)."""
     from predictionio_tpu.models.als import ALSModel
     from predictionio_tpu.utils.bimap import EntityIdIxMap
 
@@ -147,7 +148,7 @@ def test_batch_topk_brute_equals_vectors_through_fused(B):
             [f"i{i}" for i in range(itf.shape[0])]),
         seen_by_user={})
     got = model.batch_topk(uixs, cols, mask, None, 10)
-    want = recommend_topk_fused(
+    want = recommend_topk(
         table[uixs], itf, cols, mask,
         jnp.ones((itf.shape[0],), jnp.float32), 10)
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
@@ -180,24 +181,25 @@ def test_trim_seen_picks_menu_width():
 
 
 def test_dispatch_threshold_uses_chunked(monkeypatch):
-    """Above the measured envelope the fused entry must route to the
-    chunked path (checked by stubbing, not by allocating 1M items)."""
+    """Above the envelope the dispatcher must route to the chunked
+    program (checked by stubbing, not by allocating 1M items)."""
     import predictionio_tpu.ops.topk as t
 
     calls = []
     monkeypatch.setattr(
-        t, "recommend_topk_chunked",
-        lambda *a, **kw: calls.append("chunked") or t.recommend_topk(*a[:5], a[5]),
+        t, "recommend_topk_chunked_rows",
+        lambda *a: calls.append("chunked") or t.recommend_topk_rows(*a),
     )
     monkeypatch.setattr(t, "_MIN_ITEMS", 100)
     monkeypatch.setattr(t, "_MIN_BATCH", 2)
     uv, itf, cols, mask, allow = _setup(4, 200)
-    t.recommend_topk_fused(uv, itf, cols, mask, allow, 5)
+    uixs = np.arange(4, dtype=np.int32)
+    t.recommend_topk_fused_rows(uv, uixs, itf, cols, mask, allow, 5)
     assert calls == ["chunked"]
     # 2-D allow (per-query business rules) must stay on the flat path
     calls.clear()
     allow2 = jnp.ones((4, 200), jnp.float32)
-    t.recommend_topk_fused(uv, itf, cols, mask, allow2, 5)
+    t.recommend_topk_fused_rows(uv, uixs, itf, cols, mask, allow2, 5)
     assert calls == []
 
 
