@@ -18,7 +18,10 @@ process at a time, so at no moment do two of them hold JAX):
    concurrent queries, ``GET /metrics``, ``pio undeploy``;
 5. ``flash_attention`` compiled (never interpreted) against
    ``full_attention`` at both ends of the envelope the dispatcher
-   declares, at the sessionrec serving shape.
+   declares, at the sessionrec serving shape; retention's fused state
+   pass against the ``jax.numpy`` step; the flat top-k's two-stage
+   selection against one ``lax.top_k`` at the Books cell's shape and
+   over a catalog 100 items longer.
 
 It passes only on a TPU: any other platform, any failed child or any
 malformed answer is a non-zero exit with the child's last lines, and no
@@ -168,7 +171,7 @@ def kernels_child() -> int:
         ok &= good
         print(f"flash S={s}: max|diff|={err:.3e} finite={finite} "
               f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
-    return 0 if ok and retention_kernel_ok() else 1
+    return 0 if ok and retention_kernel_ok() and two_stage_topk_ok() else 1
 
 
 def retention_kernel_ok() -> bool:
@@ -211,6 +214,64 @@ def retention_kernel_ok() -> bool:
         print(f"retention {name} S={s} H={h} G={g} chunk={chunk}: "
               f"max|diff|={err:.3e} first_call_s={t1 - t0:.2f} "
               f"{'ok' if good else 'MISMATCH'}")
+    return ok
+
+
+def two_stage_topk_ok() -> bool:
+    """The flat path's two-stage selection at the Books cell's shape
+    (4.4M items, rank 128, ``num`` 10) through ``ALSModel.batch_topk``
+    at B = 1, 4, 8, and at B = 4 over a catalog 100 items longer (a
+    tail short of a group): answers equal one ``lax.top_k`` over the
+    same masked scores on every finite slot, and the model's observer
+    counts every dispatch (the rule the counter and the program
+    share)."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.als import ALSModel
+    from predictionio_tpu.ops import topk
+    from predictionio_tpu.utils.bimap import EntityIdIxMap
+
+    rank, users, k = 128, 64, 10
+    # the program this repo ran before the two-stage selection
+    single = jax.jit(lambda table, rows, *a: jax.lax.top_k(
+        topk._masked_scores(table[rows], *a), k))
+    rng = np.random.default_rng(30)
+    ok = True
+    for items, batches in ((4_400_000, (1, 4, 8)), (4_400_100, (4,))):
+        ku, ki = jax.random.split(jax.random.PRNGKey(30))
+        model = ALSModel(
+            rank=rank,
+            user_factors=jax.random.normal(ku, (users, rank), jnp.float32),
+            item_factors=jax.random.normal(ki, (items, rank), jnp.float32),
+            user_ids=EntityIdIxMap.from_ids([f"u{i}" for i in range(users)]),
+            item_ids=EntityIdIxMap.from_ids([]), seen_by_user={})
+        counted = []
+        model.set_topk_observer(lambda: counted.append(1))
+        allow = jnp.ones((items,), jnp.float32)
+        for n, b in enumerate(batches, start=1):
+            uixs = rng.integers(0, users, b).astype(np.int32)
+            cols = rng.integers(0, items, (b, 8)).astype(np.int32)
+            cols[:, 0] = items - 1                  # in the tail, where one is
+            mask = (rng.random((b, 8)) < 0.7).astype(np.float32)
+            t0 = time.perf_counter()
+            vals, idxs = (np.asarray(a) for a in
+                          model.batch_topk(uixs, cols, mask, None, k))
+            t1 = time.perf_counter()
+            want_v, want_i = (np.asarray(a) for a in single(
+                model.user_factors, uixs, model.item_factors, cols, mask,
+                allow))
+            finite = np.isfinite(want_v)
+            good = (np.array_equal(vals, want_v) and bool(finite.all())
+                    and np.array_equal(idxs[finite], want_i[finite])
+                    and len(counted) == n)
+            ok &= good
+            print(f"topk two-stage I={items} B={b}: groups of "
+                  f"{topk.two_stage_group_width(items, k)}, counted "
+                  f"{len(counted)} of {n} dispatches "
+                  f"first_call_s={t1 - t0:.2f} "
+                  f"{'ok' if good else 'MISMATCH'}")
+        del model, allow
     return ok
 
 
@@ -496,7 +557,7 @@ def main() -> int:
     # 5. kernels
     out, kern_s = run("kernels", me + ["_kernels"], 900)
     for line in out.splitlines():
-        if line.startswith(("flash ", "retention ")):
+        if line.startswith(("flash ", "retention ", "topk ")):
             log(line)
 
     # the rule of utils/accelerator, restated so that this count checks it

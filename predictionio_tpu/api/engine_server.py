@@ -570,6 +570,9 @@ class EngineService:
                 getattr(self.deployed, "models", ())):
             if hasattr(target, "set_ann_observer"):
                 target.set_ann_observer(self.serving_stats.record_ann)
+            if hasattr(target, "set_topk_observer"):
+                target.set_topk_observer(
+                    self.serving_stats.record_two_stage_topk)
         # the session engine's models report programs and tokens the
         # same way (pio_serving_seq_* on /metrics, seq* on /stats.json)
         for model in getattr(self.deployed, "models", ()):

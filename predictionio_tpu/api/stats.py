@@ -45,7 +45,8 @@ class ServingStats:
     torn histogram and the lock-discipline lint needs no suppressions."""
 
     COUNTER_FIELDS = (
-        "dispatches", "batched_queries", "deduped", "expired",
+        "dispatches", "topk_two_stage_dispatches",
+        "batched_queries", "deduped", "expired",
         "cache_hits", "cache_misses", "cache_evictions",
         "cache_expirations", "cache_invalidations",
         "cache_user_invalidations",
@@ -101,6 +102,12 @@ class ServingStats:
             self._counts["ann_queries"] += queries
             self._counts["ann_rescored"] += shortlist_width * queries
             self._ann_hist[shortlist_width] += queries
+
+    def record_two_stage_topk(self) -> None:
+        """One brute-force top-k dispatch whose program selected in two
+        stages (group maxima, then the winning groups): the ALSModel
+        observer hook, models/als.set_topk_observer."""
+        self.bump("topk_two_stage_dispatches")
 
     def record_seq_dispatch(self, programs: int, tokens: int,
                             padded_tokens: int, split: int,

@@ -239,6 +239,11 @@ class ALSModel:
     #: installs to count ANN dispatches (api/stats.ServingStats)
     _ann_observer: object = dataclasses.field(default=None, repr=False,
                                               compare=False)
+    #: optional callable() the serving layer installs to count the
+    #: brute dispatches whose program selects its top k in two stages
+    #: (api/stats.ServingStats.record_two_stage_topk)
+    _topk_observer: object = dataclasses.field(default=None, repr=False,
+                                               compare=False)
     #: real-time freshness overlay (online/overlay.OnlineOverlay),
     #: installed by the fold-in service under ``pio deploy --online``
     #: — per-user vector deltas + brand-new-item vectors consulted by
@@ -252,6 +257,7 @@ class ALSModel:
         state["_default_allow"] = None
         # the observer is serving wiring (holds the stats lock), not model
         state["_ann_observer"] = None
+        state["_topk_observer"] = None
         state["online_overlay"] = None
         return state
 
@@ -327,6 +333,11 @@ class ALSModel:
         ``ServingStats.record_ann``) without re-running retrieval
         configuration."""
         self._ann_observer = observer
+
+    def set_topk_observer(self, observer) -> None:
+        """Install the serving layer's counter of two-stage top-k
+        dispatches (callable() — ``ServingStats.record_two_stage_topk``)."""
+        self._topk_observer = observer
 
     @property
     def ann_enabled(self) -> bool:
@@ -635,6 +646,9 @@ class ALSModel:
                     jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
                     jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
                     allow_v, k, mesh)
+            if self._topk_observer is not None and topk_ops.selects_two_stage(
+                    allow_v, self.item_factors, uixs.shape[0], k):
+                self._topk_observer()
             return topk_ops.recommend_topk_fused_rows(
                 self.user_factors, uixs, self.item_factors,
                 # NumPy stays NumPy on purpose: the dispatcher's host-side

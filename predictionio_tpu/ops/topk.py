@@ -4,14 +4,20 @@ Replaces the reference templates' per-user `recommendProducts` /
 item-score sort over RDDs (reference: tests/pio_tests/engines/
 recommendation-engine/src/main/scala/ALSAlgorithm.scala:90-120 and
 examples/scala-parallel-similarproduct/.../ALSAlgorithm.scala cosine
-ranking). One matmul (queries × item-factor table) feeds
-``jax.lax.top_k`` — MXU for the scores, fused masking for seen/business
--rule filters, no per-query host loops.
+ranking). One matmul (queries × item-factor table) feeds the
+selection — MXU for the scores, fused masking for seen/business-rule
+filters, no per-query host loops. The flat path (``recommend_topk``)
+selects in two exact stages where the catalog is large against ``k``
+(``two_stage_group_width``: the maximum of each group of contiguous
+columns, then ``jax.lax.top_k`` over the ``k`` winning groups only) and
+with one ``jax.lax.top_k`` otherwise; the chunked scan's merge, the
+sharded merge and ``similar_topk`` keep the single call.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from functools import partial
 
 import jax
@@ -51,14 +57,166 @@ def recommend_topk(
     vector; seen items are masked via scatter so padding slots (mask=0)
     leave scores untouched. ``k`` clamps to the catalog size
     (``topk_scores`` contract).
+
+    The selection is a pure function of the static shapes: the single
+    ``lax.top_k`` for small catalogs and wide ``k``, else the exact
+    two-stage selection (:func:`_two_stage_topk`) over the whole groups
+    of the same masked scores, the columns short of a last group scored
+    apart as extra candidates. On a v5e at 4.4M items and ``k`` = 10
+    the selection is 0.1-0.6 ms of a 3.1-4.1 ms program where the
+    single call was 0.3-2.7 of 3.3-6.1 (PERF.md §5, PR 30).
     """
+    items = item_f.shape[0]
+    k = min(k, items)
+    width = two_stage_group_width(items, k)
+    if not width:
+        return jax.lax.top_k(
+            _masked_scores(user_vecs, item_f, seen_cols, seen_mask, allow), k)
+    b = user_vecs.shape[0]
+    if b > 1 and b % _SUBLANES:
+        # whole blocks of 8 query rows: the (8, 128)-tiled score matrix
+        # is then laid out by group as it stands, where XLA copies 2 to
+        # 7 rows into that tiling first, in a kernel that takes 12-20 s
+        # to compile (PERF.md §5, PR 30). Padding rows see nothing and
+        # are dropped after stage 1
+        user_vecs, seen_cols, seen_mask = (
+            _pad_rows(x, -b % _SUBLANES)
+            for x in (user_vecs, seen_cols, seen_mask))
+        if allow.ndim == 2:
+            allow = _pad_rows(allow, -b % _SUBLANES)
+    whole = items // width * width
+    # the matmul reads a row slice of the table in place (a slice under
+    # a reshape of the table would be copied, 1.1 GB a program)
+    return _two_stage_topk(
+        _grouped_scores(user_vecs, item_f[:whole], seen_cols, seen_mask,
+                        allow[..., :whole], width),
+        _tail_scores(user_vecs, item_f[whole:], seen_cols, seen_mask,
+                     allow[..., whole:], whole)[:b], k)
+
+
+def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
+    """``x`` with ``rows`` rows of zeros after its last."""
+    return jax.lax.pad(x, np.zeros((), x.dtype),
+                       ((0, rows, 0),) + ((0, 0, 0),) * (x.ndim - 1))
+
+
+def _masked_scores(user_vecs, item_f, seen_cols, seen_mask, allow):
+    """The (B, I) score matrix with ineligible and seen items at -inf."""
     scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)          # MXU
     scores = jnp.where(allow > 0, scores, NEG_INF)
-    b = scores.shape[0]
-    rows = jnp.broadcast_to(jnp.arange(b)[:, None], seen_cols.shape)
-    hide = jnp.where(seen_mask > 0, NEG_INF, jnp.float32(jnp.inf))
-    scores = scores.at[rows, seen_cols].min(hide)
-    return jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    rows = jnp.broadcast_to(
+        jnp.arange(seen_cols.shape[0])[:, None], seen_cols.shape)
+    return scores.at[rows, seen_cols].min(_hide(seen_mask))
+
+
+def _hide(seen_mask):
+    """What to ``min`` into the score of each seen slot: -inf for a
+    real one, +inf (a no-op) for padding."""
+    return jnp.where(seen_mask > 0, NEG_INF, jnp.float32(jnp.inf))
+
+
+def _grouped_scores(user_vecs, item_f, seen_cols, seen_mask, allow, width):
+    """:func:`_masked_scores` of a table of whole groups, viewed as
+    (blocks of 8 rows, row, group, column): a bitcast of the tiled
+    matrix for 1 row and for whole blocks. The seen items are scattered
+    into the view: on the (B, I) matrix XLA runs wide scatters as
+    serial loops over the whole array (7.5 ms at B = 8 and seen width
+    128, PERF.md §5), on the view it does not. A seen item past the
+    last whole group is out of bounds here and dropped:
+    :func:`_tail_scores` hides it."""
+    b = user_vecs.shape[0]
+    scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)          # MXU
+    scores = jnp.where(allow > 0, scores, NEG_INF)
+    lanes = _SUBLANES if b % _SUBLANES == 0 else b
+    view = scores.reshape(b // lanes, lanes, item_f.shape[0] // width, width)
+    rows = np.broadcast_to(
+        np.arange(b, dtype=np.int32)[:, None], seen_cols.shape)
+    # lax.div / lax.rem: item indices are never negative, and jnp's
+    # floor division traces five times the operations
+    w = np.asarray(width, seen_cols.dtype)
+    return view.at[
+        rows // lanes, rows % lanes,
+        jax.lax.div(seen_cols, w), jax.lax.rem(seen_cols, w)
+    ].min(_hide(seen_mask), mode="drop")
+
+
+def _tail_scores(user_vecs, item_f, seen_cols, seen_mask, allow, start):
+    """:func:`_masked_scores` of the fewer-than-a-group rows that follow
+    row ``start`` of the table (none where the catalog is whole
+    groups). Seen items are found by comparison, (B, S, tail) being
+    small."""
+    scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)
+    ids = start + jnp.arange(item_f.shape[0], dtype=seen_cols.dtype)
+    seen = (seen_cols[:, :, None] == ids) & (seen_mask[:, :, None] > 0)
+    return jnp.where((allow > 0) & ~seen.any(axis=1), scores, NEG_INF)
+
+
+#: a group of the two-stage selection is a whole number of 128-lane
+#: tiles, so a group never straddles a tile of the score matrix
+_LANES = 128
+#: rows of a tile of the score matrix
+_SUBLANES = 8
+#: two stages are taken where the groups outnumber k this many times:
+#: the k picked groups are then a small part of the catalog
+_MIN_GROUPS_PER_K = 8
+#: and up to this k, the widest the tests and the chip's exactness
+#: check cover: at 4.4M items k = 320 and 1000 measured faster in two
+#: stages too, but XLA sorts there instead of calling TopK and the
+#: indices of tied scores came back in another order (PERF.md §7)
+_MAX_TWO_STAGE_K = 100
+
+
+def two_stage_group_width(items: int, k: int) -> int:
+    """Group width of the exact two-stage selection of ``k`` out of
+    ``items`` scores, or 0 where the single ``lax.top_k`` is taken. A
+    pure function of the static shapes: the width nearest
+    sqrt(items / k) in whole lane tiles balances the two small top-ks
+    (``items / width`` group maxima against ``k * width`` candidates)."""
+    if not 1 <= k <= _MAX_TWO_STAGE_K:
+        return 0
+    width = max(1, round(math.sqrt(items / k) / _LANES)) * _LANES
+    return width if items // width >= _MIN_GROUPS_PER_K * k else 0
+
+
+def _two_stage_topk(view: jax.Array, tail: jax.Array, k: int
+                    ) -> tuple[jax.Array, jax.Array]:
+    """``lax.top_k`` over rows of scores in two exact stages. ``view``
+    (:func:`_grouped_scores`) holds the whole groups of each row, maybe
+    rows of padding after them; ``tail`` (rows, fewer than width, maybe
+    none) the columns past the last group, and says how many rows are
+    real. Stage 1 takes the maximum of each group; stage 2 looks only
+    at the ``k`` groups whose maxima win, and at the tail. An item
+    among the top ``k`` cannot lie in a group whose maximum ``k`` other
+    groups' maxima beat; with the picked groups in index order
+    ``lax.top_k``'s lowest-index rule for ties carries over, so values
+    and indices equal the single call's on every finite slot."""
+    _, lanes, groups, width = view.shape
+    b = tail.shape[0]
+    _, picked = jax.lax.top_k(view.max(axis=-1).reshape(-1, groups)[:b], k)
+    picked = jnp.sort(picked, axis=-1)
+    row = np.broadcast_to(np.arange(b, dtype=np.int32)[:, None], (b, k))
+    cand = _take(view, row // lanes, row % lanes, picked)
+    cand = jnp.concatenate([cand.reshape(b, k * width), tail], axis=1)
+    vals, sel = jax.lax.top_k(cand, k)
+    w = np.int32(width)
+    # the candidate's slot among the picked groups (a tail candidate
+    # clamps to the last one and is overridden below)
+    slot = jnp.minimum(jax.lax.div(sel, w), k - 1)
+    item = _take(picked, row, slot) * w + jax.lax.rem(sel, w)
+    return vals, jnp.where(sel < k * width, item, sel + (groups - k) * width)
+
+
+def _take(x: jax.Array, *index) -> jax.Array:
+    """``x[index[0], index[1], ...]`` for index arrays of one shape.
+    ``jnp`` indexing and ``jnp.take_along_axis`` say the same and cost
+    twice the time to trace and lower, which every serving signature
+    pays once a process (``setup_s``: PERF.md §6, PR 30)."""
+    n, at = len(index), index[-1].ndim
+    dims = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(at, at + x.ndim - n)),
+        collapsed_slice_dims=tuple(range(n)), start_index_map=tuple(range(n)))
+    return jax.lax.gather(x, jnp.stack(index, axis=-1), dims,
+                          (1,) * n + x.shape[n:], mode="promise_in_bounds")
 
 
 @partial(instrumented_jit, static_argnames=("k",))
@@ -100,9 +258,12 @@ def recommend_topk_chunked(
     sentinel indices (>= I, never colliding with a real pick) — callers
     must treat non-finite slots as absent, which both in-repo consumers
     (ALSModel._gather_results, batch_predict) already do. Restricted to
-    1-D ``allow``; measured 1.6-2.5x faster than the flat path from
-    ~1M items with batched queries (peak memory O(B x chunk)); the
-    flat path stays better for small catalogs and B=1 serving."""
+    1-D ``allow``; peak memory O(B x chunk). The dispatcher takes it
+    from 24 queries a batch at ~786K items up, an envelope that dates
+    from before the chip: on a v5e at 4.4M items the flat path with
+    its two-stage selection runs B=16 in 4.1 ms where this scan takes
+    13.2 ms at B=32 (PERF.md §5), so whether the envelope should move
+    up is open (no cell dispatches a batch that wide, PERF.md §7)."""
     B = user_vecs.shape[0]
     I = item_f.shape[0]
     k = min(k, I)                   # the shared clamp-not-assert contract
@@ -263,6 +424,15 @@ def _chunked_wins(allow, item_f, batch: int) -> bool:
         and batch >= _MIN_BATCH
 
 
+def selects_two_stage(allow, item_f, batch: int, k: int) -> bool:
+    """Whether :func:`recommend_topk_fused_rows` at these shapes runs
+    the flat program with the two-stage selection: the rule the program
+    itself applies, for the serving layer's counter."""
+    items = item_f.shape[0]
+    return not _chunked_wins(allow, item_f, batch) \
+        and two_stage_group_width(items, min(k, items)) > 0
+
+
 def recommend_topk_fused_rows(
     user_table: jax.Array,   # (U, K)
     uixs,                    # (B,) int32 rows of it; NumPy is fine
@@ -273,8 +443,8 @@ def recommend_topk_fused_rows(
     k: int,
 ) -> tuple[jax.Array, jax.Array]:
     """Top-k recommendation dispatcher: picks between the two XLA
-    formulations — flat materialize+top_k (:func:`recommend_topk_rows`,
-    best for small catalogs and B=1 serving) and the chunked-scan merge
+    formulations — flat materialize+select (:func:`recommend_topk_rows`:
+    every batch the serving cells dispatch) and the chunked-scan merge
     (:func:`recommend_topk_chunked_rows`, O(B x chunk) memory, taken
     from ~1M items with batched queries). Callers hold row indices, not
     vectors: the chosen program gathers ``user_table[uixs]`` itself, so

@@ -275,6 +275,8 @@ def test_stats_and_metrics_show_the_counter_at_zero_on_the_cpu(engine_model):
             serving = json.loads(resp.read())["serving"]
         assert serving["seqPrograms"] == 1
         assert serving["seqFusedRetentionPrograms"] == 0
+        # no ALS top-k runs behind the session engine (PR 30's counter)
+        assert serving["topkTwoStageDispatches"] == 0
         with urllib.request.urlopen(f"{base}/metrics") as resp:
             assert b"pio_serving_seq_fused_retention_programs_total 0" \
                 in resp.read()
