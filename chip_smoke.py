@@ -21,7 +21,9 @@ process at a time, so at no moment do two of them hold JAX):
    declares, at the sessionrec serving shape; retention's fused state
    pass against the ``jax.numpy`` step; the flat top-k's two-stage
    selection against one ``lax.top_k`` at the Books cell's shape and
-   over a catalog 100 items longer.
+   over a catalog 100 items longer; the ``deepseek_v2`` kind's tiled
+   attention core (S 8,192, 128 heads, 192/128) and grouped product (40
+   experts x 307 rows) against their plain forms.
 
 It passes only on a TPU: any other platform, any failed child or any
 malformed answer is a non-zero exit with the child's last lines, and no
@@ -171,7 +173,67 @@ def kernels_child() -> int:
         ok &= good
         print(f"flash S={s}: max|diff|={err:.3e} finite={finite} "
               f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
-    return 0 if ok and retention_kernel_ok() and two_stage_topk_ok() else 1
+    return 0 if (ok and retention_kernel_ok() and two_stage_topk_ok()
+                 and mla_kernels_ok()) else 1
+
+
+def mla_kernels_ok() -> bool:
+    """The ``deepseek_v2`` kind's two kernels at its cell's shape, each
+    against its plain form: the tiled attention core (S 8,192, 128 heads,
+    query/key 128 + 64, value 128; 8 of the heads are compared, the plain
+    form's logits for all would not fit) and the grouped product at 40
+    experts x 307 rows inside the 49,152-row bound."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import mla_attention as mla, moe
+
+    s, heads, dn, dr, dv = 8192, 128, 128, 64, 128
+    if not (mla.uses_kernel(s, True, dn, dr, dv, heads)
+            and moe.uses_kernel(inference=True)):
+        print("mla: the rules do not choose the kernels")
+        return False
+    keys = jax.random.split(jax.random.PRNGKey(s), 8)
+
+    def draw(key, *shape):
+        return jax.random.normal(key, shape, jnp.bfloat16)
+
+    qn, qp = draw(keys[0], 1, s, heads * dn), draw(keys[1], 1, s, heads * dr)
+    kv, kp = draw(keys[2], 1, s, heads * (dn + dv)), draw(keys[3], 1, s, dr)
+    scale = mla.softmax_scale(dn + dr)
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(mla.attend(
+        qn, qp, kv, kp, heads=heads, dn=dn, dv=dv, scale=scale,
+        inference=True))
+    t1 = time.perf_counter()
+    some = 8                    # the plain form's logits for all would not fit
+    want = mla.attend(qn[..., :some * dn], qp[..., :some * dr],
+                      kv[..., :some * (dn + dv)], kp, heads=some, dn=dn,
+                      dv=dv, scale=scale, inference=False)
+    got32 = got[..., :some * dv].astype(jnp.float32)
+    err = float(jnp.max(jnp.abs(got32 - want.astype(jnp.float32))))
+    ok = bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))) and \
+        err <= FLASH_TOL * 4
+    print(f"mla attention S={s} H={heads} 192/128: max|diff|={err:.3e} "
+          f"first_call_s={t1 - t0:.2f} {'ok' if ok else 'MISMATCH'}")
+    del qn, qp, kv, kp, got, want
+
+    rows, experts = 49152, 40
+    x = draw(keys[5], rows, 5120)
+    w = draw(keys[6], experts, 5120, 1536) * 0.02
+    sizes = jnp.full((experts,), 307, jnp.int32)
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(
+        moe._grouped(x, w, sizes, "compiled", jnp.float32))[:experts * 307]
+    t1 = time.perf_counter()
+    want = jax.lax.ragged_dot(x[:experts * 307], w, sizes,
+                              preferred_element_type=jnp.float32)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    good = err < 1e-2
+    print(f"mla grouped product {experts} x 307 rows of {rows}: "
+          f"max rel diff={err:.3e} first_call_s={t1 - t0:.2f} "
+          f"{'ok' if good else 'MISMATCH'}")
+    return ok and good
 
 
 def retention_kernel_ok() -> bool:
@@ -557,7 +619,7 @@ def main() -> int:
     # 5. kernels
     out, kern_s = run("kernels", me + ["_kernels"], 900)
     for line in out.splitlines():
-        if line.startswith(("flash ", "retention ", "topk ")):
+        if line.startswith(("flash ", "retention ", "topk ", "mla ")):
             log(line)
 
     # the rule of utils/accelerator, restated so that this count checks it

@@ -54,6 +54,7 @@ class ServingStats:
         # the session engine's dispatches (templates/sessionrec)
         "seq_programs", "seq_tokens", "seq_padded_tokens",
         "seq_split_dispatches", "seq_fused_retention_programs",
+        "seq_moe_assignments", "seq_moe_tokens", "seq_moe_max_expert_load",
     )
 
     def __init__(self):
@@ -109,23 +110,17 @@ class ServingStats:
         observer hook, models/als.set_topk_observer."""
         self.bump("topk_two_stage_dispatches")
 
-    def record_seq_dispatch(self, programs: int, tokens: int,
-                            padded_tokens: int, split: int,
-                            fused_retention_programs: int = 0) -> None:
-        """One ``batch_predict`` of the session engine: the device
-        programs it launched, the events of the histories it scored,
-        the tokens the programs ran over (each history padded to
-        ``max_len``), whether the token budget split it into more
-        than one program, and how many of the programs ran retention's
-        state pass in the fused kernel (the SeqRecEngineModel observer
-        hook)."""
+    def record_seq_dispatch(self, report) -> None:
+        """One ``batch_predict`` of the session engine, as the record
+        ``templates/sessionrec.SeqDispatch`` (the SeqRecEngineModel
+        observer hook): each of its fields adds to the counter
+        ``seq_<field>`` (``split`` to ``seq_split_dispatches``)."""
         with self._lock:
-            self._counts["seq_programs"] += programs
-            self._counts["seq_tokens"] += tokens
-            self._counts["seq_padded_tokens"] += padded_tokens
-            self._counts["seq_split_dispatches"] += split
-            self._counts["seq_fused_retention_programs"] += \
-                fused_retention_programs
+            for field in ("programs", "tokens", "padded_tokens",
+                          "fused_retention_programs", "moe_assignments",
+                          "moe_tokens", "moe_max_expert_load"):
+                self._counts["seq_" + field] += getattr(report, field)
+            self._counts["seq_split_dispatches"] += report.split
 
     def ann_histogram(self) -> dict[int, int]:
         """Shortlist width -> query count, read under the lock."""

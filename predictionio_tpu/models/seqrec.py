@@ -10,7 +10,11 @@ kind in ``BLOCKS``. "sasrec" is the pre-LN multi-head block described
 below; "brumby" is the Brumby-14B-Base block (RMSNorm, grouped
 key/value heads with QK-norm and RoPE, gated power retention from
 ops/retention.py, SwiGLU, an untied head), whose plain float32
-reference is benchmarks/reference/brumby_jnp.py.
+reference is benchmarks/reference/brumby_jnp.py; "deepseek_v2" is the
+DeepSeek-V2 block (latent attention from ops/mla_attention.py, one
+leading dense SwiGLU layer, then routed + shared experts from
+ops/moe.py, of which this chip holds a share), whose reference is
+benchmarks/reference/deepseek_v2_jnp.py.
 
 TPU-first design:
 - matmuls run in bf16 on the MXU (params and softmax/LN statistics stay
@@ -50,6 +54,63 @@ PAD = 0  # item id 0 is reserved for padding; real ids start at 1
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """``rope_scaling`` of type "yarn", keys as published."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeWidths:
+    """What the "deepseek_v2" kind adds to the widths every kind has
+    (``d_model``, ``n_heads``, ``d_ff`` for the dense layers,
+    ``rope_theta``, ``rms_eps``): latent attention and routed + shared
+    experts, keys as published, defaults DeepSeek-V2's. One frozen,
+    hashable record, held in one field by ``SeqRecConfig`` and by the
+    session template's ``AlgorithmParams`` (``engine.json``:
+    ``"mla_moe": {...}``)."""
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    moe_intermediate_size: int = 1536
+    #: the router's width: the experts of the published layer
+    n_routed_experts: int = 160
+    #: (first, count): the experts this chip holds of them
+    experts_held: tuple = (0, 160)
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    routed_scaling_factor: float = 16.0
+    first_k_dense_replace: int = 1
+    rope_scaling: YarnScaling | None = None
+
+    @classmethod
+    def of(cls, obj) -> "MlaMoeWidths | None":
+        """The record from what ``engine.json`` carries (a JSON object,
+        ``rope_scaling`` an object whose ``type`` is "yarn"), from
+        itself, or None."""
+        if obj is None or isinstance(obj, cls):
+            return obj
+        obj = dict(obj)
+        yarn = obj.get("rope_scaling")
+        if isinstance(yarn, Mapping):
+            yarn = dict(yarn)
+            if yarn.pop("type", "yarn") != "yarn":
+                raise ValueError("rope_scaling: only type 'yarn' is known")
+            obj["rope_scaling"] = YarnScaling(**yarn)
+        if "experts_held" in obj:
+            obj["experts_held"] = tuple(int(n) for n in obj["experts_held"])
+        return cls(**obj)
+
+
+@dataclasses.dataclass(frozen=True)
 class SeqRecConfig:
     vocab: int              # number of items + 1 (pad)
     max_len: int = 64
@@ -57,7 +118,6 @@ class SeqRecConfig:
     n_heads: int = 2
     n_layers: int = 2
     mlp_mult: int = 4
-    dropout: float = 0.0    # kept for config parity; inference-free model
     dtype: Any = jnp.bfloat16
     #: rematerialize each transformer block under grad (jax.checkpoint):
     #: activations are recomputed in the backward pass instead of stored,
@@ -68,8 +128,10 @@ class SeqRecConfig:
     #: is the pre-LN multi-head block with a learned position table and
     #: a tied head; "brumby" is the Brumby-14B-Base block (RMSNorm,
     #: grouped key/value heads, QK-norm, RoPE, gated power retention,
-    #: SwiGLU). The fields below are the second kind's widths; 0 means
-    #: "as the first kind derives it"
+    #: SwiGLU); "deepseek_v2" is the DeepSeek-V2 block (latent
+    #: attention, a leading dense layer, then routed + shared experts),
+    #: whose own widths are ``mla_moe``. The fields below are widths the
+    #: later kinds read; 0 means "as the first kind derives it"
     block: str = "sasrec"
     n_kv_heads: int = 0         # 0: one key/value head per query head
     head_dim: int = 0           # 0: d_model // n_heads
@@ -84,6 +146,8 @@ class SeqRecConfig:
     #: the type serving holds the weights in on the device
     #: (templates/sessionrec._as_device_tree); training keeps float32
     param_dtype: Any = jnp.float32
+    #: the "deepseek_v2" kind's widths; None for the other kinds
+    mla_moe: MlaMoeWidths | None = None
 
     @property
     def kv_heads(self) -> int:
@@ -100,15 +164,22 @@ class SeqRecConfig:
 
 def activation_bytes_per_token(cfg: SeqRecConfig) -> int:
     """What one token of a serving program holds at the program's peak,
-    estimated from the widths: the residual stream twice and the widest
-    layer's two projections (SwiGLU's gate and up; twice the MLP's
-    hidden for SASRec), in cfg.dtype, and for SASRec one float32 row of
-    attention logits per head. The compiled brumby program at the
-    published widths holds 91 KB a token; this says 90."""
-    per = 2 * (cfg.d_model + cfg.ff) * jnp.dtype(cfg.dtype).itemsize
-    if cfg.block == "sasrec":
-        per += 4 * cfg.n_heads * cfg.max_len
-    return per
+    estimated from the widths by the kind's own rule
+    (``BlockKind.bytes_per_token``)."""
+    return BLOCKS[cfg.block].bytes_per_token(cfg)
+
+
+def _residual_and_ff_bytes(cfg: SeqRecConfig) -> int:
+    """The residual stream twice and the widest dense layer's two
+    projections (SwiGLU's gate and up; twice the MLP's hidden for
+    SASRec), in cfg.dtype. The compiled brumby program at the published
+    widths holds 91 KB a token; this says 90."""
+    return 2 * (cfg.d_model + cfg.ff) * jnp.dtype(cfg.dtype).itemsize
+
+
+def _bytes_sasrec(cfg: SeqRecConfig) -> int:
+    # and one float32 row of attention logits per head
+    return _residual_and_ff_bytes(cfg) + 4 * cfg.n_heads * cfg.max_len
 
 
 def init_params(key: jax.Array, cfg: SeqRecConfig,
@@ -191,6 +262,56 @@ def _init_brumby(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
     return params
 
 
+def _init_deepseek_v2(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
+    """As ``_init_brumby``: every matrix normal / sqrt(fan-in), norm
+    weights one. The first ``first_k_dense_replace`` layers carry a dense
+    SwiGLU of width ``cfg.ff``; the others a router over all published
+    experts, the ``experts_held`` count of routed experts (stacked), and
+    the shared experts as one SwiGLU."""
+    w = cfg.mla_moe
+    d, H = cfg.d_model, cfg.n_heads
+    dn, dr, dv = w.qk_nope_head_dim, w.qk_rope_head_dim, w.v_head_dim
+    held, ffe = w.experts_held[1], w.moe_intermediate_size
+    keys = jax.random.split(key, 2 + cfg.n_layers)
+
+    def dense(k, *shape):
+        return (jax.random.normal(k, shape, dtype=jnp.float32)
+                / math.sqrt(shape[-2])).astype(dtype)
+
+    def swiglu(ks, ff, *lead):
+        return {"w_gate": dense(ks[0], *lead, d, ff),
+                "w_up": dense(ks[1], *lead, d, ff),
+                "w_down": dense(ks[2], *lead, ff, d)}
+
+    def table(k):
+        return (jax.random.normal(k, (cfg.vocab, d), dtype=jnp.float32)
+                / math.sqrt(d)).astype(dtype)
+
+    params = {"item_emb": table(keys[0]), "head": table(keys[1]),
+              "out_norm": jnp.ones((d,), dtype), "layers": []}
+    for i in range(cfg.n_layers):
+        lk = jax.random.split(keys[2 + i], 15)
+        layer = {
+            "in_norm": jnp.ones((d,), dtype),
+            "post_norm": jnp.ones((d,), dtype),
+            "q_a_norm": jnp.ones((w.q_lora_rank,), dtype),
+            "kv_a_norm": jnp.ones((w.kv_lora_rank,), dtype),
+            "wq_a": dense(lk[0], d, w.q_lora_rank),
+            "wq_b": dense(lk[1], w.q_lora_rank, H * (dn + dr)),
+            "wkv_a": dense(lk[2], d, w.kv_lora_rank + dr),
+            "wkv_b": dense(lk[3], w.kv_lora_rank, H * (dn + dv)),
+            "wo": dense(lk[4], H * dv, d),
+        }
+        if i < w.first_k_dense_replace:
+            layer["ffn"] = swiglu(lk[5:8], cfg.ff)
+        else:
+            layer["router"] = dense(lk[8], d, w.n_routed_experts)
+            layer["experts"] = swiglu(lk[9:12], ffe, held)
+            layer["shared"] = swiglu(lk[12:15], w.n_shared_experts * ffe)
+        params["layers"].append(layer)
+    return params
+
+
 def _ln(x: jax.Array, g: jax.Array, b: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -207,8 +328,9 @@ def forward(
     inference: bool = False,
 ) -> jax.Array:
     """Hidden states (B, S, D) in cfg.dtype from ``cfg.block``'s stack."""
-    return BLOCKS[cfg.block].forward(params, seqs, cfg, mesh, seq_axis,
-                                     inference)
+    kind = BLOCKS[cfg.block]
+    out = kind.forward(params, seqs, cfg, mesh, seq_axis, inference)
+    return out[0] if kind.routed else out
 
 
 def _forward_sasrec(params, seqs, cfg, mesh, seq_axis, inference):
@@ -216,13 +338,12 @@ def _forward_sasrec(params, seqs, cfg, mesh, seq_axis, inference):
     attention over it.
 
     ``inference=True`` routes single-device attention through
-    ops/pallas_attention.flash_attention, which since the round-5
-    causal-KV-skip + tile-sweep pass auto-engages the pallas kernel
-    for causal 2048<=S<=16384 on a compiled TPU backend (measured
-    1.4-5.8x over XLA there; its module docstring has the A/B table)
-    and is XLA full attention otherwise. Serving stays a distinct
-    dispatch point from the differentiable training paths — the
-    kernel is forward-only."""
+    ops/pallas_attention.flash_attention, which engages its kernel for
+    causal 2048<=S<=16384 on a compiled TPU backend inside its VMEM
+    envelope (its module docstring has what the chip has shown) and is
+    XLA full attention otherwise. Serving stays a distinct dispatch
+    point from the differentiable training paths — the kernel is
+    forward-only."""
     B, S = seqs.shape
     d, H = cfg.d_model, cfg.n_heads
     hd = d // H
@@ -339,25 +460,170 @@ def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
     return _rms(x, params["out_norm"], cfg.rms_eps)
 
 
+def _swiglu(h, ffn, dt):
+    gate = (h @ ffn["w_gate"].astype(dt)).astype(jnp.float32)
+    up = (h @ ffn["w_up"].astype(dt)).astype(jnp.float32)
+    return (jax.nn.silu(gate) * up).astype(dt) @ ffn["w_down"].astype(dt)
+
+
+def _forward_deepseek_v2(params, seqs, cfg, mesh, seq_axis, inference):
+    """The DeepSeek-V2 stack: returns (hidden, assignments per expert
+    layer and held expert, int32). RoPE and causal mixing: right padding
+    needs no mask. The routed layer computes this chip's experts' part
+    and leaves the rest out (ops/moe.py); there is no expert axis on the
+    mesh and no exchange here."""
+    # Pallas and the grouped matmul are imported with the first program
+    # of this kind, not with the module
+    from predictionio_tpu.ops import mla_attention as mla, moe
+
+    if mesh is not None and seq_axis in mesh.shape and \
+            int(mesh.shape[seq_axis]) > 1:
+        raise NotImplementedError(
+            "the deepseek_v2 block has no sequence-parallel form: use a "
+            f"mesh without a {seq_axis!r} axis")
+    w = cfg.mla_moe
+    B, S = seqs.shape
+    H, dt, eps = cfg.n_heads, cfg.dtype, cfg.rms_eps
+    dn, dr, dv = w.qk_nope_head_dim, w.qk_rope_head_dim, w.v_head_dim
+    yarn = w.rope_scaling
+    inv_freq = mla.yarn_inv_freq(dr, cfg.rope_theta, yarn)
+    scale = mla.softmax_scale(dn + dr, yarn)
+    magnitude = 1.0 if yarn is None else (
+        mla.yarn_mscale(yarn.factor, yarn.mscale)
+        / mla.yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+
+    # the queries come out of their projection as all heads' nope
+    # slices, then all heads' rotary slices with the published pairs
+    # (2i, 2i+1) taken apart, then each rotary lane's partner: the
+    # weights' columns are reordered, not the activations
+    q_cols = mla.query_columns(H, dn, dr)
+    kv_cols = np.concatenate([
+        np.arange(w.kv_lora_rank), w.kv_lora_rank + mla.pairs_apart(dr),
+        w.kv_lora_rank + mla.halves_swapped(dr)])
+    pe = H * dn                                    # where q's rotary part starts
+
+    def attention(h, layer):
+        c_q = _rms(h @ layer["wq_a"].astype(dt), layer["q_a_norm"], eps)
+        q = c_q @ layer["wq_b"].astype(dt)[:, q_cols]
+        c_kv = h @ layer["wkv_a"].astype(dt)[:, kv_cols]
+        c, k_pe = c_kv[..., :w.kv_lora_rank], c_kv[..., w.kv_lora_rank:]
+        kv = _rms(c, layer["kv_a_norm"], eps) @ layer["wkv_b"].astype(dt)
+        q_pe = mla.rope_apart(q[..., pe:pe + H * dr], q[..., pe + H * dr:],
+                              inv_freq, dr, magnitude)
+        k_pe = mla.rope_apart(k_pe[..., :dr], k_pe[..., dr:], inv_freq, dr,
+                              magnitude)
+        att = mla.attend(q[..., :pe], q_pe, kv, k_pe, heads=H, dn=dn,
+                         dv=dv, scale=scale, inference=inference)
+        return att @ layer["wo"].astype(dt)
+
+    def experts(h, layer):
+        tokens = h.reshape(B * S, -1)
+        with jax.named_scope("routed_experts"):
+            ids, weights, _ = moe.route(
+                tokens, layer["router"], n_group=w.n_group,
+                topk_group=w.topk_group, top_k=w.num_experts_per_tok,
+                scaling=w.routed_scaling_factor)
+            ex = layer["experts"]
+            routed, counts = moe.routed_experts(
+                tokens, ids, weights, ex["w_gate"], ex["w_up"],
+                ex["w_down"], w.experts_held[0], inference=inference)
+        with jax.named_scope("shared_experts"):
+            shared = _swiglu(h, layer["shared"], dt)
+        y = routed.reshape(B, S, -1) + shared.astype(jnp.float32)
+        return y.astype(dt), counts
+
+    def block(x, layer):
+        with jax.named_scope("mla_attention"):
+            x = x + attention(_rms(x, layer["in_norm"], eps), layer)
+        h = _rms(x, layer["post_norm"], eps)
+        if "ffn" in layer:
+            with jax.named_scope("swiglu"):
+                return x + _swiglu(h, layer["ffn"], dt), None
+        y, counts = experts(h, layer)
+        return x + y, counts
+
+    if cfg.remat:
+        block = jax.checkpoint(block)
+    x = params["item_emb"][seqs].astype(dt)
+    per_layer = []
+    for layer in params["layers"]:
+        x, counts = block(x, layer)
+        if counts is not None:
+            per_layer.append(counts)
+    assignments = jnp.stack(per_layer) if per_layer else \
+        jnp.zeros((0, w.experts_held[1]), jnp.int32)
+    return _rms(x, params["out_norm"], eps), assignments
+
+
+def _bytes_deepseek_v2(cfg: SeqRecConfig) -> int:
+    """The two phases a layer goes through, in cfg.dtype beside the
+    residual stream twice: attention (q, the joint k/v projection and
+    the output, each with a temporary of its size: the rotary slices'
+    partners and their float32 rotation) and the routed layer (per
+    assignment the gathered row, gate, up and hidden, the expert's
+    output and its copy in token order). The sum, not the larger: the
+    compiled program makes the routed layer's arrays while attention's
+    are live. At the published widths it holds 460 KB a token (3.77 GB
+    of temporaries at S = 8,192 beside 10.33 GB of weights, compiled for
+    a described v5e); this says 555."""
+    w, it = cfg.mla_moe, jnp.dtype(cfg.dtype).itemsize
+    qk = w.qk_nope_head_dim + w.qk_rope_head_dim
+    attn = cfg.n_heads * (2 * qk + 2 * (w.qk_nope_head_dim + w.v_head_dim)
+                          + 2 * w.v_head_dim)
+    routed = w.num_experts_per_tok * (3 * cfg.d_model
+                                      + 3 * w.moe_intermediate_size)
+    return (2 * cfg.d_model + max(attn + routed, 2 * cfg.ff)) * it
+
+
+def _kernels_brumby(cfg: SeqRecConfig, seq_len: int) -> tuple:
+    fused = fuses_state_pass(cfg.hd, cfg.n_heads // cfg.kv_heads,
+                             pick_chunk(seq_len), inference=True)
+    return ("retention_state_pass",) if fused else ()
+
+
+def _kernels_deepseek_v2(cfg: SeqRecConfig, seq_len: int) -> tuple:
+    from predictionio_tpu.ops import mla_attention, moe
+
+    w = cfg.mla_moe
+    return (("mla_flash_attention",) if mla_attention.uses_kernel(
+        seq_len, True, w.qk_nope_head_dim, w.qk_rope_head_dim, w.v_head_dim,
+        cfg.n_heads) else ()) \
+        + (("gmm",) if moe.uses_kernel(inference=True) else ())
+
+
 def fuses_retention(cfg: SeqRecConfig, seq_len: int) -> bool:
     """Whether a serving program over ``seq_len``-long histories
     (:func:`predict_topk_batch`, ``inference=True``) runs retention's
-    state pass in the fused kernel: ``_forward_brumby``'s own rule, for
-    the counters of whoever launches the program."""
-    return cfg.block == "brumby" and fuses_state_pass(
-        cfg.hd, cfg.n_heads // cfg.kv_heads, pick_chunk(seq_len),
-        inference=True)
+    state pass in the fused kernel: the kind's own rule, for the
+    counters of whoever launches the program."""
+    return "retention_state_pass" in BLOCKS[cfg.block].kernels(cfg, seq_len)
 
 
 @dataclasses.dataclass(frozen=True)
 class BlockKind:
     init: Any       # (key, cfg, dtype) -> parameter pytree
-    forward: Any    # (params, seqs, cfg, mesh, seq_axis, inference) -> hidden
+    #: (params, seqs, cfg, mesh, seq_axis, inference) -> hidden; a
+    #: ``routed`` kind returns (hidden, (expert layers, experts held)
+    #: int32 assignments)
+    forward: Any
+    #: cfg -> bytes one token of a serving program holds at its peak
+    bytes_per_token: Any
+    #: (cfg, seq_len) -> names of the Pallas kernels a serving program
+    #: over histories that long engages, by the rules the forward pass
+    #: itself applies (static shape and backend)
+    kernels: Any = lambda cfg, seq_len: ()
+    routed: bool = False
 
 
 #: the block kinds a configuration can name (``SeqRecConfig.block``)
-BLOCKS = {"sasrec": BlockKind(_init_sasrec, _forward_sasrec),
-          "brumby": BlockKind(_init_brumby, _forward_brumby)}
+BLOCKS = {
+    "sasrec": BlockKind(_init_sasrec, _forward_sasrec, _bytes_sasrec),
+    "brumby": BlockKind(_init_brumby, _forward_brumby,
+                        _residual_and_ff_bytes, _kernels_brumby),
+    "deepseek_v2": BlockKind(_init_deepseek_v2, _forward_deepseek_v2,
+                             _bytes_deepseek_v2, _kernels_deepseek_v2,
+                             routed=True),
+}
 
 
 def head_table(params: Mapping) -> jax.Array:
@@ -682,18 +948,22 @@ def _load_train_state(directory, template_params, fingerprint):
 def predict_topk_batch(
     params: Mapping, history: jax.Array, k: int, cfg: SeqRecConfig,
     vocab_masks: jax.Array
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, ...]:
     """Like :func:`predict_topk` but with a per-query additive logit mask
     ``vocab_masks`` (B, V) — the batched eval path, where each query
-    carries its own seen/black-list exclusions."""
+    carries its own seen/black-list exclusions. A kind that routes
+    tokens to experts (``BlockKind.routed``) returns a third value: the
+    (expert layers, experts held) int32 assignments of the program."""
     mask = (history != PAD)
     last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
-    h = forward(params, history, cfg, inference=True)
+    kind = BLOCKS[cfg.block]
+    h = kind.forward(params, history, cfg, None, "seq", True)
+    h, *assignments = h if kind.routed else (h,)
     hl = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     logits = jnp.einsum("bd,vd->bv", hl, head_table(params).astype(h.dtype),
                         preferred_element_type=jnp.float32)
     logits = logits + vocab_masks
-    return jax.lax.top_k(logits, k)
+    return (*jax.lax.top_k(logits, k), *assignments)
 
 
 def predict_topk(
@@ -705,4 +975,5 @@ def predict_topk(
     the logits — 0 for allowed ids, a large negative for pad/seen/
     disallowed ids. Thin wrapper over :func:`predict_topk_batch` (the
     (1, V) mask broadcasts), so both paths share one kernel."""
-    return predict_topk_batch(params, history, k, cfg, vocab_mask[None, :])
+    return predict_topk_batch(params, history, k, cfg,
+                              vocab_mask[None, :])[:2]

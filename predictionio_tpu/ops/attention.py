@@ -122,7 +122,8 @@ def blockwise_attention(
     _, tiles = lax.scan(
         jax.checkpoint(tile), None,
         (qt, jnp.arange(n_tiles, dtype=jnp.int32)))
-    return tiles.transpose(1, 2, 0, 3, 4).reshape(B, H, S, D)
+    # the value head may be narrower than the query/key head (MLA)
+    return tiles.transpose(1, 2, 0, 3, 4).reshape(B, H, S, v.shape[-1])
 
 
 def _ring_attention_local(

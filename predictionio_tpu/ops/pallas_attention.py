@@ -1,52 +1,52 @@
 """Pallas TPU kernel: flash attention — auto-dispatched for causal
-serving shapes since the round-5 optimization pass.
+serving shapes of the ``sasrec`` block.
 
 Tile-streamed causal attention with the standard flash online softmax:
 for each query tile, K/V tiles stream through the MXU and a running
 (max, denominator, numerator) carry folds each tile — the S x S logits
-matrix never exists in HBM.
+matrix never exists in HBM. K and V of one (batch, head) are **whole in
+VMEM** and the loop over their tiles runs inside the kernel, computing
+in float32, one head size for q, k and v; the loop's bound stops at the
+diagonal (causal KV-tile skip) and tiles are 512 x 512 from S = 4,096.
+That bounds the length by VMEM (the envelope below). The ``deepseek_v2``
+block's attention (query/key heads wider than value heads, 128 heads at
+S = 8,192: 3 MiB of keys a head) is ops/mla_attention.py, a kernel
+tiled over query **and** key blocks by the grid, with bfloat16 operands;
+PERF.md section 7 says what keeps the two apart.
 
-**What the chip has shown on today's code (PR 21, one v5e):** the
+**What the chip has shown on today's code.** PR 21 (one v5e): the
 kernel compiles through Mosaic and matches ``full_attention`` at both
-ends of the envelope below at the sessionrec serving shape (B=1 H=4
-D=64 bf16 causal; ``chip_smoke.py`` re-proves it on every run), and
-Mosaic refuses any shape whose K/V block reaches 4 MiB. Its speed
-against XLA has NOT been re-measured: the history below is rounds 2-5
-on a chip attachment that no longer exists.
+ends of the envelope at the sessionrec serving shape (B=1 H=4 D=64 bf16
+causal; ``chip_smoke.py`` re-proves it on every run), and Mosaic refuses
+any shape whose K/V block reaches 4 MiB. PR 31 (one v5e, B=1 H=4 D=64
+causal, wall clock per call in a chain of 20 calls, so nothing under the
+~1 ms a call costs the host can be told apart):
 
-**Measurement history** (B=1 H=4 D=64 f32 causal). Round 2 claimed the kernel won from S=2048 on XLA
-timings that were flat in S (impossible for O(S^2) attention) — caught
-and retracted in round 3, whose re-measurement had XLA ahead at every
-depth (S=4096: XLA 1.10ms vs pallas 1.88ms) and auto-dispatch turned
-OFF. Round 5's optimization pass changed the verdict with two fixes:
-(1) **causal KV-tile skip** — the inner loop's bound now stops at the
-diagonal instead of visiting fully-masked tiles (the bound is traced
-from ``program_id``; halves visited tiles on average), and (2) a
-**block-size sweep** found 512x512 tiles ~2x faster than the original
-128x128 from S=4096 (bigger per-tile MXU work, fewer carry updates).
-Same-process A/B after the pass (fresh process, 64-128-call chains):
+=======  ========  ==================  ===============
+S        dtype     pallas, ms a call   XLA, ms a call
+=======  ========  ==================  ===============
+2048     bfloat16  1.006               0.282
+4096     bfloat16  1.049               1.179
+8192     bfloat16  1.044               4.845
+16384    bfloat16  3.152               18.957
+2048     float32   1.079               0.273
+4096     float32   1.203               1.203
+8192     float32   1.011               4.715
+=======  ========  ==================  ===============
 
-=======  ==========  ====================  =====
-S        XLA (ms)    pallas (ms) [tiles]   win
-=======  ==========  ====================  =====
-2048     0.392       0.282  [128x128]      1.4x
-4096     1.113       0.487  [512x512]      2.3x
-8192     4.704       0.850  [512x512]      5.5x
-16384    18.802      3.238  [512x512]      5.8x
-=======  ==========  ====================  =====
-
-The win grows with S: the kernel's HBM traffic is O(S * D) per query
-tile against the materialized formulation's O(S^2) logits, plus the
-causal skip XLA's fused softmax cannot apply. Numerics vs XLA:
-max|diff| ~2-3e-4 (online vs materialized softmax).
+So from S = 4,096 the kernel is level or ahead and at 16,384 six times
+ahead; at 2,048, the bottom of the envelope, XLA's fused attention is
+ahead of what a call costs the host at all (the device time of the
+kernel there is not measured: no cell runs the ``sasrec`` block). The
+tables of rounds 2-5, taken on a chip attachment that no longer exists,
+are gone (``git show fc20129:predictionio_tpu/ops/pallas_attention.py``).
 
 **Auto-dispatch:** CAUSAL attention on a compiled TPU backend at
 2048 <= S <= 16384 with K/V blocks of at most 2 MiB each (the skip
 only helps causal, and non-causal remains unmeasured -> force-only).
-Note the history table's f32 S=16384 row is outside that bound: Mosaic
-refuses it today. ``force=True`` still runs the kernel at any shape
-(incl. interpret mode for CPU tests). Sequences beyond a chip shard
-over the mesh "seq" axis instead (ops/attention.ring_attention).
+``force=True`` still runs the kernel at any shape (incl. interpret mode
+for CPU tests). Sequences beyond a chip shard over the mesh "seq" axis
+instead (ops/attention.ring_attention).
 
 Forward-only: no VJP — training paths (models/seqrec.next_item_loss,
 ring attention local blocks) use ops/attention.full_attention /
@@ -68,7 +68,7 @@ from predictionio_tpu.ops.attention import full_attention
 
 _TILE_Q = 128
 _TILE_K = 128
-#: the r5 block-size sweep: 512x512 tiles win from S=4096 (module table)
+#: 512x512 tiles from S=4096 (a sweep of round 5, not re-measured)
 _TILE_BIG = 512
 _TILE_BIG_FROM = 4096
 _NEG = -1e30  # python float: jnp scalars would be captured consts in the kernel

@@ -165,8 +165,8 @@ class AlgorithmParams(Params):
     # N epochs to checkpoint_dir; a re-run resumes from the last one
     checkpoint_dir: str = ""
     checkpoint_every: int = 0
-    # the block the stack is built from (models/seqrec.BLOCKS) and, for
-    # "brumby", its widths; 0 keeps what the SASRec block derives
+    # the block the stack is built from (models/seqrec.BLOCKS) and the
+    # widths the later kinds read; 0 keeps what the SASRec block derives
     backbone: str = "sasrec"
     n_kv_heads: int = 0
     head_dim: int = 0
@@ -177,6 +177,13 @@ class AlgorithmParams(Params):
     tie_embeddings: bool = True
     # the type serving holds the weights in on the device
     param_dtype: str = "float32"
+    # the "deepseek_v2" backbone's own widths (seqrec.MlaMoeWidths;
+    # engine.json carries a JSON object with the published keys)
+    mla_moe: Any = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "mla_moe",
+                           seqrec.MlaMoeWidths.of(self.mla_moe))
 
     def seqrec_config(self, vocab: int) -> seqrec.SeqRecConfig:
         import jax.numpy as jnp
@@ -189,7 +196,28 @@ class AlgorithmParams(Params):
             rope_theta=self.rope_theta, rms_eps=self.rms_eps,
             retention_degree=self.retention_degree,
             tie_embeddings=self.tie_embeddings,
-            param_dtype=jnp.dtype(self.param_dtype))
+            param_dtype=jnp.dtype(self.param_dtype), mla_moe=self.mla_moe)
+
+
+@dataclasses.dataclass(frozen=True)
+class SeqDispatch:
+    """What one ``batch_predict`` reports to ``dispatch_observer``
+    (``ServingStats.record_seq_dispatch``): always whole, a count that
+    does not apply is 0."""
+    programs: int               # device programs launched
+    tokens: int                 # events of the histories scored
+    padded_tokens: int          # tokens the programs ran over
+    split: int                  # 1 if the token budget split the dispatch
+    #: of the programs, those whose retention layers ran the fused
+    #: state pass (seqrec.fuses_retention)
+    fused_retention_programs: int = 0
+    #: the routed layers (a kind with ``BlockKind.routed``): assignments
+    #: to held experts over all expert layers, the tokens routed (once,
+    #: not per layer), and the fullest (layer, expert)'s assignments,
+    #: summed over the programs
+    moe_assignments: int = 0
+    moe_tokens: int = 0
+    moe_max_expert_load: int = 0
 
 
 @dataclasses.dataclass
@@ -202,11 +230,8 @@ class SeqRecEngineModel:
     # serialized (recreated after checkpoint load / reload)
     device_tree: Any = dataclasses.field(default=None, repr=False,
                                          compare=False)
-    # called once per batch_predict with (device programs launched,
-    # real tokens, padded tokens, 1 if the token budget split it) and,
-    # where any ran retention's state pass in the fused kernel, how many
-    # did: the engine server points it at
-    # ServingStats.record_seq_dispatch
+    # called once per batch_predict with one SeqDispatch: the engine
+    # server points it at ServingStats.record_seq_dispatch
     dispatch_observer: Any = dataclasses.field(default=None, repr=False,
                                                compare=False)
     # token_budget()'s answer for this model on this device (0: not
@@ -329,6 +354,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         # one answer for every program: the rule looks at the widths and
         # at the history length, not at the batch
         fused = seqrec.fuses_retention(model.cfg, S)
+        routed = np.zeros(3, np.int64)  # assignments, tokens, fullest expert
         while pos < len(rows):
             bucket = 1
             while bucket * 2 <= min(len(rows) - pos, widest):
@@ -337,9 +363,12 @@ class SeqRecAlgorithm(HostModelAlgorithm):
             pos += bucket
             programs += 1
             with span("dispatch.enqueue"):
-                scores, ids = seqrec.predict_topk_batch(
+                program = seqrec.predict_topk_batch(
                     tree, padded[part], k, model.cfg, masks[part])
-            scores, ids = await_and_fetch((scores, ids))
+            scores, ids, *assignments = await_and_fetch(program)
+            for per_expert in assignments:      # a routed kind: one array
+                routed += (per_expert.sum(), bucket * S,
+                           per_expert.max(initial=0))
             with span("dispatch.results"):
                 for (i, q), svals, sids in zip(rows[part], scores, ids):
                     items = []
@@ -351,9 +380,12 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                             items.append(ItemScore(item=item, score=float(v)))
                     out.append((i, PredictedResult(item_scores=tuple(items))))
         if model.dispatch_observer is not None:
-            report = (programs, int(lengths.sum()), len(rows) * S,
-                      int(len(rows) > widest))
-            model.dispatch_observer(*report, *((programs,) if fused else ()))
+            model.dispatch_observer(SeqDispatch(
+                programs=programs, tokens=int(lengths.sum()),
+                padded_tokens=len(rows) * S, split=int(len(rows) > widest),
+                fused_retention_programs=programs if fused else 0,
+                moe_assignments=int(routed[0]), moe_tokens=int(routed[1]),
+                moe_max_expert_load=int(routed[2])))
         return out
 
 

@@ -256,7 +256,11 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
     assert sessionrec.token_budget(model) == 2 * S
     split = dict(algo.batch_predict(model, queries))
     real = sum(min(len(model.histories[u]), S) for u in users)
-    assert seen == [(1, real, 8 * S, 0), (4, real, 8 * S, 1)]
+    # one record a dispatch (sessionrec.SeqDispatch), always whole
+    assert [(r.programs, r.tokens, r.padded_tokens, r.split)
+            for (r,) in seen] == [(1, real, 8 * S, 0), (4, real, 8 * S, 1)]
+    assert all(r.fused_retention_programs == 0 and r.moe_tokens == 0
+               for (r,) in seen)
     for i in whole:
         assert [s.item for s in whole[i].item_scores] == \
             [s.item for s in split[i].item_scores]
