@@ -30,6 +30,7 @@ from predictionio_tpu.obs.compile import instrumented_jit
 from predictionio_tpu.obs.trace import span
 from predictionio_tpu.ops import ann as ann_ops
 from predictionio_tpu.ops import topk as topk_ops
+from predictionio_tpu.serving.dispatch_phases import start_copies
 from predictionio_tpu.utils.bimap import BiMap, EntityIdIxMap
 
 logger = logging.getLogger(__name__)
@@ -130,8 +131,12 @@ def _serve_recommend(user_factors, item_f, packed, allow, k):
     One host->device and one device->host transfer per query: the
     query uploads as ONE int32 buffer [uix, seen_cols(512),
     seen_mask(512)] and the result downloads as ONE int32 buffer
-    [bitcast(vals,k), idxs(k)]. What a transfer costs on a directly
-    attached chip has not been measured on today's code."""
+    [bitcast(vals,k), idxs(k)]. One blocking copy of a ready 320-byte
+    result to the host is 0.43 ms on a v5e, whatever its size: a round
+    trip to the device (PERF.md §5, the copy probe of PR 32). The
+    batched path starts its copies at launch instead
+    (serving/dispatch_phases.start_copies); here one launch is followed
+    at once by its one ``np.asarray``, so there is nothing to hide."""
     uix = packed[0]
     cols = packed[1 : 1 + _SEEN_PAD][None, :]
     mask = (packed[1 + _SEEN_PAD : 1 + 2 * _SEEN_PAD] > 0
@@ -612,7 +617,9 @@ class ALSModel:
         probe + exact-rescore kernel, ops/ann) and the deployed-sharded
         merge take vectors, which one small jitted gather
         (:func:`_take_rows`) hands them. ``allow=None`` uses the
-        device-cached all-ones vector."""
+        device-cached all-ones vector. Whichever launched, the copy of
+        its ``(vals, idxs)`` to the host is started before this returns
+        (serving/dispatch_phases.start_copies); the caller collects it."""
         # dispatch.gather / dispatch.enqueue: ambient spans on the
         # batcher's per-dispatch trace (no-ops with tracing off). Both
         # time the HOST side only — upload + launch return before the
@@ -630,31 +637,37 @@ class ALSModel:
             if ann:
                 centroids, flat_items, flat_vecs, cell_offset, nprobe, \
                     rescore = self._ann_args()
-                vals, idxs = ann_ops.ann_topk(
-                    uv, self.item_factors, centroids, flat_items, flat_vecs,
-                    cell_offset, jnp.asarray(seen_cols),
-                    jnp.asarray(seen_mask), allow_v, k, nprobe, rescore)
                 self._record_ann(
                     self.ann_index.shortlist_width(nprobe, rescore),
                     int(uv.shape[0]))
-                return vals, idxs
-            if sharded:
+                launched = ann_ops.ann_topk(
+                    uv, self.item_factors, centroids, flat_items, flat_vecs,
+                    cell_offset, jnp.asarray(seen_cols),
+                    jnp.asarray(seen_mask), allow_v, k, nprobe, rescore)
+            elif sharded:
                 # deployed-sharded dispatch (docs/parallelism.md): local
                 # top-k per model shard, candidate all-gather, global merge
-                return topk_ops.recommend_topk_sharded(
+                launched = topk_ops.recommend_topk_sharded(
                     uv, self.item_factors,
                     jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
                     jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
                     allow_v, k, mesh)
-            if self._topk_observer is not None and topk_ops.selects_two_stage(
-                    allow_v, self.item_factors, uixs.shape[0], k):
-                self._topk_observer()
-            return topk_ops.recommend_topk_fused_rows(
-                self.user_factors, uixs, self.item_factors,
-                # NumPy stays NumPy on purpose: the dispatcher's host-side
-                # _trim_seen can only right-size concrete host arrays, and
-                # jit uploads them, with the indices, in the one launch
-                seen_cols, seen_mask, allow_v, k)
+            else:
+                if (self._topk_observer is not None
+                        and topk_ops.selects_two_stage(
+                            allow_v, self.item_factors, uixs.shape[0], k)):
+                    self._topk_observer()
+                launched = topk_ops.recommend_topk_fused_rows(
+                    self.user_factors, uixs, self.item_factors,
+                    # NumPy stays NumPy on purpose: the dispatcher's
+                    # host-side _trim_seen can only right-size concrete
+                    # host arrays, and jit uploads them, with the
+                    # indices, in the one launch
+                    seen_cols, seen_mask, allow_v, k)
+            # the one way out of all three branches: the copies of
+            # (vals, idxs) to the host start behind the program, first
+            # thing after its launch (dispatch.copy_start)
+            return start_copies(launched)
 
     def predict_rating(self, user_id: str, item_id: str) -> float | None:
         uix = self.user_ids.get(user_id)

@@ -343,7 +343,9 @@ class ALSAlgorithm(ShardedAlgorithm):
         Each phase is an ambient ``dispatch.*`` span (obs/trace.span: a
         no-op unless the batcher bound its per-dispatch trace), recorded
         here and in ``ALSModel.batch_topk`` where the work happens:
-        prepare → gather → enqueue → device_wait → fetch → results."""
+        prepare → gather → enqueue (⊃ copy_start: the results' copies
+        to the host start behind the launch) → device_wait → fetch →
+        results."""
         if not queries:
             return []
         with span("dispatch.prepare"):

@@ -47,7 +47,10 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.obs.trace import span
 from predictionio_tpu.ops.topk import serving_k
-from predictionio_tpu.serving.dispatch_phases import await_and_fetch
+from predictionio_tpu.serving.dispatch_phases import (
+    await_and_fetch,
+    start_copies,
+)
 from predictionio_tpu.utils.bimap import BiMap
 
 _NEG = np.float32(-1e30)
@@ -314,7 +317,8 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         template records (no-ops unless the batcher bound its
         per-dispatch trace): prepare (routing, the histories looked
         up), gather (the padded history matrix and the seen masks the
-        programs take), then per program enqueue -> device_wait ->
+        programs take), then per program enqueue (the launch, and the
+        start of its results' copies to the host) -> device_wait ->
         fetch -> results."""
         S = model.cfg.max_len
         with span("dispatch.prepare"):
@@ -363,8 +367,8 @@ class SeqRecAlgorithm(HostModelAlgorithm):
             pos += bucket
             programs += 1
             with span("dispatch.enqueue"):
-                program = seqrec.predict_topk_batch(
-                    tree, padded[part], k, model.cfg, masks[part])
+                program = start_copies(seqrec.predict_topk_batch(
+                    tree, padded[part], k, model.cfg, masks[part]))
             scores, ids, *assignments = await_and_fetch(program)
             for per_expert in assignments:      # a routed kind: one array
                 routed += (per_expert.sum(), bucket * S,

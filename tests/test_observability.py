@@ -618,10 +618,17 @@ class TestDispatchSpans:
         # the order they ran, each inside the parent's interval
         phases = [by_name[name] for name in DISPATCH_PHASES]
         assert [s["name"] for s in spans
-                if s["name"].startswith("dispatch.")] == list(DISPATCH_PHASES)
+                if s["name"].startswith("dispatch.")
+                and s["name"] != "dispatch.copy_start"] == list(DISPATCH_PHASES)
         for before, after in zip(phases, phases[1:]):
             assert _end(before) <= after["startMs"] + self.TOL_MS
-        for s in phases:
+        # dispatch.copy_start: no seventh phase, it lies inside enqueue
+        # (the results' copies to the host start right behind the launch)
+        copy_start, enqueue = by_name["dispatch.copy_start"], \
+            by_name["dispatch.enqueue"]
+        assert copy_start["startMs"] >= enqueue["startMs"] - self.TOL_MS
+        assert _end(copy_start) <= _end(enqueue) + self.TOL_MS
+        for s in phases + [copy_start]:
             assert s["parentId"] == dd["spanId"]
             assert s["startMs"] >= dd["startMs"] - self.TOL_MS
             assert _end(s) <= _end(dd) + self.TOL_MS
