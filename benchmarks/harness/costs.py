@@ -27,11 +27,17 @@ def als_iteration(config: dict, nnz: int | None = None) -> dict:
 
 def recommend_topk(config: dict, batch: float) -> dict:
     """One score-and-top-k dispatch of ``batch`` queries: B x I x K
-    multiply-adds; the item table read once in float32, one score per
-    (query, item) written and read again by the selection."""
+    multiply-adds; the item table read once at the operand width the
+    configuration guarantees its scores are formed from
+    (``score_operand_bytes``; 4, float32, where the file does not say),
+    one float32 score per (query, item) written and read again by the
+    selection. What the guarantee needs, not what one program happens
+    to hold: a program that reads a wider table than the guarantee asks
+    for moves bytes that need not move, and its share says so."""
     k, items = config["rank"], config["items"]
+    width = float(config.get("score_operand_bytes", 4))
     return {"flops": 2.0 * batch * items * k,
-            "bytes": items * k * 4.0 + 2 * batch * items * 4.0}
+            "bytes": items * k * width + 2 * batch * items * 4.0}
 
 
 def roofline(cost: dict, seconds: float, peaks: dict) -> dict:

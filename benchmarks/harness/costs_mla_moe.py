@@ -99,3 +99,22 @@ def forward(config: dict, tokens: float, assignments: float) -> dict:
              + 2.0 * w["vocab"] * w["d"] * queries)
     return {"flops": flops,
             "bytes": 2.0 * held_params(config) + 2.0 * tokens * w["d"]}
+
+
+def window_values(counters: dict, config: dict) -> dict:
+    """What the routed layers' counters say of the window (the kind
+    merges it into the run's ``notes`` and the readers' ``values``):
+    assignments to held experts per program, per token and expert
+    layer, and the fullest (layer, expert) of a program over the mean.
+    Empty where the program has no such counters or routed nothing."""
+    tokens = counters.get("seq_moe_tokens", 0)
+    assignments = counters.get("seq_moe_assignments", 0)
+    if not tokens or not assignments:
+        return {}
+    layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
+    held = config["experts_held"][1]
+    return {"moe_assignments_per_program":
+            assignments / max(counters["seq_programs"], 1),
+            "routed_assignments_per_token": assignments / (tokens * layers),
+            "expert_load_max_over_mean":
+            counters["seq_moe_max_expert_load"] * layers * held / assignments}

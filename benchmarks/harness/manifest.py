@@ -44,7 +44,8 @@ def load_cell(name: str, manifest_file: str = "BENCHMARK.json",
     cfg_entry = next(c for c in manifest["configs"] if c["name"] == w["config"])
     config = load_json(os.path.join(root, cfg_entry["file"]))
     traffic = load_json(os.path.join(
-        BENCH_DIR, "traffic", w["traffic"] + ".json"))
+        root, os.path.basename(BENCH_DIR), "traffic",
+        w["traffic"] + ".json"))
     e2e = tuple(m for m in manifest["end_to_end"] if _in_cell(m, name))
     moved = {m["name"] for m in e2e}
     layer = tuple(m for m in manifest["per_layer"]

@@ -34,9 +34,13 @@ RANK_TOL = 2 * SCORE_TOL
 
 
 def check_one(ref_logits: np.ndarray, history: np.ndarray, answer: list,
-              num: int) -> tuple[str | None, dict]:
+              num: int, score_tol: float = SCORE_TOL,
+              rank_tol: float = RANK_TOL) -> tuple[str | None, dict]:
     """``answer``: [(dense item index, score)] as returned. Returns
-    (what is wrong or None, the worst differences found)."""
+    (what is wrong or None, the worst differences found). What has to
+    hold whatever the precision (the count, no item twice, no PAD, no
+    item of the history, descending scores) comes first; then both
+    differences are taken and held to the limits given."""
     worst = {"score_diff": 0.0, "rank_gap": 0.0}
     allowed = ref_logits.copy()
     allowed[0] = -np.inf                                   # PAD
@@ -52,15 +56,15 @@ def check_one(ref_logits: np.ndarray, history: np.ndarray, answer: list,
     if np.any(np.diff(scores) > 0):
         return "scores not in descending order", worst
     worst["score_diff"] = float(np.max(np.abs(scores - ref_logits[ids])))
-    if worst["score_diff"] > SCORE_TOL:
-        return f"score off by {worst['score_diff']:.4f}", worst
     order = np.argsort(-allowed, kind="stable")
     tenth = allowed[order[len(ids) - 1]]
     below = float(np.max(tenth - allowed[ids]))            # returned, ranked lower
     missed = np.setdiff1d(order[:len(ids)], ids)
     above = float(np.max(allowed[missed] - tenth)) if len(missed) else 0.0
     worst["rank_gap"] = max(below, above, 0.0)
-    if worst["rank_gap"] > RANK_TOL:
+    if worst["score_diff"] > score_tol:
+        return f"score off by {worst['score_diff']:.4f}", worst
+    if worst["rank_gap"] > rank_tol:
         return f"top-{len(ids)} differs by {worst['rank_gap']:.4f}", worst
     return None, worst
 

@@ -2,12 +2,16 @@
 configuration names its reference (``harness/seq_ref_data.reference``):
 answers the timed path returned, recomputed by that plain reference on
 the same device, at the served sizes, from the served weights, and held
-to it by ``seq_check.check_one``'s rules under this module's limits.
+to it by ``seq_check.check_one``'s rules under the limits the reference
+module carries beside the reasons for them: ``SCORE_TOL`` and
+``RANK_TOL``, and where it has near ties to resolve ``NEAR_TIE`` and
+``MAX_STEPS``. This module keeps no number of its own.
 
-A reference that routes tokens (``resolutions``) yields the logits
-under the resolutions of the last position's router near ties, best
-first; the answer has to agree, whole, with one of the first
-``MAX_STEPS`` search steps' worth of them.
+A reference that has near ties to resolve has ``resolutions``, which
+yields the logits under them, best first; the answer has to agree,
+whole, with one of the first ``MAX_STEPS`` search steps' worth of them.
+One that has none has ``last_logits`` alone, and that is its one
+resolution.
 """
 
 from __future__ import annotations
@@ -19,70 +23,33 @@ import numpy as np
 from benchmarks.harness import seq_check, seq_ref_data, traffic as tr
 
 SAMPLE = seq_check.SAMPLE
-#: |returned score - reference logit of that item|, in logits (~N(0, 1)
-#: over the catalog, the top ten between 3.5 and 5). Four times
-#: ``seq_check``'s limits: beside the bfloat16 rounding of every
-#: activation through five layers, a router near tie at a position
-#: *before* the last may fall the other way in the served path, and only
-#: the last position's are resolved. The limit lies between two readings
-#: on the chip (PERF.md section 6, PR 31), three times above the one and
-#: six times under the other: the served path's worst over 77 checked
-#: queries of 20 seeds, 0.132, and the reference with both operands of
-#: every product rounded to 8 bits (float8 e4m3), whose own top ten are
-#: off by 2.61 to 4.11 and so come out as not correct
-SCORE_TOL = 0.4
-#: a returned item may rank below the reference's tenth, and a reference
-#: top-ten item may be missing, only if its reference logit is this
-#: close to the tenth's: two scores' worth (the worst seen is 0.222)
-RANK_TOL = 2 * SCORE_TOL
-#: two router scores of the last position are a near tie when the ``ln``
-#: of their ratio is under this: the served path's bfloat16 activations
-#: have turned ties of margins up to 0.029 (PERF.md section 6, PR 31);
-#: a chosen group or expert that close to an excluded one may have been
-#: exchanged for it
-NEAR_TIE = 0.1
-#: search steps (one layer of one row each, ~0.15 s on the chip) the
-#: reference may spend on one answer
-MAX_STEPS = 400
 
 
-def check_one(ref_logits: np.ndarray, history: np.ndarray, answer: list,
-              num: int) -> tuple[str | None, dict]:
-    """``seq_check.check_one`` under this module's limits: what has to
-    hold whatever the precision (the count, no item twice, no PAD, no
-    item of the history, descending scores) is its own verdict."""
-    why, worst = seq_check.check_one(ref_logits, history, answer, num)
-    if why is None or not why.startswith(("score off", "top-")):
-        return why, worst
-    ids = np.asarray([ix for ix, _ in answer], np.int64)
-    scores = np.asarray([s for _, s in answer], np.float64)
-    allowed = ref_logits.copy()
-    allowed[0] = -np.inf
-    allowed[history] = -np.inf
-    order = np.argsort(-allowed, kind="stable")
-    tenth = allowed[order[len(ids) - 1]]
-    missed = np.setdiff1d(order[:len(ids)], ids)
-    worst = {"score_diff": float(np.max(np.abs(scores - ref_logits[ids]))),
-             "rank_gap": max(float(np.max(tenth - allowed[ids])), 0.0, float(
-                 np.max(allowed[missed] - tenth)) if len(missed) else 0.0)}
-    if worst["score_diff"] > SCORE_TOL:
-        return f"score off by {worst['score_diff']:.4f}", worst
-    if worst["rank_gap"] > RANK_TOL:
-        return f"top-{len(ids)} differs by {worst['rank_gap']:.4f}", worst
-    return None, worst
+def resolutions(reference, weights, history, config: dict):
+    """The reference's (logits, margin given up), best first, under its
+    own ``NEAR_TIE`` and ``MAX_STEPS``; of a reference with no near ties
+    to resolve, its one answer."""
+    if not hasattr(reference, "resolutions"):
+        return iter([(reference.last_logits(weights, history, config), 0.0)])
+    return reference.resolutions(weights, history, config,
+                                 near_tie=reference.NEAR_TIE,
+                                 max_steps=reference.MAX_STEPS)
 
 
-def hold_to_resolutions(resolutions, history, answer, num):
-    """``resolutions``: the reference's (logits, margin given up), best
+def hold_to_resolutions(found, history, answer, num, reference):
+    """``found``: the reference's (logits, margin given up), best
     first. Returns (what is wrong or None, the worst differences, how
     many were tried, the margin given up by the one that agreed or -1):
-    of the first resolution the answer agrees with, else of the one
-    whose scores it comes nearest."""
+    of the first resolution the answer agrees with under
+    ``seq_check.check_one``'s rules and the reference module's
+    ``SCORE_TOL`` and ``RANK_TOL``, else of the one whose scores it
+    comes nearest."""
     nearest, tried = None, 0
-    for logits, cost in resolutions:
+    for logits, cost in found:
         tried += 1
-        why, worst = check_one(np.asarray(logits, np.float32), history,
-                               answer, num)
+        why, worst = seq_check.check_one(
+            np.asarray(logits, np.float32), history, answer, num,
+            reference.SCORE_TOL, reference.RANK_TOL)
         if why is None:
             return None, worst, tried, float(cost)
         if nearest is None or worst["score_diff"] < nearest[1]["score_diff"]:
@@ -116,16 +83,18 @@ def check_answers(rec: dict, model, histories: np.ndarray, pool,
             problems.append(f"malformed answer: {exc}")
             continue
         why, found, tried, cost = hold_to_resolutions(
-            reference.resolutions(model.device_tree, histories[u], config,
-                                  near_tie=NEAR_TIE, max_steps=MAX_STEPS),
-            histories[u], answer, num)
+            resolutions(reference, model.device_tree, histories[u], config),
+            histories[u], answer, num, reference)
         worst = {key: max(worst[key], found[key]) for key in worst}
         tried_most, given_up = max(tried_most, tried), max(given_up, cost)
         if why:
             problems.append(f"u{u}: {why} ({tried} resolutions)")
     notes = {"checked": int(len(sample)),
-             "score_diff_max": worst["score_diff"], "score_tol": SCORE_TOL,
-             "rank_gap_max": worst["rank_gap"], "rank_tol": RANK_TOL,
-             "near_tie": NEAR_TIE, "resolutions_tried_most": int(tried_most),
+             "score_diff_max": worst["score_diff"],
+             "score_tol": reference.SCORE_TOL,
+             "rank_gap_max": worst["rank_gap"],
+             "rank_tol": reference.RANK_TOL,
+             "near_tie": getattr(reference, "NEAR_TIE", 0.0),
+             "resolutions_tried_most": int(tried_most),
              "margin_given_up_most": given_up}
     return not problems and len(sample) > 0, problems[:5], notes

@@ -3,9 +3,11 @@ says how it is read: ``algorithm_params`` maps the template's
 ``AlgorithmParams`` fields to the file's keys, ``reference`` names the
 module under ``benchmarks/reference/`` that decides ``correct`` and
 ``costs`` the module under ``benchmarks/harness/`` that prices the
-forward pass. The next backbone brings those three and its files, and no
-copy of this module. Histories, warm-up and counters are
-``harness/seq_data``'s.
+forward pass and says what the window's counters mean
+(``window_values``), ``counters`` the program's own counters beside the
+dispatch counters. The next backbone brings those four and its files,
+and no copy of this module. Histories, warm-up and the dispatch counters
+are ``harness/seq_data``'s.
 """
 
 from __future__ import annotations
@@ -43,6 +45,16 @@ def reference(config: dict):
 
 def costs(config: dict):
     return importlib.import_module(f"benchmarks.harness.{config['costs']}")
+
+
+def window_values(counters: dict, config: dict) -> dict:
+    """What the configuration's cost module makes of a window's counters
+    (its ``window_values``: values for the run's notes and the readers);
+    nothing where the file names no module or the module has no such
+    function."""
+    derive = getattr(costs(config), "window_values", None) \
+        if config.get("costs") else None
+    return derive(counters, config) if derive else {}
 
 
 def build_model(config: dict, traffic: dict, seed: int):
@@ -88,12 +100,12 @@ def deployed_engine(config: dict, model):
     return DeployedEngine(None, instance, [algo], FirstServing(), [model])
 
 
-def seq_counters(server) -> dict:
-    """``seq_data.seq_counters`` and the routed layers' counters
-    (absent from a program that has none: left out)."""
+def seq_counters(server, config: dict) -> dict:
+    """``seq_data.seq_counters`` and the counters the configuration's
+    file lists under ``counters`` (the name a window's evidence gives
+    it -> its name in ``/stats.json`` ``serving``; one that is absent
+    from the program is left out)."""
     snap = server.service.serving_stats.snapshot()
-    routed = {"seq_moe_assignments": "seqMoeAssignments",
-              "seq_moe_tokens": "seqMoeTokens",
-              "seq_moe_max_expert_load": "seqMoeMaxExpertLoad"}
     return {**seq_data.seq_counters(server),
-            **{k: int(snap[v]) for k, v in routed.items() if v in snap}}
+            **{k: int(snap[v]) for k, v in config.get("counters", {}).items()
+               if v in snap}}

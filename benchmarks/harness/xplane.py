@@ -81,17 +81,37 @@ def busy_seconds(events) -> float:
     return sum(b - a for a, b in union(events)) / 1e9
 
 
+def extent(events) -> tuple[int, int]:
+    """The first start and the last end, ns on the trace's clock."""
+    return (min(s for _, s, _ in events),
+            max(s + d for _, s, d in events))
+
+
 def extent_seconds(events) -> float:
     """First start to last end."""
     if not events:
         return 0.0
-    return (max(s + d for _, s, d in events)
-            - min(s for _, s, _ in events)) / 1e9
+    first, last = extent(events)
+    return (last - first) / 1e9
 
 
 def matching(events, pattern: str) -> list:
     rx = re.compile(pattern)
     return [e for e in events if rx.search(e[0])]
+
+
+def whole_runs(plane: dict, pattern: str) -> list:
+    """The runs of the modules whose name matches that the profile holds
+    whole. The profiler keeps what ran while it recorded: of a program in
+    flight when it started or stopped it keeps a part, as a run of its
+    own, shorter than a run. Such a run's interval touches the plane's
+    traced extent (its first event's start or its last event's end), so
+    a run that does is left out (a whole one that happens to be the
+    plane's first or last event goes with it: the others read the same
+    per run)."""
+    first, last = extent(plane["ops"] + plane["modules"])
+    return [m for m in matching(plane["modules"], pattern)
+            if first < m[1] and m[1] + m[2] < last]
 
 
 def within(events, modules, pattern: str) -> list:

@@ -1,9 +1,12 @@
 """A session-engine serve cell whose configuration file names its own
-reference, cost module and ``AlgorithmParams`` (``harness/seq_ref_data``):
-``kinds/serve_seq_open.py``'s run with those in the place of the names
-it has built in, and the routed layers' counters beside the dispatch
-counters. The window, the generators, the latency arithmetic and the
-result line are ``kinds/serve_open.py``'s.
+reference, cost module, counters and ``AlgorithmParams``
+(``harness/seq_ref_data``): ``kinds/serve_seq_open.py``'s run with those
+in the place of the names it has built in. The counters the file lists
+are read beside the dispatch counters, and what the cost module's
+``window_values`` makes of them (none where it has no such function)
+joins the run's ``notes`` and the readers' ``values``. The window, the
+generators, the latency arithmetic and the result line are
+``kinds/serve_open.py``'s.
 """
 
 from __future__ import annotations
@@ -16,24 +19,6 @@ from benchmarks.harness import (
 from benchmarks.harness.output import end_to_end_line, per_layer_line
 from benchmarks.kinds.serve_open import request_spans
 from benchmarks.kinds.serve_seq_open import failed_statuses, traced_seconds
-
-
-def routed_values(counters: dict, config: dict) -> dict:
-    """What the routed layers' counters say of the window: assignments
-    to held experts per token and expert layer, and the fullest (layer,
-    expert) of a program over the mean. Empty where the program has no
-    such counters or routed nothing."""
-    tokens = counters.get("seq_moe_tokens", 0)
-    assignments = counters.get("seq_moe_assignments", 0)
-    if not tokens or not assignments:
-        return {}
-    layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
-    held = config["experts_held"][1]
-    return {"moe_assignments_per_program":
-            assignments / max(counters["seq_programs"], 1),
-            "routed_assignments_per_token": assignments / (tokens * layers),
-            "expert_load_max_over_mean":
-            counters["seq_moe_max_expert_load"] * layers * held / assignments}
 
 
 def run(cell, args, t_start: float) -> str:
@@ -55,7 +40,7 @@ def run(cell, args, t_start: float) -> str:
             parts_s["warm_up"] = time.monotonic() - t_start
             mark = device.clock_marker() if trace else None
             before = {**serve.batch_counters(server),
-                      **seq_ref_data.seq_counters(server)}
+                      **seq_ref_data.seq_counters(server, cell.config)}
             compiles0 = serve.compile_count()
             trace_dir = f"{work}/trace" if trace else None
             setup_s = time.monotonic() + serve.GO_LEAD - t_start
@@ -64,7 +49,7 @@ def run(cell, args, t_start: float) -> str:
                 parts, clock = serve.run_window(children, server, seconds,
                                                 trace_dir, mark)
             after = {**serve.batch_counters(server),
-                     **seq_ref_data.seq_counters(server)}
+                     **seq_ref_data.seq_counters(server, cell.config)}
             window_compiles = serve.compile_count() - compiles0
             spans, requests, host = (request_spans(server) if trace
                                      else ({}, [], []))
@@ -79,13 +64,13 @@ def run(cell, args, t_start: float) -> str:
         correct = correct and window_compiles == 0 and m["failed"] == 0
         counters = {k: after[k] - before[k] for k in after}
         counters["window_compiles"] = window_compiles
-        routed = routed_values(counters, cell.config)
+        derived = seq_ref_data.window_values(counters, cell.config)
         notes = {"n": m["attempted"], "tail_percentile": m["tail_percentile"],
                  "query_tail_ms": m["query_tail_ms"],
                  "percentiles_ms": {p: m[f"query_p{p}_ms"]
                                     for p in (50, 90, 95, 99)},
                  "served_qps": m["served_qps"], "slices": m["slices"],
-                 **counters, **routed, "reference": checked,
+                 **counters, **derived, "reference": checked,
                  "failed_statuses": failed_statuses(rec),
                  "warmed_signatures": n_sigs, "setup_reached_s": parts_s,
                  "problems": problems}
@@ -99,7 +84,7 @@ def run(cell, args, t_start: float) -> str:
                          / max(counters["dispatches"], 1),
                          "seq_tokens_per_program":
                          counters["seq_padded_tokens"]
-                         / max(counters["seq_programs"], 1), **routed},
+                         / max(counters["seq_programs"], 1), **derived},
               "notes": notes}
         return per_layer_line(
             cell, ev, xplane.load_dir(trace_dir),

@@ -54,6 +54,37 @@ import jax.numpy as jnp
 import numpy as np
 
 F32 = jnp.float32
+
+# -- the limits ``harness/seq_ref_check`` holds a served answer to -----------
+#: |returned score - reference logit of that item|, in logits (~N(0, 1)
+#: over the catalog, the top ten between 3.5 and 5). Four times
+#: ``harness/seq_check``'s limits: beside the bfloat16 rounding of every
+#: activation through five layers, a router near tie at a position
+#: *before* the last may fall the other way in the served path, and only
+#: the last position's are resolved. The limit lies between two readings
+#: on the chip (PERF.md section 6, PR 31), three times above the one and
+#: six times under the other: the served path's worst over 77 checked
+#: queries of 20 seeds, 0.132, and this reference with both operands of
+#: every product rounded to 8 bits (float8 e4m3), whose own top ten are
+#: off by 2.61 to 4.11 and so come out as not correct (2.84 to 3.74
+#: again in PR 33, which also read served answers at 0.30 to 0.35: the
+#: check stops at the first resolution that agrees, so a last-position
+#: tie worth less than the limit stays unresolved; PERF.md section 6)
+SCORE_TOL = 0.4
+#: a returned item may rank below the reference's tenth, and a reference
+#: top-ten item may be missing, only if its reference logit is this
+#: close to the tenth's: two scores' worth (the worst seen is 0.222)
+RANK_TOL = 2 * SCORE_TOL
+#: two router scores of the last position are a near tie when the ``ln``
+#: of their ratio is under this: the served path's bfloat16 activations
+#: have turned ties of margins up to 0.029 (PERF.md section 6, PR 31);
+#: a chosen group or expert that close to an excluded one may have been
+#: exchanged for it
+NEAR_TIE = 0.1
+#: search steps (one layer of one row each, ~0.15 s on the chip) the
+#: reference may spend on one answer
+MAX_STEPS = 400
+
 #: None: float32 everywhere (the reference). tools/seq_ref_precision.py
 #: sets one of these for the readings that have to come out as not
 #: correct: "operands" rounds both operands of every product to 8 bits
@@ -310,7 +341,7 @@ def layer(x, c_kv, k_pe, w, cfg):
 
 
 def resolutions(weights, history, config: dict, near_tie: float = 0.0,
-                max_steps: int = 400):
+                max_steps: int = MAX_STEPS):
     """Yields (logits (vocabulary,), margin given up) of the position
     after ``history`` under the resolutions of the last position's
     router near ties, best first (module docstring): a best-first search

@@ -1,11 +1,14 @@
 """Look at a trace by hand before trusting code against it.
 
     python3 benchmarks/tools/dump_xplane.py <file.xplane.pb | --record DIR>
+    python3 benchmarks/tools/dump_xplane.py --runs <pattern> <file.xplane.pb>...
 
 With ``--record`` it traces a small jitted loop on this machine's device
 (host and Python tracers off), prints the clocks at the start, and
 leaves the trace in DIR: that is how the recorded trace beside the
-tests was made.
+tests was made. With ``--runs`` it lists every run of the programs whose
+name matches beside the profile's edges (``tools/keep_trace.py`` keeps a
+cell's profile): a run the profile cut is short and touches one.
 """
 
 import os
@@ -64,8 +67,37 @@ def dump(path: str) -> None:
                 print(f"    {ns / 1e6:10.3f} ms  {name[:100]}")
 
 
+def runs(pattern: str, path: str) -> None:
+    """Each run of the matching programs: start, length, device time of
+    the operations inside, and how far it lies from the profile's first
+    and last instant (all ms)."""
+    from jax.profiler import ProfileData
+
+    from benchmarks.harness import xplane
+
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            stats = dict(plane.stats)
+            print(path, "profiled for", (stats["profile_stop_time"]
+                  - stats["profile_start_time"]) / 1e6, "ms")
+    for plane in xplane.load(path):
+        first, last = xplane.extent(plane["ops"] + plane["modules"])
+        print(f"{plane['device']}: first event {first / 1e6:.3f}, last "
+              f"event ends {last / 1e6:.3f}")
+        for name, start, dur in xplane.matching(plane["modules"], pattern):
+            inside = xplane.within(plane["ops"], [(name, start, dur)], ".")
+            print(f"  {name[:40]:40s} start {start / 1e6:11.3f} length "
+                  f"{dur / 1e6:9.3f} ops {len(inside):6d} busy "
+                  f"{xplane.busy_seconds(inside) * 1e3:9.3f} from first "
+                  f"{(start - first) / 1e6:9.3f} to last "
+                  f"{(last - start - dur) / 1e6:9.3f}")
+
+
 if __name__ == "__main__":
-    if sys.argv[1] == "--record":
+    if sys.argv[1] == "--runs":
+        for path in sys.argv[3:]:
+            runs(sys.argv[2], path)
+    elif sys.argv[1] == "--record":
         dump(record(sys.argv[2]))
     else:
         dump(sys.argv[1])

@@ -1,4 +1,4 @@
-"""The two readings a limit of ``harness/seq_ref_check`` lies between, by
+"""The two readings a limit of a reference module lies between, by
 hand on the chip, for a cell of the ``serve_seq_ref_open`` kind.
 
     python3 benchmarks/tools/seq_ref_precision.py --workload <cell> --seeds 1,2 [--users 8] [--lower 3]
@@ -50,13 +50,13 @@ def main() -> int:
         users = [int(u) for u in dict.fromkeys(pool.tolist())
                  if u >= 0][:args.users]
         line = {"seed": seed, "users": users, "served": [],
-                "score_tol": seq_ref_check.SCORE_TOL,
-                "rank_tol": seq_ref_check.RANK_TOL,
-                "near_tie": seq_ref_check.NEAR_TIE}
+                "score_tol": reference.SCORE_TOL,
+                "rank_tol": reference.RANK_TOL,
+                "near_tie": reference.NEAR_TIE}
         def resolutions(u, steps):
             return reference.resolutions(
                 model.device_tree, histories[u], cell.config,
-                near_tie=seq_ref_check.NEAR_TIE, max_steps=steps)
+                near_tie=reference.NEAR_TIE, max_steps=steps)
 
         for u in users:
             got = algo.batch_predict(
@@ -68,12 +68,13 @@ def main() -> int:
                 for logits, cost in found:
                     if not own:
                         own.append(seq_ref_check.hold_to_resolutions(
-                            [(logits, cost)], histories[u], answer, num))
+                            [(logits, cost)], histories[u], answer, num,
+                            reference))
                     yield logits, cost
 
             why, worst, tried, cost = seq_ref_check.hold_to_resolutions(
-                watched(resolutions(u, seq_ref_check.MAX_STEPS)),
-                histories[u], answer, num)
+                watched(resolutions(u, reference.MAX_STEPS)),
+                histories[u], answer, num, reference)
             line["served"].append({
                 "why": why, **worst, "tried": tried, "margin_given_up": cost,
                 "own_choice_why": own[0][0],
@@ -91,7 +92,7 @@ def main() -> int:
                 top = np.argsort(-allowed, kind="stable")[:num]
                 why, worst, tried, cost = seq_ref_check.hold_to_resolutions(
                     resolutions(u, 100), histories[u],
-                    [(int(i), float(low[i])) for i in top], num)
+                    [(int(i), float(low[i])) for i in top], num, reference)
                 line[what].append({"why": why, **worst, "tried": tried,
                                    "margin_given_up": cost})
         reference.set_lower(None)

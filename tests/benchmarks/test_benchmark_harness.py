@@ -15,7 +15,6 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-BENCH = os.path.join(ROOT, "benchmarks")
 
 from benchmarks.harness import costs, serve, stats, traffic as tr, xplane  # noqa: E402
 from benchmarks.reference import als_numpy  # noqa: E402
@@ -24,15 +23,21 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def load_manifest(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
-# -- manifest lint -----------------------------------------------------------
+@pytest.fixture(scope="module")
+def manifest():
+    return load_manifest()
 
-def test_manifest_keys_and_names(manifest):
+
+# -- manifest lint -----------------------------------------------------------
+# Rules of a checkout at ``root``: ``test_additions_by_files.py`` holds a
+# copy with a fifth cell laid over it to the same rules.
+
+def lint_keys_and_names(manifest: dict) -> None:
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
     assert 1 <= manifest["run_seconds"] <= 51
@@ -57,7 +62,8 @@ def test_manifest_keys_and_names(manifest):
         assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
 
 
-def test_every_cell_finds_its_files(manifest):
+def lint_every_cell_finds_its_files(manifest: dict, root: str) -> None:
+    bench = os.path.join(root, "benchmarks")
     configs = {c["name"]: c for c in manifest["configs"]}
     cells = [w["name"] for w in manifest["workloads"]]
     assert 2 <= len(cells) <= 24 and len(set(cells)) == len(cells)
@@ -67,13 +73,13 @@ def test_every_cell_finds_its_files(manifest):
     e2e = {m["name"] for m in manifest["end_to_end"]}
     for w in manifest["workloads"]:
         assert w["chips"] in (1, 4)
-        cfg_file = os.path.join(ROOT, configs[w["config"]]["file"])
+        cfg_file = os.path.join(root, configs[w["config"]]["file"])
         with open(cfg_file) as f:
             config = json.load(f)
         assert not config.get("rehearsal"), "a toy cannot be a cell"
         assert config["source"] == configs[w["config"]]["source"]
         assert config["reduced"] == configs[w["config"]]["reduced"]
-        with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
+        with open(os.path.join(bench, "traffic", w["traffic"] + ".json")) as f:
             kind = json.load(f)["kind"]
         importlib.import_module(f"benchmarks.kinds.{kind}")
 
@@ -88,9 +94,17 @@ def test_every_cell_finds_its_files(manifest):
             assert m["moves"] in mine, (w["name"], m["name"])
     for m in manifest["per_layer"]:
         assert m["moves"] in e2e
-        with open(os.path.join(BENCH, "layer_metrics", m["name"] + ".json")) as f:
+        with open(os.path.join(bench, "layer_metrics", m["name"] + ".json")) as f:
             spec = json.load(f)
         importlib.import_module(f"benchmarks.readers.{spec['reader']}")
+
+
+def test_manifest_keys_and_names(manifest):
+    lint_keys_and_names(manifest)
+
+
+def test_every_cell_finds_its_files(manifest):
+    lint_every_cell_finds_its_files(manifest, ROOT)
 
 
 def test_one_name_per_layer(manifest):
@@ -190,6 +204,64 @@ def test_xplane_reduction_on_plain_tuples():
     assert gaps["after:while"] == pytest.approx(100e-9)
 
 
+def _plane(runs, idle_ends=True):
+    """A plane whose programs run ``runs`` [(start, length) in ms]; each
+    is busy for its whole length in two ops. With ``idle_ends`` the
+    clock marker comes first and another program's op last, as in a
+    window that started and stopped between two programs."""
+    ms = 1_000_000
+    modules = [("jit_predict_topk_batch(7)", s * ms, d * ms) for s, d in runs]
+    ops = [op for s, d in runs for op in (
+        ("%fusion.1", s * ms, d * ms // 2),
+        ("%fusion.2", s * ms + d * ms // 2, d * ms - d * ms // 2))]
+    if idle_ends:
+        modules.insert(0, ("jit_benchmark_clock_marker(1)", 0, 1000))
+        ops.insert(0, ("%add.1", 0, 1000))
+        end = max(s + d for s, d in runs) + 50
+        modules.append(("jit_other(2)", end * ms, ms))
+        ops.append(("%fusion.7", end * ms, ms))
+    return {"device": "/device:TPU:0", "ops": ops, "modules": modules}
+
+
+WHOLE = [(100, 300), (500, 300), (1200, 300)]
+
+
+@pytest.mark.parametrize("plane, runs, per_run_ms", [
+    # the window started and stopped between programs: every run is whole
+    (_plane(WHOLE), 3, 300.0),
+    # a program in flight when the profile started: its last 120 ms are
+    # the plane's first event, the marker waits behind it
+    (_plane([(0, 120)] + WHOLE + [(1600, 1)]), 4, 225.25),
+    # a program in flight when it stopped: its first 110 ms come last
+    (_plane([(40, 5)] + WHOLE + [(1600, 110)], idle_ends=False), 3, 300.0),
+    # both at once, and a window that holds no whole run at all
+    (_plane([(0, 120)] + WHOLE + [(1600, 110)], idle_ends=False), 3, 300.0),
+    (_plane([(0, 120), (500, 110)], idle_ends=False), 0, None)],
+    ids=["cut_nowhere", "cut_at_the_start", "cut_at_the_end", "cut_at_both",
+         "no_whole_run"])
+def test_device_readers_count_whole_program_runs_only(plane, runs, per_run_ms):
+    """A run the profile's edge cut is no run: it goes, with its ops,
+    before runs and device time are counted. (In the second case the
+    marker is not first, so the cut run touches the extent and goes;
+    the 1 ms program after the three is whole and counts.)"""
+    from benchmarks.readers import device_events, xplane_time
+
+    spec = {"module": "predict_topk_batch", "per": "module_runs",
+            "scale": 1000.0}
+    ev = {"planes": [plane]}
+    ops, counted = device_events(ev, spec)
+    assert counted == runs
+    got = xplane_time.read(spec, ev)
+    assert got == (None if per_run_ms is None else pytest.approx(per_run_ms))
+    if runs:
+        assert xplane.busy_seconds(ops) * 1e3 == pytest.approx(
+            per_run_ms * runs)
+    # a kind whose profile starts and stops around its own work counts
+    # every run, the plane's first and last among them
+    every = len(xplane.matching(plane["modules"], spec["module"]))
+    assert device_events({**ev, "trace_edges": "idle"}, spec)[1] == every
+
+
 def test_xplane_reduction_on_the_recorded_trace():
     """``data/small_loop.xplane.pb``: three runs of an 8-step jitted
     scan on one TPU v5e (``tools/dump_xplane.py --record``)."""
@@ -256,6 +328,43 @@ def test_costs_and_roofline():
     assert r["bound"] == "flops" and r["share_pct"] == pytest.approx(50.0)
     r = costs.roofline({"flops": 1e9, "bytes": 1e11}, 4.0, peaks)
     assert r["bound"] == "bytes" and r["share_pct"] == pytest.approx(25.0)
+
+
+@pytest.mark.parametrize("config, width", [
+    ({"rank": 64, "items": 500}, 4),          # a file that does not say
+    ({"rank": 64, "items": 500, "score_operand_bytes": 4}, 4),
+    ({"rank": 64, "items": 500, "score_operand_bytes": 2}, 2)])
+def test_recommend_topk_prices_the_guaranteed_operand_width(config, width):
+    """By hand at batch 3: 2 B I K flops; the table once at the width
+    the configuration guarantees, a float32 score written and read."""
+    c = costs.recommend_topk(config, batch=3.0)
+    assert c["flops"] == 2 * 3 * 500 * 64
+    assert c["bytes"] == 500 * 64 * width + 2 * 3 * 500 * 4
+
+
+@pytest.mark.parametrize("path, stated", [
+    ("benchmarks/configs/als_amazon23_books_r128.json", 2),
+    ("benchmarks/configs/als_ml20m_r32.json", None),
+    ("benchmarks/configs/als_ml20m_r32_500k.json", None),
+    ("benchmarks/configs/als_ml20m_r200.json", None),
+    ("tests/benchmarks/rehearsal/benchmarks/configs/rehearsal_tiny.json",
+     None)])
+def test_configurations_price_the_width_they_state(path, stated):
+    """Books guarantees scores from bfloat16 operands and is priced at
+    2 bytes a table entry: 1.2566 GB a dispatch at the mean batch of
+    3.7 where the float32 price was 2.383. The ML-20M and rehearsal
+    configurations say nothing and price 4."""
+    with open(os.path.join(ROOT, path)) as f:
+        config = json.load(f)
+    assert config.get("score_operand_bytes") == stated
+    c = costs.recommend_topk(config, batch=3.7)
+    assert c["bytes"] == pytest.approx(config["items"] * (
+        config["rank"] * (stated or 4) + 2 * 3.7 * 4), rel=1e-12)
+    if stated:
+        assert "bfloat16 operands" in config["guarantees"]
+        assert round(c["bytes"] / 1e9, 4) == 1.2566
+        assert round((c["bytes"] + 2 * config["items"] * config["rank"])
+                     / 1e9, 3) == 2.383
 
 
 # -- references against the program ------------------------------------------

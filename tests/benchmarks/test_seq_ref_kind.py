@@ -14,6 +14,8 @@ import shutil
 import subprocess
 import sys
 
+import types
+
 import numpy as np
 import pytest
 
@@ -23,8 +25,8 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 from benchmarks.harness import (  # noqa: E402
     costs_mla_moe, seq_ref_check, seq_ref_data)
 from benchmarks.harness.manifest import load_cell, load_json  # noqa: E402
-from benchmarks.kinds import serve_seq_ref_open  # noqa: E402
 from benchmarks.readers import read_metric  # noqa: E402
+from benchmarks.reference import deepseek_v2_jnp  # noqa: E402
 
 CELL = "deepseekv2_l5_seqrec.serve_history8k"
 NEW = ("mla_moe_forward_device_ms", "mla_moe_forward_mfu",
@@ -52,9 +54,12 @@ PUBLISHED = {
 
 
 # -- the cell's files --------------------------------------------------------
+# What has to hold of this cell in a checkout at ``root``, whatever other
+# cells, configurations and metrics the manifest there has:
+# ``test_additions_by_files.py`` holds a copy with a fifth cell to the same.
 
-def test_configuration_keeps_the_published_keys():
-    config = load_cell(CELL).config
+def configuration_keeps_the_published_keys(root: str = ROOT) -> None:
+    config = load_cell(CELL, root=root).config
     differs = {k for k, v in PUBLISHED.items() if config.get(k, "absent") != v}
     assert differs == set(config["reduced"]) == \
         {"num_hidden_layers", "n_routed_experts", "vocab_size"}
@@ -78,28 +83,99 @@ def test_configuration_keeps_the_published_keys():
         (1536, 512, 128, 64, 128, 1536)
     assert w.rope_scaling.factor == 40 and \
         w.rope_scaling.original_max_position_embeddings == 4096
-    assert seq_ref_data.reference(config).__name__.endswith("deepseek_v2_jnp")
+    assert seq_ref_data.reference(config) is deepseek_v2_jnp
     assert seq_ref_data.costs(config) is costs_mla_moe
+    assert set(config["counters"].values()) == {
+        "seqMoeAssignments", "seqMoeTokens", "seqMoeMaxExpertLoad"}
+
+
+def manifest_has_the_cell_and_its_metrics(root: str = ROOT) -> None:
+    manifest = load_json(os.path.join(root, "BENCHMARK.json"))
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for name in NEW:
+        assert CELL in by_name[name]["workloads"]
+        assert by_name[name]["moves"] == "query_p50_ms"
+        assert os.path.exists(os.path.join(
+            root, "benchmarks", "layer_metrics", name + ".json"))
+    cell = load_cell(CELL, root=root)
+    listless = [m for m in manifest["per_layer"] if "workloads" not in m]
+    own = [m for m in manifest["per_layer"] if CELL in m.get("workloads", ())]
+    assert len(cell.per_layer) == len(listless) + len(own)
+    assert set(NEW) <= {m["name"] for m in own}
+    assert cell.chips == 1 and cell.traffic["kind"] == "serve_seq_ref_open"
+    assert {e["name"] for e in cell.end_to_end} == {"query_p50_ms", "setup_s"}
+
+
+def test_configuration_keeps_the_published_keys():
+    configuration_keeps_the_published_keys()
 
 
 def test_the_manifest_gained_one_cell_and_its_metrics():
-    manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    by_name = {m["name"]: m for m in manifest["per_layer"]}
-    for name in NEW:
-        assert by_name[name]["workloads"] == [CELL]
-        assert by_name[name]["moves"] == "query_p50_ms"
-        assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "layer_metrics", name + ".json"))
-    cell = load_cell(CELL)
-    listless = [m for m in manifest["per_layer"] if "workloads" not in m]
-    assert len(cell.per_layer) == len(listless) + len(NEW) == 28
-    assert cell.chips == 1 and cell.traffic["kind"] == "serve_seq_ref_open"
-    assert {e["name"] for e in cell.end_to_end} == {"query_p50_ms", "setup_s"}
-    assert len(manifest["workloads"]) == 4
-    assert all(w["chips"] == 1 for w in manifest["workloads"])
-    # the other session cell reports none of them
-    assert not set(NEW) & {m["name"] for m in load_cell(
-        "brumby14b_l4_seqrec.serve_history16k").per_layer}
+    manifest_has_the_cell_and_its_metrics()
+
+
+# -- the configuration's counters, window values and limits ------------------
+
+def _server(snapshot: dict):
+    stats = types.SimpleNamespace(snapshot=lambda: snapshot)
+    return types.SimpleNamespace(
+        service=types.SimpleNamespace(serving_stats=stats))
+
+
+@pytest.mark.parametrize("named, extra", [
+    ({}, {}),                                       # a file without the key
+    ({"seq_moe_tokens": "seqMoeTokens"}, {"seq_moe_tokens": 7}),
+    # a name the program's /stats.json lacks (the parent commit) is left out
+    ({"seq_moe_tokens": "seqMoeTokens", "blocks": "seqBlocksChosen"},
+     {"seq_moe_tokens": 7})])
+def test_seq_counters_reads_the_names_the_configuration_lists(named, extra):
+    server = _server({"seqPrograms": 3, "seqTokens": 30, "seqPaddedTokens": 40,
+                      "seqSplitDispatches": 1, "seqMoeTokens": 7,
+                      "seqMoeAssignments": 9})
+    dispatch = {"seq_programs": 3, "seq_tokens": 30, "seq_padded_tokens": 40,
+                "seq_split_dispatches": 1}
+    config = {"counters": named} if named else {}
+    assert seq_ref_data.seq_counters(server, config) == {**dispatch, **extra}
+
+
+def test_window_values_come_from_the_cost_module_the_file_names():
+    counters = {"seq_programs": 2, "seq_moe_tokens": 100,
+                "seq_moe_assignments": 300, "seq_moe_max_expert_load": 20}
+    config = {"costs": "costs_mla_moe", "num_hidden_layers": 3,
+              "first_k_dense_replace": 1, "experts_held": [0, 4]}
+    want = {"moe_assignments_per_program": 150.0,
+            "routed_assignments_per_token": 1.5,
+            "expert_load_max_over_mean": 20 * 2 * 4 / 300}
+    assert costs_mla_moe.window_values(counters, config) == want
+    assert seq_ref_data.window_values(counters, config) == want
+    # a cost module without the function, or no module, adds nothing
+    assert seq_ref_data.window_values(counters, {"costs": "costs_seq"}) == {}
+    assert seq_ref_data.window_values(counters, {}) == {}
+
+
+def test_a_reference_without_near_ties_is_its_one_resolution():
+    plain = types.SimpleNamespace(
+        SCORE_TOL=0.1, RANK_TOL=0.2,
+        last_logits=lambda weights, history, config: "logits")
+    assert list(seq_ref_check.resolutions(plain, None, None, {})) == \
+        [("logits", 0.0)]
+    asked = {}
+
+    def resolutions(weights, history, config, **limits):
+        asked.update(limits)
+        return iter(())
+
+    ties = types.SimpleNamespace(NEAR_TIE=0.07, MAX_STEPS=9,
+                                 resolutions=resolutions)
+    assert list(seq_ref_check.resolutions(ties, None, None, {})) == []
+    assert asked == {"near_tie": 0.07, "max_steps": 9}
+    # the cell's reference carries the limits PR 31 fitted, and nothing
+    # in the check module stands in for them
+    assert (deepseek_v2_jnp.SCORE_TOL, deepseek_v2_jnp.RANK_TOL,
+            deepseek_v2_jnp.NEAR_TIE, deepseek_v2_jnp.MAX_STEPS) == \
+        (0.4, 0.8, 0.1, 400)
+    assert not {"SCORE_TOL", "RANK_TOL", "NEAR_TIE", "MAX_STEPS"} \
+        & set(vars(seq_ref_check))
 
 
 # -- costs -------------------------------------------------------------------
@@ -186,7 +262,7 @@ def _evidence(config):
             "peaks": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
             "counters": counters,
             "values": {"seq_tokens_per_program": 8192.0,
-                       **serve_seq_ref_open.routed_values(counters, config)}}
+                       **costs_mla_moe.window_values(counters, config)}}
 
 
 def test_new_metrics_read_a_synthetic_trace():
@@ -226,8 +302,7 @@ def test_new_metrics_find_nothing_without_the_program():
     ev["planes"][0]["modules"] = [("jit_recommend_topk_rows(1)", 0, 10)]
     ev["counters"] = {"seq_programs": 4, "seq_padded_tokens": 4 * 8192}
     ev["values"] = {"seq_tokens_per_program": 8192.0,
-                    **serve_seq_ref_open.routed_values(ev["counters"],
-                                                       config)}
+                    **costs_mla_moe.window_values(ev["counters"], config)}
     assert [read_metric(name, ev) for name in NEW] == [None] * 8
     # a configuration that names no cost module reads no share either
     ev = _evidence({k: v for k, v in config.items() if k != "costs"})
@@ -251,20 +326,21 @@ def test_an_answer_has_to_agree_with_one_resolution_whole():
 
     found = [(logits, 0.0), (other, 0.02)]
     why, worst, tried, cost = seq_ref_check.hold_to_resolutions(
-        iter(found), history, top(logits), 10)
+        iter(found), history, top(logits), 10, deepseek_v2_jnp)
     assert (why, tried, cost) == (None, 1, 0.0) and worst["score_diff"] < 0.02
     why, worst, tried, cost = seq_ref_check.hold_to_resolutions(
-        iter(found), history, top(other), 10)
+        iter(found), history, top(other), 10, deepseek_v2_jnp)
     assert (why, tried, cost) == (None, 2, 0.02)
     # half of one and half of the other agrees with neither
     mixed = sorted(top(logits)[:5] + [p for p in top(other)
                                       if p[0] not in dict(top(logits)[:5])][:5],
                    key=lambda p: -p[1])
     why, _, tried, cost = seq_ref_check.hold_to_resolutions(
-        iter(found), history, mixed, 10)
+        iter(found), history, mixed, 10, deepseek_v2_jnp)
     assert why is not None and (tried, cost) == (2, -1.0)
     assert seq_ref_check.hold_to_resolutions(
-        iter(found[:1]), history, top(other), 10)[0] is not None
+        iter(found[:1]), history, top(other), 10, deepseek_v2_jnp)[0] \
+        is not None
 
 
 @pytest.mark.parametrize("shift, swap, verdict", [
@@ -285,17 +361,26 @@ def test_check_one_holds_an_answer_to_this_kinds_limits(shift, swap, verdict):
         answer = answer[:9]
     elif swap:      # an item ranked far below the tenth, with its own score
         answer[9] = (int(order[swap]), float(logits[order[swap]]))
-    why, worst = seq_ref_check.check_one(logits, history, answer, 10)
+    def held_to(reference):
+        return seq_ref_check.hold_to_resolutions(
+            iter([(logits, 0.0)]), history, answer, 10, reference)
+
+    why, worst, tried, _ = held_to(deepseek_v2_jnp)
+    assert tried == 1
     assert (why is None) if verdict is None else (verdict in why)
-    assert seq_ref_check.SCORE_TOL == 0.4 and seq_ref_check.RANK_TOL == 0.8
+    # the limits are the reference module's: under a tighter module the
+    # same answer is held to that one's
+    if shift == 0.3:
+        assert "score off" in held_to(types.SimpleNamespace(
+            SCORE_TOL=0.05, RANK_TOL=0.1))[0]
     if verdict is None:
         assert worst["score_diff"] == pytest.approx(shift, abs=1e-6)
 
 
 def test_routed_values_are_empty_without_the_counters():
     config = load_cell(CELL).config
-    assert serve_seq_ref_open.routed_values({"seq_programs": 3}, config) == {}
-    assert serve_seq_ref_open.routed_values(
+    assert costs_mla_moe.window_values({"seq_programs": 3}, config) == {}
+    assert costs_mla_moe.window_values(
         {"seq_programs": 3, "seq_moe_tokens": 0, "seq_moe_assignments": 0,
          "seq_moe_max_expert_load": 0}, config) == {}
 
