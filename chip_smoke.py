@@ -174,7 +174,62 @@ def kernels_child() -> int:
         print(f"flash S={s}: max|diff|={err:.3e} finite={finite} "
               f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
     return 0 if (ok and retention_kernel_ok() and two_stage_topk_ok()
-                 and mla_kernels_ok()) else 1
+                 and mla_kernels_ok() and sparse_kernels_ok()) else 1
+
+
+def sparse_kernels_ok() -> bool:
+    """The ``minicpm_sala`` kind's two kernels at its cell's shape (S
+    32,768, 32 query heads over 2 key/value heads of 128), each against
+    its plain form on the last 256 positions: block scores from
+    compressed keys, and attention over the blocks the positions kept."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    s, G, R, d, tail = 32768, 2, 16, 128, 256
+    sz = sa.SparseSizes()
+    if not sa.uses_kernel(s, True, sz, d, R):
+        print("sparse: the rule does not choose the kernels")
+        return False
+    keys = jax.random.split(jax.random.PRNGKey(s), 3)
+    q = (jax.random.normal(keys[0], (1, s, G * R * d)) * 4 / d ** 0.5
+         ).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, s, G * d)).astype(jnp.bfloat16)
+            for key in keys[1:])
+    pos = jnp.arange(s)
+    t0 = time.perf_counter()
+    scores = jax.block_until_ready(sa.selection_scores(q, k, sz=sz, groups=G))
+    t1 = time.perf_counter()
+    want = sa.block_scores(
+        q.reshape(1, s, G, R, d)[:, -tail:],
+        sa.compressed_keys(k.reshape(1, s, G, d), sz), pos[-tail:], sz,
+        sa.n_blocks(s, sz))
+    err = float(jnp.max(jnp.abs(scores[:, :, -tail:] - want)))
+    ok = err < 1e-2                      # sums of 16 heads' probabilities
+    print(f"sparse selection S={s}: max|diff|={err:.3e} "
+          f"first_call_s={t1 - t0:.2f} {'ok' if ok else 'MISMATCH'}")
+    kept = sa.select_blocks(scores, pos, sz)
+    out, counts, _ = jax.jit(
+        lambda q, k, v: sa.attend(q, k, v, sz, groups=G, inference=True))(
+            q, k, v)
+    heads = (1, s, G, R, d)
+    sc = jnp.einsum("btgrd,bsgd->bgrts", q.reshape(heads)[:, -tail:],
+                    k.reshape(1, s, G, d), preferred_element_type=jnp.float32)
+    seen = (pos[None, :] <= pos[-tail:, None]) & jnp.repeat(
+        kept[:, :, -tail:], sz.block_size, axis=-1)[:, :, None]
+    p = jax.nn.softmax(jnp.where(seen, sc, -1e30), axis=-1)
+    plain = jnp.einsum("bgrts,bsgd->btgrd", p.astype(v.dtype),
+                       v.reshape(1, s, G, d),
+                       preferred_element_type=jnp.float32)
+    err = float(jnp.max(jnp.abs(out[:, -tail:].astype(jnp.float32)
+                                - plain.reshape(1, tail, -1))))
+    good = bool(jnp.all(jnp.isfinite(out.astype(jnp.float32)))) and \
+        err <= FLASH_TOL * 4 and int(counts[0]) == s * G
+    print(f"sparse attention S={s}: max|diff|={err:.3e} rows, blocks, "
+          f"key blocks scored {counts.tolist()} "
+          f"{'ok' if good else 'MISMATCH'}")
+    return ok and good
 
 
 def mla_kernels_ok() -> bool:

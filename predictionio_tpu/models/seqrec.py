@@ -14,7 +14,12 @@ reference is benchmarks/reference/brumby_jnp.py; "deepseek_v2" is the
 DeepSeek-V2 block (latent attention from ops/mla_attention.py, one
 leading dense SwiGLU layer, then routed + shared experts from
 ops/moe.py, of which this chip holds a share), whose reference is
-benchmarks/reference/deepseek_v2_jnp.py.
+benchmarks/reference/deepseek_v2_jnp.py; "minicpm_sala" is the
+MiniCPM-SALA block (a list of mixers a layer: block-sparse attention
+chosen per query position from ops/sparse_attention.py beside lightning
+attention, the degree-1 member of ops/retention.py, under depth, embedding
+and head scalings), whose reference is
+benchmarks/reference/minicpm_sala_jnp.py.
 
 TPU-first design:
 - matmuls run in bf16 on the MXU (params and softmax/LN statistics stay
@@ -110,6 +115,67 @@ class MlaMoeWidths:
         return cls(**obj)
 
 
+#: MiniCPM-SALA's published layer list (``mixer_types``)
+_SALA_MIXERS = tuple(
+    "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "lightning-attn"
+    for i in range(32))
+
+
+@dataclasses.dataclass(frozen=True)
+class SalaWidths:
+    """What the "minicpm_sala" kind adds to the widths every kind has
+    (``d_model``, ``n_heads`` over ``n_kv_heads`` of ``head_dim`` for
+    the sparse mixer, ``d_ff``, ``rope_theta``, ``rms_eps``): keys as
+    published, defaults MiniCPM-SALA's; the seven sparse sizes are the
+    MiniCPM4 family's ``sparse_config``. One frozen, hashable record,
+    held in one field by ``SeqRecConfig`` and by the session template's
+    ``AlgorithmParams`` (``engine.json``: ``"sala": {...}``)."""
+    #: the mixer of each layer held here, in order: "minicpm4" (sparse
+    #: attention) or "lightning-attn"
+    mixer_types: tuple = _SALA_MIXERS
+    lightning_nh: int = 32
+    lightning_nkv: int = 32
+    lightning_head_dim: int = 128
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    dim_model_base: int = 256
+    #: the published depth: a layer's residual scale is ``scale_depth /
+    #: sqrt`` of it, however many layers are held here
+    published_layers: int = 32
+    #: what the ``q_norm`` / ``k_norm`` weights are drawn at (a choice of
+    #: weights: trained models sharpen their attention by these)
+    qk_norm_init: float = 1.0
+
+    @classmethod
+    def of(cls, obj) -> "SalaWidths | None":
+        """The record from what ``engine.json`` carries (a JSON object),
+        from itself, or None."""
+        if obj is None or isinstance(obj, cls):
+            return obj
+        obj = dict(obj)
+        if "mixer_types" in obj:
+            obj["mixer_types"] = tuple(obj["mixer_types"])
+        return cls(**obj)
+
+    @property
+    def sparse(self):
+        from predictionio_tpu.ops.sparse_attention import SparseSizes
+
+        return SparseSizes(**{f.name: getattr(self, f.name)
+                              for f in dataclasses.fields(SparseSizes)})
+
+    @property
+    def residual_scale(self) -> float:
+        return self.scale_depth / math.sqrt(self.published_layers)
+
+
 @dataclasses.dataclass(frozen=True)
 class SeqRecConfig:
     vocab: int              # number of items + 1 (pad)
@@ -130,7 +196,9 @@ class SeqRecConfig:
     #: grouped key/value heads, QK-norm, RoPE, gated power retention,
     #: SwiGLU); "deepseek_v2" is the DeepSeek-V2 block (latent
     #: attention, a leading dense layer, then routed + shared experts),
-    #: whose own widths are ``mla_moe``. The fields below are widths the
+    #: whose own widths are ``mla_moe``; "minicpm_sala" is the
+    #: MiniCPM-SALA block (sparse or lightning attention by layer),
+    #: whose own widths are ``sala``. The fields below are widths the
     #: later kinds read; 0 means "as the first kind derives it"
     block: str = "sasrec"
     n_kv_heads: int = 0         # 0: one key/value head per query head
@@ -148,6 +216,8 @@ class SeqRecConfig:
     param_dtype: Any = jnp.float32
     #: the "deepseek_v2" kind's widths; None for the other kinds
     mla_moe: MlaMoeWidths | None = None
+    #: the "minicpm_sala" kind's widths; None for the other kinds
+    sala: SalaWidths | None = None
 
     @property
     def kv_heads(self) -> int:
@@ -312,6 +382,56 @@ def _init_deepseek_v2(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
     return params
 
 
+def _init_minicpm_sala(key: jax.Array, cfg: SeqRecConfig, dtype: Any) -> dict:
+    """As ``_init_brumby``: every matrix normal / sqrt(fan-in), norm
+    weights one but ``q_norm`` and ``k_norm``, drawn at the constant
+    ``qk_norm_init``. A layer's projections have the widths of its
+    mixer: ``n_heads`` over ``kv_heads`` of ``hd`` for "minicpm4",
+    ``lightning_nh`` over ``lightning_nkv`` of ``lightning_head_dim``
+    (and a norm over the mixer's whole output) for "lightning-attn";
+    both gate their output at full width."""
+    w = cfg.sala
+    d, ff = cfg.d_model, cfg.ff
+    if len(w.mixer_types) != cfg.n_layers:
+        raise ValueError(f"{len(w.mixer_types)} mixers for {cfg.n_layers} "
+                         "layers")
+    keys = jax.random.split(key, 2 + cfg.n_layers)
+
+    def dense(k, m, n):
+        return (jax.random.normal(k, (m, n), dtype=jnp.float32)
+                / math.sqrt(m)).astype(dtype)
+
+    def table(k):
+        return (jax.random.normal(k, (cfg.vocab, d), dtype=jnp.float32)
+                / math.sqrt(d)).astype(dtype)
+
+    params = {"item_emb": table(keys[0]), "head": table(keys[1]),
+              "out_norm": jnp.ones((d,), dtype), "layers": []}
+    for i, mixer in enumerate(w.mixer_types):
+        H, G, hd = (cfg.n_heads, cfg.kv_heads, cfg.hd) \
+            if mixer == "minicpm4" else \
+            (w.lightning_nh, w.lightning_nkv, w.lightning_head_dim)
+        lk = jax.random.split(keys[2 + i], 8)
+        layer = {
+            "in_norm": jnp.ones((d,), dtype),
+            "post_norm": jnp.ones((d,), dtype),
+            "q_norm": jnp.full((hd,), w.qk_norm_init, dtype),
+            "k_norm": jnp.full((hd,), w.qk_norm_init, dtype),
+            "wq": dense(lk[0], d, H * hd),
+            "wk": dense(lk[1], d, G * hd),
+            "wv": dense(lk[2], d, G * hd),
+            "wg": dense(lk[3], d, H * hd),
+            "wo": dense(lk[4], H * hd, d),
+            "w_gate": dense(lk[5], d, ff),
+            "w_up": dense(lk[6], d, ff),
+            "w_down": dense(lk[7], ff, d),
+        }
+        if mixer != "minicpm4":
+            layer["o_norm"] = jnp.ones((H * hd,), dtype)
+        params["layers"].append(layer)
+    return params
+
+
 def _ln(x: jax.Array, g: jax.Array, b: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -330,7 +450,7 @@ def forward(
     """Hidden states (B, S, D) in cfg.dtype from ``cfg.block``'s stack."""
     kind = BLOCKS[cfg.block]
     out = kind.forward(params, seqs, cfg, mesh, seq_axis, inference)
-    return out[0] if kind.routed else out
+    return out[0] if kind.tally else out
 
 
 def _forward_sasrec(params, seqs, cfg, mesh, seq_axis, inference):
@@ -555,6 +675,146 @@ def _forward_deepseek_v2(params, seqs, cfg, mesh, seq_axis, inference):
     return _rms(x, params["out_norm"], eps), assignments
 
 
+def lightning_log_decay(heads: int) -> np.ndarray:
+    """Lightning Attention's per-head slopes (arXiv:2401.04658), the
+    same in every layer: head a = 1..H decays by ``exp(-2**(-8 a / H))``
+    a position; the log of it, float32."""
+    return -(2.0 ** (-8.0 * np.arange(1, heads + 1, dtype=np.float32)
+                     / heads)).astype(np.float32)
+
+
+def _forward_minicpm_sala(params, seqs, cfg, mesh, seq_axis, inference):
+    """The MiniCPM-SALA stack: returns (hidden, counts, kept ids) —
+    int32 (3,) rows that selected, blocks they kept and key blocks
+    scored for them over the sparse layers and real positions
+    (ops/sparse_attention.visit_counts), and (B, sparse layers, key/value
+    heads, topk) the blocks each history's last position kept (-1 after
+    the last; all -1 where the layer does not select). The hidden states
+    carry the head's 1 / (d_model / dim_model_base). Sparse layers have
+    no positions and lightning layers RoPE, both mix causally: right
+    padding needs no mask."""
+    from predictionio_tpu.ops import sparse_attention
+
+    if mesh is not None and seq_axis in mesh.shape and \
+            int(mesh.shape[seq_axis]) > 1:
+        raise NotImplementedError(
+            "the minicpm_sala block has no sequence-parallel form: use a "
+            f"mesh without a {seq_axis!r} axis")
+    w = cfg.sala
+    if w.lightning_nh != w.lightning_nkv:
+        raise NotImplementedError(
+            "lightning attention decays per key/value head: "
+            f"{w.lightning_nh} heads over {w.lightning_nkv}")
+    sz = w.sparse
+    B, S = seqs.shape
+    dt, eps, f32 = cfg.dtype, cfg.rms_eps, jnp.float32
+    c = w.residual_scale
+    valid = seqs != PAD
+    last = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0)
+    log_decay = jnp.asarray(lightning_log_decay(w.lightning_nkv))
+
+    def normed(x, weight, scale=1.0):
+        x32 = x.astype(f32)
+        return x32 * (jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
+                                             keepdims=True) + eps)
+                      * scale) * weight.astype(f32)
+
+    def gated(o, h, layer):
+        gate = jax.nn.sigmoid((h @ layer["wg"].astype(dt)).astype(f32))
+        return (o.astype(f32) * gate).astype(dt) @ layer["wo"].astype(dt)
+
+    def sparse_mixer(h, layer):
+        H, G, hd = cfg.n_heads, cfg.kv_heads, cfg.hd
+        q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
+        k = (h @ layer["wk"].astype(dt)).reshape(B, S, G, hd)
+        v = h @ layer["wv"].astype(dt)
+        q = normed(q, layer["q_norm"], 1.0 / math.sqrt(hd)).astype(dt)
+        k = normed(k, layer["k_norm"]).astype(dt)
+        o, counts, kept = sparse_attention.attend(
+            q.reshape(B, S, H * hd), k.reshape(B, S, G * hd), v, sz,
+            groups=G, valid=valid, inference=inference)
+        if kept is None:
+            ids = jnp.full((B, G, sz.topk), -1, jnp.int32)
+        else:
+            ids = sparse_attention.kept_ids(jnp.take_along_axis(
+                kept, last[:, None, None, None], axis=2)[:, :, 0], sz.topk)
+        return gated(o, h, layer), counts, ids
+
+    def lightning_mixer(h, layer):
+        H, hd = w.lightning_nh, w.lightning_head_dim
+        q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
+        k = (h @ layer["wk"].astype(dt)).reshape(B, S, H, hd)
+        v = (h @ layer["wv"].astype(dt)).reshape(B, S, H, hd)
+        q = _rope(normed(q, layer["q_norm"]), cfg.rope_theta).astype(dt)
+        k = _rope(normed(k, layer["k_norm"]), cfg.rope_theta).astype(dt)
+        o = power_retention(q, k, v, log_decay, degree=1,
+                            inference=inference)
+        o = _rms(o.reshape(B, S, H * hd), layer["o_norm"], eps)
+        return gated(o, h, layer)
+
+    def residual(x, y):
+        return (x.astype(f32) + c * y.astype(f32)).astype(dt)
+
+    def block(x, layer, mixer):
+        h = _rms(x, layer["in_norm"], eps)
+        counts = ids = None
+        if mixer == "minicpm4":
+            with jax.named_scope("minicpm4_mixer"):
+                y, counts, ids = sparse_mixer(h, layer)
+        else:
+            with jax.named_scope("lightning_mixer"):
+                y = lightning_mixer(h, layer)
+        x = residual(x, y)
+        with jax.named_scope("swiglu"):
+            h = _rms(x, layer["post_norm"], eps)
+            return residual(x, _swiglu(h, layer, dt)), counts, ids
+
+    if cfg.remat:
+        block = jax.checkpoint(block, static_argnums=(2,))
+    x = (params["item_emb"][seqs].astype(f32) * w.scale_emb).astype(dt)
+    counts, kept_ids = jnp.zeros((3,), jnp.int32), []
+    for layer, mixer in zip(params["layers"], w.mixer_types):
+        x, layer_counts, ids = block(x, layer, mixer)
+        if ids is not None:
+            counts = counts + layer_counts
+            kept_ids.append(ids)
+    kept_ids = jnp.stack(kept_ids, axis=1) if kept_ids else \
+        jnp.zeros((B, 0, cfg.kv_heads, sz.topk), jnp.int32)
+    head_scale = w.dim_model_base / cfg.d_model
+    hidden = (_rms(x, params["out_norm"], eps).astype(f32)
+              * head_scale).astype(dt)
+    return hidden, counts, kept_ids
+
+
+def _kernels_minicpm_sala(cfg: SeqRecConfig, seq_len: int) -> tuple:
+    from predictionio_tpu.ops import sparse_attention
+
+    w = cfg.sala
+    if "minicpm4" in w.mixer_types and sparse_attention.uses_kernel(
+            seq_len, True, w.sparse, cfg.hd, cfg.n_heads // cfg.kv_heads):
+        return sparse_attention.kernel_names(seq_len, w.sparse)
+    return ()
+
+
+def _tally_deepseek_v2(cfg: SeqRecConfig, tokens: int, per_expert) -> dict:
+    """The routed layers of one program: assignments to held experts
+    over all expert layers, the tokens routed (once, not per layer) and
+    the fullest (layer, expert)'s assignments."""
+    return {"moe_assignments": int(per_expert.sum()), "moe_tokens": tokens,
+            "moe_max_expert_load": int(per_expert.max(initial=0))}
+
+
+def _tally_minicpm_sala(cfg: SeqRecConfig, tokens: int, counts,
+                        kept_ids) -> dict:
+    """The sparse layers of one program: rows that selected (position x
+    sparse layer x key/value head), the blocks they kept, and the keys
+    whose scores stage 2 computed for them (the device counts whole
+    blocks)."""
+    return {"sparse_rows": int(counts[0]),
+            "sparse_blocks_selected": int(counts[1]),
+            "sparse_keys_scored": int(counts[2]) * cfg.sala.block_size}
+
+
 def _bytes_deepseek_v2(cfg: SeqRecConfig) -> int:
     """The two phases a layer goes through, in cfg.dtype beside the
     residual stream twice: attention (q, the joint k/v projection and
@@ -602,9 +862,9 @@ def fuses_retention(cfg: SeqRecConfig, seq_len: int) -> bool:
 @dataclasses.dataclass(frozen=True)
 class BlockKind:
     init: Any       # (key, cfg, dtype) -> parameter pytree
-    #: (params, seqs, cfg, mesh, seq_axis, inference) -> hidden; a
-    #: ``routed`` kind returns (hidden, (expert layers, experts held)
-    #: int32 assignments)
+    #: (params, seqs, cfg, mesh, seq_axis, inference) -> hidden; a kind
+    #: with a ``tally`` returns (hidden, *int32 arrays of what the
+    #: program counted on the device)
     forward: Any
     #: cfg -> bytes one token of a serving program holds at its peak
     bytes_per_token: Any
@@ -612,7 +872,12 @@ class BlockKind:
     #: over histories that long engages, by the rules the forward pass
     #: itself applies (static shape and backend)
     kernels: Any = lambda cfg, seq_len: ()
-    routed: bool = False
+    #: None, or (cfg, tokens of the program, *those arrays on the host)
+    #: -> {field of templates/sessionrec.SeqDispatch: what to add}: the
+    #: one road by which a kind's program reports counters beside scores
+    #: and ids ("deepseek_v2": the routed layers' assignments;
+    #: "minicpm_sala": the sparse layers' selection)
+    tally: Any = None
 
 
 #: the block kinds a configuration can name (``SeqRecConfig.block``)
@@ -622,7 +887,10 @@ BLOCKS = {
                         _residual_and_ff_bytes, _kernels_brumby),
     "deepseek_v2": BlockKind(_init_deepseek_v2, _forward_deepseek_v2,
                              _bytes_deepseek_v2, _kernels_deepseek_v2,
-                             routed=True),
+                             tally=_tally_deepseek_v2),
+    "minicpm_sala": BlockKind(_init_minicpm_sala, _forward_minicpm_sala,
+                              _residual_and_ff_bytes, _kernels_minicpm_sala,
+                              tally=_tally_minicpm_sala),
 }
 
 
@@ -951,19 +1219,21 @@ def predict_topk_batch(
 ) -> tuple[jax.Array, ...]:
     """Like :func:`predict_topk` but with a per-query additive logit mask
     ``vocab_masks`` (B, V) — the batched eval path, where each query
-    carries its own seen/black-list exclusions. A kind that routes
-    tokens to experts (``BlockKind.routed``) returns a third value: the
-    (expert layers, experts held) int32 assignments of the program."""
+    carries its own seen/black-list exclusions. A kind whose program
+    counts on the device (``BlockKind.tally``) returns its int32 arrays
+    after scores and ids: "deepseek_v2" the (expert layers, experts
+    held) assignments, "minicpm_sala" the selection's counts and the
+    blocks each last position kept."""
     mask = (history != PAD)
     last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
     kind = BLOCKS[cfg.block]
     h = kind.forward(params, history, cfg, None, "seq", True)
-    h, *assignments = h if kind.routed else (h,)
+    h, *counted = h if kind.tally else (h,)
     hl = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     logits = jnp.einsum("bd,vd->bv", hl, head_table(params).astype(h.dtype),
                         preferred_element_type=jnp.float32)
     logits = logits + vocab_masks
-    return (*jax.lax.top_k(logits, k), *assignments)
+    return (*jax.lax.top_k(logits, k), *counted)
 
 
 def predict_topk(

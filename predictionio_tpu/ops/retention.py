@@ -21,7 +21,19 @@ state, the normaliser, the gates' cumulative sums and every weight are
 float32. Padding after a history's last event cannot change an earlier
 position: the mixing is causal and chunks are scanned in order.
 
-Two ways through a chunk step's state pass (the read ``phi(q)^T S`` and
+The family's degree-1 member is lightning attention (arXiv:2401.04658),
+unnormalised: ``y[t, a] = sum_i decay(i..t) (q[t, a] . k[i, b] /
+sqrt(d)) v[i, b]``, no feature map (the state is ``sum_i decay * k_i
+v_i^T``, ``d x d`` per key/value head), no normaliser, and a decay that
+may be one constant per head (``log_g`` of shape ``(G,)``). It shares
+:func:`pick_chunk`, the chunk-major layout, the in-chunk decay matrix and
+the ``lax.scan`` carry with degree 2 and nothing else: its state pass is
+two small products in ``jax.numpy`` and is not fused (at d = 128 it is
+under a fortieth of degree 2's). So the family holds degrees 1 and 2:
+degree 2 normalised, its state pass fused on a compiled TPU backend;
+degree 1 unnormalised, ``jax.numpy`` throughout.
+
+Two ways through a degree-2 chunk step's state pass (the read ``phi(q)^T S`` and
 the update ``keep * S + phi(k) (v * left)``), one set of equations. The
 ``jax.numpy`` one (:func:`_read_state`, :func:`_phi_keys`) is the
 definition: JAX's autodiff differentiates it, so it is the path of
@@ -147,19 +159,28 @@ def power_retention(
 ) -> jax.Array:
     """The mixing above; returns (B, S, H, d) in ``q.dtype``.
 
-    ``chunk`` is chosen from the sequence length when not given (tests
+    ``degree`` 2 is the normalised form of the module's first equations;
+    ``degree`` 1 the unnormalised linear form (lightning attention),
+    whose ``log_g`` may also be ``(G,)``: one constant decay per
+    key/value head. ``eps`` belongs to the normaliser and so to degree 2
+    alone. ``chunk`` is chosen from the sequence length when not given (tests
     pass small ones). ``inference`` says that the caller takes no
     gradient: the state pass may then run in the forward-only kernel
     (:func:`fuses_state_pass`). ``_state_dtype`` exists for one test,
     which shows that a state accumulated in bfloat16 is caught."""
-    if degree != 2:
+    if degree not in (1, 2):
         raise NotImplementedError(
-            f"power retention of degree {degree}: only degree 2 has a "
-            "feature map here")
+            f"power retention of degree {degree}: the family holds degree "
+            "1 (unnormalised, no feature map, jax.numpy state pass) and "
+            "degree 2 (normalised, the block-triangular feature map, "
+            "state pass fused on a compiled TPU backend)")
     if q.shape[2] % k.shape[2]:
         raise ValueError(
             f"{q.shape[2]} query heads over {k.shape[2]} key/value heads")
     chunk = chunk or pick_chunk(q.shape[1])
+    if degree == 1:
+        with jax.named_scope("lightning_attention"):
+            return _linear_retention(q, k, v, log_g, chunk, _state_dtype)
     kernel = "compiled" if fuses_state_pass(
         q.shape[3], q.shape[2] // k.shape[2], chunk, inference=inference,
         state_dtype=_state_dtype) else None
@@ -168,9 +189,12 @@ def power_retention(
                                 kernel)
 
 
-def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
-    """``kernel``: None for the ``jax.numpy`` state pass, ``"compiled"``
-    or ``"interpret"`` (tests, on the CPU) for the fused one."""
+def _chunk_major(q, k, v, log_g, C):
+    """Both degrees' layout: the sequence padded to whole chunks of
+    ``C``, then chunk-major with heads before positions, bfloat16
+    operands and float32 gates: q (n, B, G, R, C, d), k and v (n, B, G,
+    C, d), ``log_g`` (B, S, G) as (n, B, G, C) (degree 1 with a constant
+    decay passes none: G = 0 there)."""
     B, S, H, d = q.shape
     G = k.shape[2]
     R = H // G
@@ -185,7 +209,26 @@ def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
     qc = q.reshape(B, n, C, G, R, d).transpose(1, 0, 3, 4, 2, 5).astype(bf16)
     kc = k.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
     vc = v.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
-    gc = log_g.astype(f32).reshape(B, n, C, G).transpose(1, 0, 3, 2)
+    gc = log_g.astype(f32).reshape(B, n, C, log_g.shape[2]) \
+        .transpose(1, 0, 3, 2)
+    return qc, kc, vc, gc
+
+
+def _token_major(y, S, dtype):
+    """(n, B, G, R, C, d) out of the scan -> (B, S, H, d)."""
+    n, B, G, R, C, d = y.shape
+    y = y.transpose(1, 0, 4, 2, 3, 5).reshape(B, n * C, G * R, d)
+    return y[:, :S].astype(dtype)
+
+
+def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
+    """``kernel``: None for the ``jax.numpy`` state pass, ``"compiled"``
+    or ``"interpret"`` (tests, on the CPU) for the fused one."""
+    B, S, H, d = q.shape
+    G = k.shape[2]
+    R = H // G
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    qc, kc, vc, gc = _chunk_major(q, k, v, log_g, C)
     causal = np.tril(np.ones((C, C), bool))
     inv_d = 1.0 / d
     hi = lax.Precision.HIGHEST
@@ -241,5 +284,56 @@ def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
     init = (jnp.zeros((*state_shape, phi_width(d), d), state_dtype),
             jnp.zeros((B, G, d, d), state_dtype))
     _, y = lax.scan(step, init, (qc, kc, vc, gc))           # (n, B, G, R, C, d)
-    y = y.transpose(1, 0, 4, 2, 3, 5).reshape(B, n * C, H, d)
-    return y[:, :S].astype(q.dtype)
+    return _token_major(y, S, q.dtype)
+
+
+def _linear_retention(q, k, v, log_g, C, state_dtype):
+    """Degree 1 through the same chunks: inside a chunk ``((q k^T) *
+    decay / sqrt(d)) v``, between chunks one (d, d) float32 state per
+    key/value head, read by the chunk's queries and then decayed to the
+    chunk's end with its keys and values added. A ``log_g`` of shape
+    (G,) is one constant decay per head: the decay matrix is then the
+    same in every chunk and is worked once, outside the scan."""
+    B, S, H, d = q.shape
+    G = k.shape[2]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    constant = log_g.ndim == 1
+    qc, kc, vc, gc = _chunk_major(
+        q, k, v, jnp.zeros((B, S, 0), f32) if constant else log_g, C)
+    causal = np.tril(np.ones((C, C), bool))
+    scale = 1.0 / np.sqrt(d)
+
+    def decays(gi):
+        """From a chunk's log gates (b, G, C): the in-chunk decay matrix
+        times the scale, and what carries the state in (per position),
+        the keys to the chunk's end, and the state itself over it."""
+        cum = jnp.cumsum(gi, axis=-1)
+        total = cum[..., -1]
+        inside = cum[..., :, None] - cum[..., None, :]
+        inside = jnp.exp(jnp.where(causal, inside, -jnp.inf)) * scale
+        return (inside[:, :, None], jnp.exp(cum)[:, :, None, :, None] * scale,
+                jnp.exp(total[..., None] - cum)[..., None],
+                jnp.exp(total)[..., None, None])
+
+    if constant:
+        fixed = decays(jnp.broadcast_to(log_g.astype(f32)[None, :, None],
+                                        (1, G, C)))
+
+    def step(state, xs):
+        qi, ki, vi, gi = xs
+        inside, carried, left, keep = fixed if constant else decays(gi)
+        s = jnp.einsum("bgrtd,bgsd->bgrts", qi, ki,
+                       preferred_element_type=f32)
+        y = jnp.einsum("bgrts,bgse->bgrte", (s * inside).astype(bf16), vi,
+                       preferred_element_type=f32)
+        y = y + carried * jnp.einsum(
+            "bgrtd,bgde->bgrte", qi, state.astype(bf16),
+            preferred_element_type=f32)
+        vl = (vi.astype(f32) * left).astype(bf16)
+        state = keep * state.astype(f32) + jnp.einsum(
+            "bgsd,bgse->bgde", ki, vl, preferred_element_type=f32)
+        return state.astype(state_dtype), y
+
+    _, y = lax.scan(step, jnp.zeros((B, G, d, d), state_dtype),
+                    (qc, kc, vc, gc))
+    return _token_major(y, S, q.dtype)

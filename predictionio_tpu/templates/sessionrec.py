@@ -25,6 +25,7 @@ Usage (engine.json):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Mapping, Sequence
 
@@ -183,10 +184,14 @@ class AlgorithmParams(Params):
     # the "deepseek_v2" backbone's own widths (seqrec.MlaMoeWidths;
     # engine.json carries a JSON object with the published keys)
     mla_moe: Any = None
+    # the "minicpm_sala" backbone's own widths (seqrec.SalaWidths), the
+    # same way
+    sala: Any = None
 
     def __post_init__(self):
         object.__setattr__(self, "mla_moe",
                            seqrec.MlaMoeWidths.of(self.mla_moe))
+        object.__setattr__(self, "sala", seqrec.SalaWidths.of(self.sala))
 
     def seqrec_config(self, vocab: int) -> seqrec.SeqRecConfig:
         import jax.numpy as jnp
@@ -199,7 +204,8 @@ class AlgorithmParams(Params):
             rope_theta=self.rope_theta, rms_eps=self.rms_eps,
             retention_degree=self.retention_degree,
             tie_embeddings=self.tie_embeddings,
-            param_dtype=jnp.dtype(self.param_dtype), mla_moe=self.mla_moe)
+            param_dtype=jnp.dtype(self.param_dtype), mla_moe=self.mla_moe,
+            sala=self.sala)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,13 +220,22 @@ class SeqDispatch:
     #: of the programs, those whose retention layers ran the fused
     #: state pass (seqrec.fuses_retention)
     fused_retention_programs: int = 0
-    #: the routed layers (a kind with ``BlockKind.routed``): assignments
-    #: to held experts over all expert layers, the tokens routed (once,
-    #: not per layer), and the fullest (layer, expert)'s assignments,
-    #: summed over the programs
+    #: what a kind's programs counted on the device, by the kind's own
+    #: ``BlockKind.tally``, summed over the programs. The routed layers
+    #: ("deepseek_v2"): assignments to held experts over all expert
+    #: layers, the tokens routed (once, not per layer), and the fullest
+    #: (layer, expert)'s assignments
     moe_assignments: int = 0
     moe_tokens: int = 0
     moe_max_expert_load: int = 0
+    #: the sparse layers ("minicpm_sala"): rows that selected (position
+    #: x sparse layer x key/value head; 0 where the history is at or
+    #: under ``dense_len``), the key blocks they kept, and the keys
+    #: whose scores stage 2 computed for them (from the visit map the
+    #: kernel was given)
+    sparse_rows: int = 0
+    sparse_blocks_selected: int = 0
+    sparse_keys_scored: int = 0
 
 
 @dataclasses.dataclass
@@ -358,7 +373,8 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         # one answer for every program: the rule looks at the widths and
         # at the history length, not at the batch
         fused = seqrec.fuses_retention(model.cfg, S)
-        routed = np.zeros(3, np.int64)  # assignments, tokens, fullest expert
+        tally = seqrec.BLOCKS[model.cfg.block].tally
+        counted = collections.Counter()     # SeqDispatch field -> sum
         while pos < len(rows):
             bucket = 1
             while bucket * 2 <= min(len(rows) - pos, widest):
@@ -369,10 +385,9 @@ class SeqRecAlgorithm(HostModelAlgorithm):
             with span("dispatch.enqueue"):
                 program = start_copies(seqrec.predict_topk_batch(
                     tree, padded[part], k, model.cfg, masks[part]))
-            scores, ids, *assignments = await_and_fetch(program)
-            for per_expert in assignments:      # a routed kind: one array
-                routed += (per_expert.sum(), bucket * S,
-                           per_expert.max(initial=0))
+            scores, ids, *arrays = await_and_fetch(program)
+            if tally:
+                counted.update(tally(model.cfg, bucket * S, *arrays))
             with span("dispatch.results"):
                 for (i, q), svals, sids in zip(rows[part], scores, ids):
                     items = []
@@ -388,8 +403,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                 programs=programs, tokens=int(lengths.sum()),
                 padded_tokens=len(rows) * S, split=int(len(rows) > widest),
                 fused_retention_programs=programs if fused else 0,
-                moe_assignments=int(routed[0]), moe_tokens=int(routed[1]),
-                moe_max_expert_load=int(routed[2])))
+                **counted))
         return out
 
 

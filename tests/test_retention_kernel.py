@@ -324,3 +324,20 @@ def test_mosaic_builds_both_ends_of_the_envelope(one_chip):
                 sds((n, chunk, D), bf16), sds((n,), F32),
                 sds((n, retention.phi_width(D), D), F32))
         assert "tpu_custom_call" in lowered.compile().as_text()
+    # the sparse layer's two kernels (ops/sparse_attention.py) at the
+    # MiniCPM-SALA cell's shape, in this test for the reason above
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    sz, S, G, R = sa.SparseSizes(), 32768, 2, 16
+    q, k = sds((1, S, G * R * D), bf16), sds((1, S, G * D), bf16)
+    kept = sds((1, G, S, sa.n_blocks(S, sz)), jnp.bool_)
+
+    def stage_2(q, k, v, kept):
+        visits = sa.visit_map(kept, S, sa.TILE_Q, sa.TILE_K, sz.block_size)
+        return sa.attention(q, k, v, kept, visits, groups=G,
+                            block=sz.block_size)
+
+    for fn, args in ((lambda q, k: sa.selection_scores(
+            q, k, sz=sz, groups=G), (q, k)), (stage_2, (q, k, k, kept))):
+        assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile() \
+            .as_text()
