@@ -339,9 +339,10 @@ def two_stage_topk_ok() -> bool:
     (4.4M items, rank 128, ``num`` 10) through ``ALSModel.batch_topk``
     at B = 1, 4, 8, and at B = 4 over a catalog 100 items longer (a
     tail short of a group): answers equal one ``lax.top_k`` over the
-    same masked scores on every finite slot, and the model's observer
-    counts every dispatch (the rule the counter and the program
-    share)."""
+    same masked scores on every finite slot, both read from the
+    model's bfloat16 serving copy of the item table, and the model's
+    observer counts every dispatch (the rule the counter and the
+    program share)."""
     import jax
     import jax.numpy as jnp
 
@@ -375,11 +376,12 @@ def two_stage_topk_ok() -> bool:
             vals, idxs = (np.asarray(a) for a in
                           model.batch_topk(uixs, cols, mask, None, k))
             t1 = time.perf_counter()
+            served = model.serving_item_factors()
             want_v, want_i = (np.asarray(a) for a in single(
-                model.user_factors, uixs, model.item_factors, cols, mask,
-                allow))
+                model.user_factors, uixs, served, cols, mask, allow))
             finite = np.isfinite(want_v)
             good = (np.array_equal(vals, want_v) and bool(finite.all())
+                    and served.dtype == jnp.bfloat16
                     and np.array_equal(idxs[finite], want_i[finite])
                     and len(counted) == n)
             ok &= good

@@ -566,6 +566,7 @@ class EngineService:
     def _wire_ann_observers(self) -> None:
         # getattr: test doubles and minimal deployments may not carry a
         # models list — they simply have no ANN-capable targets
+        table_bytes = 0
         for target in retrieval_targets(
                 getattr(self.deployed, "models", ())):
             if hasattr(target, "set_ann_observer"):
@@ -573,6 +574,13 @@ class EngineService:
             if hasattr(target, "set_topk_observer"):
                 target.set_topk_observer(
                     self.serving_stats.record_two_stage_topk)
+            if (hasattr(target, "score_table_bytes_per_entry")
+                    and not getattr(target, "ann_enabled", False)):
+                # reading it makes the brute path's serving copy of the
+                # item table now, ahead of the first query
+                table_bytes = max(table_bytes,
+                                  target.score_table_bytes_per_entry)
+        self.serving_stats.set_score_table_bytes(table_bytes)
         # the session engine's models report programs and tokens the
         # same way (pio_serving_seq_* on /metrics, seq* on /stats.json)
         for model in getattr(self.deployed, "models", ()):

@@ -67,6 +67,11 @@ class ServingStats:
         #: ANN shortlist width (candidate columns rescored per query,
         #: pad included — the static jit width) -> query count
         self._ann_hist: Counter[int] = Counter()
+        #: byte width of an entry of the table the brute-force
+        #: recommend programs score from (2: the model's bfloat16
+        #: serving copy; 4: a float32 table); 0 until a server wires a
+        #: model that has one, and while it answers through ANN
+        self._score_table_bytes = 0
         #: latency attribution (obs/histogram.py; each histogram owns
         #: its own lock): queue component vs device component of the
         #: batched serving path — the Clipper-style split GET /metrics
@@ -112,6 +117,17 @@ class ServingStats:
         observer hook, models/als.set_topk_observer."""
         self.bump("topk_two_stage_dispatches")
 
+    def set_score_table_bytes(self, width: int) -> None:
+        """The byte width of an entry of the item table brute-force
+        dispatches read (``ALSModel.score_table_bytes_per_entry``): a
+        gauge the engine server sets when it wires its models."""
+        with self._lock:
+            self._score_table_bytes = int(width)
+
+    def score_table_bytes(self) -> int:
+        with self._lock:
+            return self._score_table_bytes
+
     def record_seq_dispatch(self, report) -> None:
         """One ``batch_predict`` of the session engine, as the record
         ``templates/sessionrec.SeqDispatch`` (the SeqRecEngineModel
@@ -151,10 +167,12 @@ class ServingStats:
             hist = {str(k): v for k, v in sorted(self._batch_hist.items())}
             ann_hist = {str(k): v
                         for k, v in sorted(self._ann_hist.items())}
+            table_bytes = self._score_table_bytes
         hits, misses = counts["cache_hits"], counts["cache_misses"]
         looked = hits + misses
         return {
             **{snake_to_camel(k): v for k, v in counts.items()},
+            "scoreTableBytesPerEntry": table_bytes,
             "batchSizeHistogram": hist,
             "annShortlistHistogram": ann_hist,
             "cacheHitRatio": round(hits / looked, 4) if looked else None,

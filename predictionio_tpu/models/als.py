@@ -10,7 +10,10 @@ bundle userFeatures/productFeatures RDDs with the BiMaps.
 Serving-time design: factors stay resident as jax.Arrays between
 requests (no per-query transfer) and queries are answered by the jitted
 fixed-shape kernels in ops/topk — the "models resident in HBM, no
-per-query recompile" requirement of SURVEY.md §7 step 7.
+per-query recompile" requirement of SURVEY.md §7 step 7. The factors
+are float32; the brute-force recommend paths score from a bfloat16
+copy of the item table made once on the device
+(``ALSModel.serving_item_factors``), half the bytes of every scan.
 """
 
 from __future__ import annotations
@@ -140,7 +143,7 @@ def _serve_recommend(user_factors, item_f, packed, allow, k):
     uix = packed[0]
     cols = packed[1 : 1 + _SEEN_PAD][None, :]
     mask = (packed[1 + _SEEN_PAD : 1 + 2 * _SEEN_PAD] > 0
-            ).astype(item_f.dtype)[None, :]
+            ).astype(jnp.float32)[None, :]
     uv = user_factors[uix[None]]                     # (1, K)
     vals, idxs = topk_ops.recommend_topk(uv, item_f, cols, mask, allow, k)
     return jnp.concatenate(
@@ -207,6 +210,14 @@ def _serve_similar(item_f, packed, allow, k):
 
 
 @instrumented_jit
+def _serving_copy(table):
+    """The item table as the brute-force recommend programs read it:
+    bfloat16, half the bytes of a scan (PERF.md §5, PR 35). One
+    elementwise program, so a row-sharded table keeps its sharding."""
+    return table.astype(jnp.bfloat16)
+
+
+@instrumented_jit
 def _take_rows(table, ixs):
     """``table[ixs]`` as one device program: eager indexing launches
     seven (index wrap, broadcasts, the gather), 3-4 ms of host work a
@@ -228,6 +239,14 @@ class ALSModel:
     # costs ~125ms of host+transfer at a 2M-item catalog (measured);
     # never serialized
     _default_allow: object = dataclasses.field(default=None, repr=False,
+                                               compare=False)
+    #: device-cached ``(item_factors, its bfloat16 copy)``: the copy is
+    #: what every brute-force recommend path scores from
+    #: (``serving_item_factors``); made once a table (the pair says which
+    #: table: ``dataclasses.replace(model, item_factors=...)`` carries
+    #: this field over), never serialized: ``item_factors`` stays the
+    #: model
+    _serving_items: object = dataclasses.field(default=None, repr=False,
                                                compare=False)
     #: IVF-flat MIPS index over item_factors (ops/ann.AnnIndex), built
     #: at persist time and serialized beside the factor checkpoint;
@@ -260,6 +279,7 @@ class ALSModel:
     def __getstate__(self):
         state = self.__dict__.copy()
         state["_default_allow"] = None
+        state["_serving_items"] = None
         # the observer is serving wiring (holds the stats lock), not model
         state["_ann_observer"] = None
         state["_topk_observer"] = None
@@ -273,6 +293,34 @@ class ALSModel:
             self._default_allow = jax.device_put(
                 jnp.ones((self.item_factors.shape[0],), dtype=jnp.float32))
         return self._default_allow
+
+    def serving_item_factors(self):
+        """The table the brute-force recommend paths score from: the
+        bfloat16 copy of ``item_factors`` (``item_factors`` itself where
+        it is bfloat16 already), made by one jitted cast on first use
+        and kept for as long as ``item_factors`` is that array. Scores are its products with the user rows (rounded
+        to bfloat16 too where the matrix unit multiplies), accumulated
+        in float32 (ops/topk._scores), so ids may differ from the
+        float32 order only where scores tie within 2^-8 |u| |v|; it
+        costs 2 bytes an entry of device memory beside the float32
+        table, which stays what ``save``, ``predict_rating``,
+        ``similar``, the ANN index and the online fold-in read
+        (docs/serving-performance.md)."""
+        table = self.item_factors
+        cached = self._serving_items
+        if cached is None or cached[0] is not table:
+            cached = self._serving_items = (
+                table, table if table.dtype == jnp.bfloat16
+                else _serving_copy(table))
+        return cached[1]
+
+    @property
+    def score_table_bytes_per_entry(self) -> int:
+        """Byte width of an entry of the table brute-force dispatches
+        read (2, or 4 had the copy stayed float32): the `/stats.json`
+        ``serving.scoreTableBytesPerEntry`` signal. Reading it makes the
+        serving copy, as the first dispatch would."""
+        return int(self.serving_item_factors().dtype.itemsize)
 
     # ---- sublinear retrieval (ops/ann; docs/serving-performance.md) -----
     def configure_retrieval(self, mode: str = "brute", nprobe: int = 0,
@@ -438,7 +486,7 @@ class ALSModel:
             mask[0, : len(seen)] = 1.0
             uv = self.user_factors[jnp.asarray([uix], dtype=jnp.int32)]
             vals, idxs = topk_ops.recommend_topk_sharded(
-                uv, self.item_factors, jnp.asarray(cols),
+                uv, self.serving_item_factors(), jnp.asarray(cols),
                 jnp.asarray(mask), allow_v, k, mesh)
             return self._gather_results(
                 np.asarray(vals)[0], np.asarray(idxs)[0], num)
@@ -465,7 +513,7 @@ class ALSModel:
         # always takes the flat XLA kernel — the chunked-scan dispatch
         # engages only for batched prediction (batch_predict) at scale
         out = np.asarray(_serve_recommend(
-            self.user_factors, self.item_factors, jnp.asarray(buf),
+            self.user_factors, self.serving_item_factors(), jnp.asarray(buf),
             allow_v, k,
         ))
         return self._gather_results(out[:k].view(np.float32), out[k:], num)
@@ -532,7 +580,7 @@ class ALSModel:
                 self.ann_index.shortlist_width(nprobe, rescore), 1)
         else:
             vals, idxs = topk_ops.recommend_topk(
-                uvj, self.item_factors, jnp.asarray(cols),
+                uvj, self.serving_item_factors(), jnp.asarray(cols),
                 jnp.asarray(mask), allow_v, k)
         base = self._gather_results(
             np.asarray(vals)[0], np.asarray(idxs)[0], num)
@@ -616,10 +664,13 @@ class ALSModel:
         the index array — ONE device launch a dispatch; ann (the IVF
         probe + exact-rescore kernel, ops/ann) and the deployed-sharded
         merge take vectors, which one small jitted gather
-        (:func:`_take_rows`) hands them. ``allow=None`` uses the
-        device-cached all-ones vector. Whichever launched, the copy of
-        its ``(vals, idxs)`` to the host is started before this returns
-        (serving/dispatch_phases.start_copies); the caller collects it."""
+        (:func:`_take_rows`) hands them. The brute and sharded branches
+        score from :meth:`serving_item_factors`, as the single-query
+        ``recommend`` does; ann keeps the float32 table. ``allow=None``
+        uses the device-cached all-ones vector. Whichever launched, the
+        copy of its ``(vals, idxs)`` to the host is started before this
+        returns (serving/dispatch_phases.start_copies); the caller
+        collects it."""
         # dispatch.gather / dispatch.enqueue: ambient spans on the
         # batcher's per-dispatch trace (no-ops with tracing off). Both
         # time the HOST side only — upload + launch return before the
@@ -648,7 +699,7 @@ class ALSModel:
                 # deployed-sharded dispatch (docs/parallelism.md): local
                 # top-k per model shard, candidate all-gather, global merge
                 launched = topk_ops.recommend_topk_sharded(
-                    uv, self.item_factors,
+                    uv, self.serving_item_factors(),
                     jnp.asarray(np.asarray(seen_cols, dtype=np.int32)),
                     jnp.asarray(np.asarray(seen_mask, dtype=np.float32)),
                     allow_v, k, mesh)
@@ -658,7 +709,7 @@ class ALSModel:
                             allow_v, self.item_factors, uixs.shape[0], k)):
                     self._topk_observer()
                 launched = topk_ops.recommend_topk_fused_rows(
-                    self.user_factors, uixs, self.item_factors,
+                    self.user_factors, uixs, self.serving_item_factors(),
                     # NumPy stays NumPy on purpose: the dispatcher's
                     # host-side _trim_seen can only right-size concrete
                     # host arrays, and jit uploads them, with the

@@ -176,6 +176,13 @@ def serving_collector(stats: Any) -> Collector:
             for field, value in counts.items()
         ]
         out.append(Metric(
+            name="pio_serving_score_table_bytes_per_entry", kind="gauge",
+            help="Byte width of an entry of the item table brute-force "
+                 "recommend dispatches read (2: the bfloat16 serving "
+                 "copy; 0: none wired, or ANN retrieval)",
+            samples=[({}, float(stats.score_table_bytes()))],
+        ))
+        out.append(Metric(
             name="pio_serving_batch_size", kind="histogram",
             help="Dispatched (post-dedup) batch sizes",
             histograms=[({}, counts_to_snapshot(stats.batch_histogram()))],

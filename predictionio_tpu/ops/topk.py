@@ -78,7 +78,11 @@ def recommend_topk(
         # is then laid out by group as it stands, where XLA copies 2 to
         # 7 rows into that tiling first, in a kernel that takes 12-20 s
         # to compile (PERF.md §5, PR 30). Padding rows see nothing and
-        # are dropped after stage 1
+        # are dropped after stage 1. One row stays one row: XLA scores
+        # it on the vector unit, the table's widening fused into the
+        # multiply, at the speed of the read from either table width
+        # (1.62 ms a program from a bfloat16 table where the 8-row form
+        # takes 2.05: PERF.md §5, PR 35)
         user_vecs, seen_cols, seen_mask = (
             _pad_rows(x, -b % _SUBLANES)
             for x in (user_vecs, seen_cols, seen_mask))
@@ -100,9 +104,24 @@ def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
                        ((0, rows, 0),) + ((0, 0, 0),) * (x.ndim - 1))
 
 
+def _scores(user_vecs, item_f):
+    """The (B, I) float32 score matrix ``user_vecs @ item_f.T``, read
+    from the table in the dtype it is handed: one product of mixed
+    operands accumulated in float32, so a bfloat16 table (``ALSModel``'s
+    serving copy) is read at 2 bytes an entry and never widened to an
+    (I, K) float32 array first, and a float32 table gives the program
+    it always gave. The user rows are handed over as they are: the
+    matrix unit's default precision rounds them to bfloat16, as it
+    always did (two rows or more); where the hardware multiplies in
+    float32 (one row on the vector unit, the CPU) they stay float32
+    and only the table's rounding is in a score (PERF.md §5, PR 35)."""
+    return jnp.einsum("bk,ik->bi", user_vecs, item_f,
+                      preferred_element_type=jnp.float32)          # MXU
+
+
 def _masked_scores(user_vecs, item_f, seen_cols, seen_mask, allow):
     """The (B, I) score matrix with ineligible and seen items at -inf."""
-    scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)          # MXU
+    scores = _scores(user_vecs, item_f)
     scores = jnp.where(allow > 0, scores, NEG_INF)
     rows = jnp.broadcast_to(
         jnp.arange(seen_cols.shape[0])[:, None], seen_cols.shape)
@@ -125,7 +144,7 @@ def _grouped_scores(user_vecs, item_f, seen_cols, seen_mask, allow, width):
     last whole group is out of bounds here and dropped:
     :func:`_tail_scores` hides it."""
     b = user_vecs.shape[0]
-    scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)          # MXU
+    scores = _scores(user_vecs, item_f)
     scores = jnp.where(allow > 0, scores, NEG_INF)
     lanes = _SUBLANES if b % _SUBLANES == 0 else b
     view = scores.reshape(b // lanes, lanes, item_f.shape[0] // width, width)
@@ -145,7 +164,7 @@ def _tail_scores(user_vecs, item_f, seen_cols, seen_mask, allow, start):
     row ``start`` of the table (none where the catalog is whole
     groups). Seen items are found by comparison, (B, S, tail) being
     small."""
-    scores = jnp.einsum("bk,ik->bi", user_vecs, item_f)
+    scores = _scores(user_vecs, item_f)
     ids = start + jnp.arange(item_f.shape[0], dtype=seen_cols.dtype)
     seen = (seen_cols[:, :, None] == ids) & (seen_mask[:, :, None] > 0)
     return jnp.where((allow > 0) & ~seen.any(axis=1), scores, NEG_INF)
@@ -260,10 +279,12 @@ def recommend_topk_chunked(
     (ALSModel._gather_results, batch_predict) already do. Restricted to
     1-D ``allow``; peak memory O(B x chunk). The dispatcher takes it
     from 24 queries a batch at ~786K items up, an envelope that dates
-    from before the chip: on a v5e at 4.4M items the flat path with
-    its two-stage selection runs B=16 in 4.1 ms where this scan takes
-    13.2 ms at B=32 (PERF.md §5), so whether the envelope should move
-    up is open (no cell dispatches a batch that wide, PERF.md §7)."""
+    from before the chip: on a v5e at 4.4M items and a bfloat16 table
+    the flat path with its two-stage selection runs B=16 in 2.6 ms
+    where this scan takes 6.6 ms at B=32 (PERF.md §5, PR 35; from a
+    float32 table it also casts the whole table on every dispatch,
+    5.1 ms more), so whether the envelope should move up is open (no
+    cell dispatches a batch that wide, PERF.md §7)."""
     B = user_vecs.shape[0]
     I = item_f.shape[0]
     k = min(k, I)                   # the shared clamp-not-assert contract
@@ -291,7 +312,7 @@ def recommend_topk_chunked(
         tile = jax.lax.dynamic_slice(
             item_f, (start, 0), (chunk, item_f.shape[1]))
         tallow = jax.lax.dynamic_slice(allow, (start,), (chunk,))
-        scores = jnp.einsum("bk,ik->bi", user_vecs, tile)
+        scores = _scores(user_vecs, tile)
         idx = start + jax.lax.iota(jnp.int32, chunk)[None, :]
         scores = jnp.where(tallow[None, :] > 0, scores, NEG_INF)
         scores = jnp.where(idx >= vfrom, scores, NEG_INF)
@@ -533,7 +554,7 @@ def _sharded_topk_fn(mesh, k: int, shard_rows: int):
 
     def local(uv, itf, sc, sm, al):
         start = jax.lax.axis_index("model") * shard_rows
-        scores = jnp.einsum("bk,ik->bi", uv, itf)           # (b, rows)
+        scores = _scores(uv, itf)                           # (b, rows)
         scores = jnp.where(al > 0, scores, NEG_INF)
         loc = sc - start
         in_shard = (loc >= 0) & (loc < shard_rows) & (sm > 0)

@@ -444,6 +444,133 @@ class TestPredictAndModel:
         )
         assert m2.recommend("u0", 3) == m.recommend("u0", 3)
 
+    # -- the bfloat16 serving copy of the item table (PR 35) --------------
+    def test_serving_copy_is_made_once_in_bfloat16(self):
+        m = self._model(np.random.default_rng(12))
+        assert m._serving_items is None
+        cols = np.zeros((2, 8), np.int32)
+        mask = np.zeros((2, 8), np.float32)
+        m.batch_topk(np.asarray([0, 1], np.int32), cols, mask, None, 3)
+        assert m._serving_items is not None
+        served = m.serving_item_factors()
+        assert served.dtype == jnp.bfloat16
+        assert served.shape == m.item_factors.shape
+        np.testing.assert_array_equal(
+            np.asarray(served),
+            np.asarray(m.item_factors.astype(jnp.bfloat16)))
+        m.batch_topk(np.asarray([2, 3], np.int32), cols, mask, None, 3)
+        m.recommend("u0", 3)
+        assert m.serving_item_factors() is served
+        # the model's own table is untouched, and is what the gauge is not
+        assert m.item_factors.dtype == jnp.float32
+        assert m.score_table_bytes_per_entry == 2
+
+    def test_serving_copy_of_a_bfloat16_table_is_the_table(self):
+        m = self._model(np.random.default_rng(13))
+        m.item_factors = m.item_factors.astype(jnp.bfloat16)
+        assert m.serving_item_factors() is m.item_factors
+
+    def test_serving_copy_follows_a_replaced_table(self):
+        """``dataclasses.replace(model, item_factors=...)`` (the
+        weighted-items example scales the table so) carries the cache
+        field over: the copy must be of the new table."""
+        import dataclasses
+
+        m = self._model(np.random.default_rng(19))
+        before = m.serving_item_factors()
+        scaled = dataclasses.replace(m, item_factors=m.item_factors * 3.0)
+        np.testing.assert_array_equal(
+            np.asarray(scaled.serving_item_factors()),
+            np.asarray((m.item_factors * 3.0).astype(jnp.bfloat16)))
+        assert m.serving_item_factors() is before
+
+    def test_serving_copy_keeps_a_row_sharded_tables_sharding(self, mesh8):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        m = self._model(np.random.default_rng(14))
+        ways = int(mesh8.shape["model"])
+        rows = NamedSharding(mesh8, P("model", None))
+        table = np.asarray(m.item_factors)[:12 // ways * ways]
+        m.item_factors = jax.device_put(table, rows)
+        served = m.serving_item_factors()
+        assert served.dtype == jnp.bfloat16
+        assert served.sharding == m.item_factors.sharding
+
+    def test_serving_copy_is_never_serialized(self, tmp_path):
+        import pickle
+
+        from predictionio_tpu.models.als import ALSModel
+        from predictionio_tpu.utils.checkpoint import load_sharded
+
+        m = self._model(np.random.default_rng(15))
+        before = m.recommend("u0", 3)
+        assert m._serving_items is not None
+        assert m.__getstate__()["_serving_items"] is None
+        again = pickle.loads(pickle.dumps(m))
+        assert again._serving_items is None
+        assert again.recommend("u0", 3) == before
+        # the checkpoint holds the two float32 tables and nothing else
+        m.save(str(tmp_path / "model"))
+        arrays = load_sharded(str(tmp_path / "model"))
+        assert sorted(arrays) == ["item", "user"]
+        assert all(a.dtype == np.float32 for a in arrays.values())
+        m2 = ALSModel.load(str(tmp_path / "model"))
+        assert m2.item_factors.dtype == jnp.float32
+        assert m2._serving_items is None
+        assert m2.recommend("u0", 3) == before
+        assert m2.serving_item_factors().dtype == jnp.bfloat16
+
+    def test_predict_rating_and_similar_still_read_float32(self):
+        rng = np.random.default_rng(16)
+        m = self._model(rng)
+        m.recommend("u0", 3)                      # the copy exists
+        uf = np.asarray(m.user_factors, np.float64)
+        itf = np.asarray(m.item_factors, np.float64)
+        # a bfloat16 operand would be off by up to 2^-8 of each product
+        assert m.predict_rating("u1", "i3") == pytest.approx(
+            uf[1] @ itf[3], rel=1e-6, abs=1e-6)
+        itn = itf / np.linalg.norm(itf, axis=1, keepdims=True)
+        for name, score in m.similar(["i2"], 4):
+            assert score == pytest.approx(itn[int(name[1:])] @ itn[2],
+                                          abs=1e-6)
+
+    @pytest.mark.parametrize("user", [0, 1, 4])
+    def test_recommend_and_batch_topk_give_the_same_ids(self, user):
+        """Both read the serving copy, so an answer does not depend on
+        whether batching is on."""
+        m = self._model(np.random.default_rng(17))
+        seen = m.seen_by_user.get(user, np.empty(0, np.int32))
+        cols = np.zeros((1, 8), np.int32)
+        mask = np.zeros((1, 8), np.float32)
+        cols[0, :len(seen)], mask[0, :len(seen)] = seen, 1.0
+        vals, idxs = m.batch_topk(np.asarray([user], np.int32), cols, mask,
+                                  None, 5)
+        single = m.recommend(f"u{user}", 5)
+        assert [name for name, _ in single] == [
+            f"i{i}" for i in np.asarray(idxs)[0]]
+        np.testing.assert_allclose([v for _, v in single],
+                                   np.asarray(vals)[0], rtol=1e-6)
+
+    def test_serving_stats_reports_the_score_tables_width(self):
+        """``scoreTableBytesPerEntry``: 0 until a server wires a model
+        (tests/test_topk.py reads 2 behind a live one), then what it
+        was told."""
+        from predictionio_tpu.api.stats import ServingStats
+        from predictionio_tpu.obs.registry import serving_collector
+
+        stats = ServingStats()
+        assert stats.snapshot()["scoreTableBytesPerEntry"] == 0
+        m = self._model(np.random.default_rng(18))
+        stats.set_score_table_bytes(m.score_table_bytes_per_entry)
+        assert stats.snapshot()["scoreTableBytesPerEntry"] == 2
+        assert stats.score_table_bytes() == 2
+        gauge = [x for x in serving_collector(stats)()
+                 if x.name == "pio_serving_score_table_bytes_per_entry"]
+        assert [x.samples for x in gauge] == [[({}, 2.0)]]
+        # not a counter: it does not ride the *_total exposition
+        assert "score_table_bytes" not in stats.raw_counts()
+
     def test_predict_ratings_pairs(self):
         rng = np.random.default_rng(11)
         m = self._model(rng)

@@ -357,12 +357,29 @@ class TestModelIntegration:
         assert not names & {"i3", "i4", "i5"}
 
     def test_full_probe_recommend_matches_brute_path(self):
+        """Probing every cell, ANN's exact float32 rescore ranks like
+        brute force over the float32 table. The served brute path
+        scores from the model's bfloat16 copy: the same items up to
+        scores that tie within 2^-8 |u| |v|."""
+        from predictionio_tpu.ops import topk as topk_ops
+
         m = _als_model(seed=20)
         brute = m.recommend("u1", 10)
         m.configure_retrieval("ann")
         m.ann_nprobe = m.ann_index.nlist        # probe everything
         ann = m.recommend("u1", 10)
-        assert [r[0] for r in ann] == [r[0] for r in brute]
+        _, exact = topk_ops.recommend_topk(
+            m.user_factors[1:2], m.item_factors,
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.float32),
+            jnp.ones((m.item_factors.shape[0],), jnp.float32), 10)
+        assert [r[0] for r in ann] == [f"i{i}" for i in np.asarray(exact)[0]]
+        u = np.asarray(m.user_factors[1])
+        items = np.asarray(m.item_factors)
+        for name, score in brute:
+            v = items[int(name[1:])]
+            assert abs(score - float(u @ v)) <= (
+                2.0 ** -8 * np.linalg.norm(u) * np.linalg.norm(v))
+        assert len({r[0] for r in brute} & {r[0] for r in ann}) >= 9
 
     def test_full_probe_similar_matches_brute_path(self):
         m = _als_model(seed=21)

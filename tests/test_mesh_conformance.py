@@ -212,7 +212,9 @@ class TestServingDispatch:
         """The deployed-sharded branch gets its query vectors from one
         jitted gather (``models/als._take_rows``), whether the user
         table is row-sharded too or not: the same answer as the
-        distributed merge on ``user_factors[uixs]``."""
+        distributed merge on ``user_factors[uixs]`` and the model's
+        bfloat16 serving copy of the item table, row-sharded like the
+        table it was cast from."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from predictionio_tpu.models.als import ALSModel
@@ -237,8 +239,11 @@ class TestServingDispatch:
         cols = rng.integers(0, I, (B, 8)).astype(np.int32)
         mask = (rng.random((B, 8)) < 0.5).astype(np.float32)
         got = model.batch_topk(uixs, cols, mask, None, 12)
+        served = model.serving_item_factors()
+        assert served.dtype == jnp.bfloat16
+        assert served.sharding == model.item_factors.sharding
         want = recommend_topk_sharded(
-            users[uixs], model.item_factors, jnp.asarray(cols),
+            users[uixs], served, jnp.asarray(cols),
             jnp.asarray(mask), jnp.ones((I,), jnp.float32), 12, mesh)
         np.testing.assert_array_equal(np.asarray(got[1]),
                                       np.asarray(want[1]))

@@ -308,7 +308,8 @@ def test_mosaic_builds_both_ends_of_the_envelope(one_chip):
     """Interpret mode accepts what Mosaic refuses (a slice off the
     tiling, more VMEM than a kernel may use): compile for a described
     v5e, the session cell's shape and the smallest. Nothing runs; the
-    chip run is ``chip_smoke.py``'s. One test for both: the TPU's
+    chip run is ``chip_smoke.py``'s. One test for all of it (and for
+    the ALS top-k programs' compiled text at the end): the TPU's
     compiler loads in one process at a time, and cases of one test
     cannot land on two workers."""
     def sds(shape, dtype):
@@ -341,3 +342,24 @@ def test_mosaic_builds_both_ends_of_the_envelope(one_chip):
             q, k, sz=sz, groups=G), (q, k)), (stage_2, (q, k, k, kept))):
         assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile() \
             .as_text()
+    # the ALS top-k programs at the Books cells' shape (ops/topk.py), in
+    # this test for the same reason: from the model's bfloat16 serving
+    # copy no program holds a float32 array of the table's shape (a
+    # hoisted convert would move 2.25 GB a dispatch where the scan
+    # moves 1.13; inside the B = 1 fusion the widening is not an array)
+    from predictionio_tpu.ops import topk
+
+    items, rank = 4_400_000, 128
+    for b in (1, 8, 16):
+        compiled = jax.jit(
+            topk.recommend_topk_rows.__wrapped__, static_argnames="k"
+        ).lower(sds((1024, rank), F32), sds((b,), jnp.int32),
+                sds((items, rank), bf16), sds((b, 8), jnp.int32),
+                sds((b, 8), F32), sds((items,), F32), k=10).compile()
+        text = compiled.as_text()
+        entry = text[text.index("ENTRY"):]
+        assert f"bf16[{items},{rank}]" in entry
+        assert f"f32[{items},{rank}]" not in entry
+        # scores and selection only: 8 or 16 rows of float32 scores
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 1.1 * max(b, 8) * items * 4

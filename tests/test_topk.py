@@ -136,21 +136,26 @@ def test_two_stage_selection_equals_lax_top_k(B, k, I):
             raise AssertionError(f"{kind}: {e}") from e
 
 
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("allow_rank", [1, 2])
 @pytest.mark.parametrize("S", [8, 128])
 @pytest.mark.parametrize("B", [1, 2, 4, 8, 16])
 @pytest.mark.parametrize("I", [12_800, 12_877], ids=["whole_groups", "tail"])
-def test_recommend_topk_two_stage_equals_single_top_k(I, B, S, allow_rank):
+def test_recommend_topk_two_stage_equals_single_top_k(I, B, S, allow_rank,
+                                                      table_dtype):
     """``recommend_topk`` above the rule's threshold against the same
     masked scores under one ``lax.top_k``: a catalog of whole groups
     and one with a tail (some of its items seen), seen widths 8 and
     128, a shared and a per-query ``allow``, integer factors so that
-    the matmuls agree to the bit and ties abound."""
+    the matmuls agree to the bit and ties abound; over a float32 table
+    and over the bfloat16 one the model serves from (the scores float32
+    either way)."""
     K, k = 8, 10
     assert topk_ops.two_stage_group_width(I, k) == 128
     rng = np.random.default_rng(S + B + allow_rank)
     uv = jnp.asarray(rng.integers(-2, 3, (B, K)).astype(np.float32))
-    itf = jnp.asarray(rng.integers(-2, 3, (I, K)).astype(np.float32))
+    itf = jnp.asarray(rng.integers(-2, 3, (I, K)).astype(np.float32)
+                      ).astype(table_dtype)
     cols = rng.integers(0, I, (B, S)).astype(np.int32)
     cols[:, :3] = I - 1 - np.arange(3)            # the last items: the tail's
     mask = (rng.random((B, S)) < 0.7).astype(np.float32)
@@ -162,6 +167,7 @@ def test_recommend_topk_two_stage_equals_single_top_k(I, B, S, allow_rank):
         uv, itf, cols, mask, allow)
     got = recommend_topk(uv, itf, cols, mask, allow, k)
     assert got[0].shape == got[1].shape == (B, k)
+    assert got[0].dtype == want[0].dtype == jnp.float32
     _assert_same_on_finite_slots(got, want)
     seen = {(b, int(c)) for b in range(B) for c, m in
             zip(np.asarray(cols)[b], np.asarray(mask)[b]) if m > 0}
@@ -198,6 +204,66 @@ def test_two_stage_rule_is_a_function_of_the_shapes():
     small = jax.ShapeDtypeStruct((5_000, 8), jnp.float32)
     assert not topk_ops.selects_two_stage(
         jax.ShapeDtypeStruct((5_000,), jnp.float32), small, 8, 10)
+
+
+# -- the table's width: bfloat16 is inside the guarantee, int8 is not ----------
+
+def _int8_rows(table):
+    """``table`` through int8 with one scale a row, back in float32."""
+    scale = np.abs(table).max(axis=1, keepdims=True) / 127.0
+    return (np.round(table / scale) * scale).astype(np.float32)
+
+
+@pytest.mark.parametrize("form", ["flat", "chunked"])
+@pytest.mark.parametrize("B", [1, 2, 8, 16])
+def test_bfloat16_table_holds_the_tie_bound_and_int8_does_not(B, form):
+    """Sixteen users' answers, ``B`` a program, checked as the ALS
+    cells check theirs (``als_numpy.check_answer`` against float32
+    ``u . V``: ids may differ only where scores tie within
+    2^-8 |u| |v|). From the float32 table and from its bfloat16 copy
+    every answer passes. From a table quantised to int8 with one scale
+    a row many do not: the factors have one dominant component (a
+    popularity direction, which the users here do not weigh), so the
+    row's scale is set by it and the other components keep two or
+    three bits. On factors without one int8 passes this bound too: it
+    is Cauchy-Schwarz's worst case (PERF.md §7, PR 35)."""
+    from benchmarks.reference import als_numpy
+
+    I, K, users, k = 12_800, 32, 16, 10
+    rng = np.random.default_rng(35)
+    itf = rng.standard_normal((I, K)).astype(np.float32)
+    itf[:, 0] = 30.0 + rng.standard_normal(I)
+    uf = rng.standard_normal((users, K)).astype(np.float32)
+    uf[:, 0] = 0.0
+    cols = rng.integers(0, I, (users, 8)).astype(np.int32)
+    mask = np.ones((users, 8), np.float32)
+    allow = jnp.ones((I,), jnp.float32)
+    reference = als_numpy.reference_scores(itf, uf)
+    norms = als_numpy.item_norms(itf)
+
+    def program(*a):
+        if form == "flat":
+            return recommend_topk(*a, allow, k)
+        return recommend_topk_chunked(*a, allow, k, chunk=2048)
+
+    def failed(table):
+        n = 0
+        for lo in range(0, users, B):
+            part = slice(lo, lo + B)
+            vals, idxs = (np.asarray(a) for a in program(
+                jnp.asarray(uf[part]), table, jnp.asarray(cols[part]),
+                jnp.asarray(mask[part])))
+            assert vals.dtype == np.float32
+            for j, u in enumerate(range(lo, lo + B)):
+                n += als_numpy.check_answer(
+                    reference[u], float(np.linalg.norm(uf[u])), norms,
+                    cols[u], list(zip(idxs[j].tolist(), vals[j].tolist())),
+                    k) is not None
+        return n
+
+    assert failed(jnp.asarray(itf)) == 0
+    assert failed(jnp.asarray(itf).astype(jnp.bfloat16)) == 0
+    assert failed(jnp.asarray(_int8_rows(itf))) >= users // 4
 
 
 def _served_counts(n_items, n_queries):
@@ -253,6 +319,10 @@ def _served_counts(n_items, n_queries):
             metrics = resp.read().decode()
     finally:
         server.stop()
+    # the served table is the model's bfloat16 copy, and the server says so
+    assert serving["scoreTableBytesPerEntry"] == 2
+    assert "pio_serving_score_table_bytes_per_entry 2" in metrics
+    assert model.item_factors.dtype == jnp.float32
     return serving["dispatches"], serving["topkTwoStageDispatches"], metrics
 
 

@@ -40,8 +40,10 @@ def test_fused_matches_flat_small():
     np.testing.assert_allclose(np.asarray(fv), np.asarray(rv))
 
 
-def test_chunked_matches_flat_on_finite_slots():
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+def test_chunked_matches_flat_on_finite_slots(table_dtype):
     uv, itf, cols, mask, allow = _setup(6, 5000, S=24)
+    itf = itf.astype(table_dtype)
     fv, fi = recommend_topk(uv, itf, cols, mask, allow, 10)
     cv, ci = recommend_topk_chunked(uv, itf, cols, mask, allow, 10,
                                     chunk=1024)
@@ -50,6 +52,7 @@ def test_chunked_matches_flat_on_finite_slots():
     finite = np.isfinite(fv)
     np.testing.assert_array_equal(ci[finite], fi[finite])
     np.testing.assert_allclose(cv[finite], fv[finite], rtol=1e-6)
+    assert cv.dtype == fv.dtype == np.float32
     # sentinel slots never collide with real item indices
     assert (ci[~np.isfinite(cv)] >= 5000).all()
 
@@ -74,14 +77,18 @@ def _rows_setup(B, padded, seed=0):
     return table, uixs, itf, cols, mask, allow
 
 
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("padded", [False, True], ids=["full", "padded"])
 @pytest.mark.parametrize("B", [1, 4, 16, 32])
 @pytest.mark.parametrize("form", ["flat", "chunked"])
-def test_row_taking_program_equals_vector_program(form, B, padded):
+def test_row_taking_program_equals_vector_program(form, B, padded,
+                                                  table_dtype):
     """``recommend_topk*_rows(table, uixs, ...)`` is
     ``recommend_topk*(table[uixs], ...)``: the same indices, and on the
-    CPU the same values bit for bit."""
+    CPU the same values bit for bit, whichever width the item table
+    has."""
     table, uixs, itf, cols, mask, allow = _rows_setup(B, padded, seed=B)
+    itf = itf.astype(table_dtype)
     if form == "flat":
         want = recommend_topk(table[uixs], itf, cols, mask, allow, 10)
         got = recommend_topk_rows(table, uixs, itf, cols, mask, allow, 10)
@@ -133,9 +140,11 @@ def test_fused_rows_makes_the_fused_choice(monkeypatch, B, form):
 
 @pytest.mark.parametrize("B", [1, 4, 16])
 def test_batch_topk_brute_equals_vectors_through_fused(B):
-    """``ALSModel.batch_topk`` on the brute branch hands the table and
-    the indices to the dispatcher: what it answers is what the eager
-    gather + ``recommend_topk`` answers (5,000 items: the flat side)."""
+    """``ALSModel.batch_topk`` on the brute branch hands the user
+    table, the indices and its bfloat16 serving copy of the item table
+    to the dispatcher: what it answers is what the eager gather +
+    ``recommend_topk`` answers on that copy (5,000 items: the flat
+    side)."""
     from predictionio_tpu.models.als import ALSModel
     from predictionio_tpu.utils.bimap import EntityIdIxMap
 
@@ -148,8 +157,9 @@ def test_batch_topk_brute_equals_vectors_through_fused(B):
             [f"i{i}" for i in range(itf.shape[0])]),
         seen_by_user={})
     got = model.batch_topk(uixs, cols, mask, None, 10)
+    assert model.serving_item_factors().dtype == jnp.bfloat16
     want = recommend_topk(
-        table[uixs], itf, cols, mask,
+        table[uixs], itf.astype(jnp.bfloat16), cols, mask,
         jnp.ones((itf.shape[0],), jnp.float32), 10)
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))

@@ -193,9 +193,12 @@ def test_live_weights_shift_ranking(example_engine, seeded_storage):
         weighted = query()
         w_scores = {s["item"]: s["score"] for s in weighted}
         assert weighted[0]["item"] == target
+        # brute-force recommend scores from a bfloat16 copy of the
+        # (scaled) item table: 5 v and v round apart, each by up to 2^-8
+        # of an entry, so the fold is exact to about a percent
         assert w_scores[target] == pytest.approx(
-            5.0 * base_scores[target], rel=1e-4)
-        assert w_scores.get(leader, 0.0) <= 0.1 * base_scores[leader] + 1e-6
+            5.0 * base_scores[target], rel=1e-2)
+        assert w_scores.get(leader, 0.0) <= 0.101 * base_scores[leader] + 1e-6
 
         # weights replace (not merge): publishing a neutral set restores
         body = json.dumps({
