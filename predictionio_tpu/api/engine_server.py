@@ -121,6 +121,7 @@ from predictionio_tpu.obs.slo import SLOEngine, serving_pressure_collector
 from predictionio_tpu.obs.trace import (
     PARENT_SPAN_HEADER,
     TRACE_ID_HEADER,
+    GcPauseSpans,
     TraceLog,
     active_trace,
     parse_trace_context,
@@ -317,6 +318,9 @@ class EngineService:
                     max_entries=config.cache_max_entries,
                     ttl_s=config.cache_ttl_s,
                     stats=self.serving_stats)
+        #: the ring behind /traces.json: one trace a traced request
+        #: and, with batching, one ``dispatch`` trace a dispatcher cycle
+        self.trace_log = TraceLog()
         #: opt-in micro-batching: concurrent queries coalesce into one
         #: device dispatch (ServerConfig.batching; QueryBatcher docs);
         #: the wait/target per batch comes from the configured policy
@@ -325,7 +329,8 @@ class EngineService:
                          policy=make_batch_policy(config.batch_policy,
                                                   config.batch_max,
                                                   config.batch_wait_ms),
-                         stats=self.serving_stats)
+                         stats=self.serving_stats,
+                         trace_log=self.trace_log)
             if config.batching else None
         )
         #: precompiled query binder — refreshed on /reload with the new
@@ -342,7 +347,11 @@ class EngineService:
         self.access_log = access_log_enabled(config.access_log)
         if self.access_log:
             ensure_access_log_handler()
-        self.trace_log = TraceLog()
+        #: collector pauses as spans on whichever traced thread they
+        #: ran on (obs/trace.GcPauseSpans): installed when an
+        #: EngineServer is built around this service, removed when it
+        #: closes
+        self.gc_pauses = GcPauseSpans() if self.tracing else None
         self.request_latency = HistogramFamily(
             "pio_http_request_seconds",
             "HTTP request walltime by route (handler-measured)",
@@ -1328,6 +1337,15 @@ class _Handler(BaseHTTPRequestHandler):
     def _params(self) -> dict[str, str]:
         return {k: v[0] for k, v in parse_qs(urlparse(self.path).query).items()}
 
+    #: perf_counter with the request line in hand (tracing on only):
+    #: where a traced query's ``request`` and ``request.read`` start
+    _t_request: float | None = None
+
+    def parse_request(self) -> bool:
+        if self.service.tracing:
+            self._t_request = time.perf_counter()
+        return super().parse_request()
+
     def _dispatch(self, method: str) -> None:
         """Observability envelope around the real dispatch: request-id
         resolution (echoed by _respond), optional trace creation for
@@ -1343,12 +1361,15 @@ class _Handler(BaseHTTPRequestHandler):
             # adopt inbound cross-process context (the router's trace
             # id + its attempt span id) when well-formed; malformed or
             # oversized headers fall back to fresh local ids — never a
-            # rejected request (obs/trace.parse_trace_context)
+            # rejected request (obs/trace.parse_trace_context). The
+            # trace counts from the stamp at the request line, and its
+            # root span ``request`` is open from there
             inbound_id, inbound_parent = parse_trace_context(self.headers)
             self._trace = start_trace(
                 "queries.json", request_id=self._request_id,
                 trace_id=inbound_id, parent_span_id=inbound_parent,
-                service="engine")
+                service="engine", start_perf=self._t_request)
+            self._trace.open_root("request")
         try:
             self._dispatch_inner(method, path)
         finally:
@@ -1366,6 +1387,17 @@ class _Handler(BaseHTTPRequestHandler):
                     "engine", method, path, self._last_status, dt,
                     self._request_id, client=self.address_string(),
                     **({"worker": wid} if wid else {}))
+        if self._trace is not None:
+            # the trace is finished and in the ring (whoever has the
+            # response finds it); now the flush the stdlib would make
+            # one statement later, timed: ``request.flush`` is the
+            # bookkeeping above, run while the response waited in the
+            # buffer, and the socket write. The root closes with it
+            # and the trace ends there
+            self.wfile.flush()
+            t_end = time.perf_counter()
+            self._trace.add_span("request.flush", self._t_responded, t_end)
+            self._trace.close_root(t_end)
 
     def _dispatch_inner(self, method: str, path: str) -> None:
         body: Any = None
@@ -1395,6 +1427,11 @@ class _Handler(BaseHTTPRequestHandler):
                           {"Connection": "close"})
             return
         raw = self.rfile.read(length) if length else b""
+        if self._trace is not None:
+            # the stdlib's header parse, request id, trace creation and
+            # the body read: the request line in hand -> the body in hand
+            self._trace.add_span("request.read", self._trace.start_perf,
+                                 time.perf_counter())
         if method == "POST" and raw:
             try:
                 if self._trace is not None:
@@ -1425,10 +1462,12 @@ class _Handler(BaseHTTPRequestHandler):
             self._write_response(status, payload, extra_headers)
             return
         # the last stretch of a traced query that does work: body
-        # encode, header lines, the buffered write (the socket flush
-        # itself happens after _dispatch, outside every span)
-        with trace.span("respond"):
-            self._write_response(status, payload, extra_headers)
+        # encode, header lines, the buffered write; where it ends
+        # ``request.flush`` starts (_dispatch)
+        t0 = time.perf_counter()
+        self._write_response(status, payload, extra_headers)
+        self._t_responded = time.perf_counter()
+        trace.add_span("respond", t0, self._t_responded)
 
     def _write_response(self, status: int, payload: Any,
                         extra_headers: Mapping[str, str] | None) -> None:
@@ -1521,6 +1560,8 @@ class EngineServer(RestServer):
         )
         self.service.on_stop = self.stop
         self.service.client_disconnects = lambda: self.client_disconnects
+        if self.service.gc_pauses is not None:
+            self.service.gc_pauses.install()
 
     def _on_bind_failure(self, attempt: int, ip: str, port: int) -> None:
         if attempt == 0 and port:
@@ -1528,6 +1569,8 @@ class EngineServer(RestServer):
             undeploy(ip, port, self.config.server_key)
 
     def _on_close(self) -> None:
+        if self.service.gc_pauses is not None:
+            self.service.gc_pauses.remove()
         if self.service.online is not None:
             self.service.online.close()
         if self.service.coherence is not None:

@@ -23,13 +23,22 @@ Propagation has two legs:
   the batch) and copies what ``span()`` recorded there onto each
   request's trace (``Trace.add_spans_from``).
 
+A trace may carry a **root span** over its whole life
+(``Trace.open_root`` / ``close_root``): the engine server's ``request``,
+from the request line in hand to the response flushed. Every span
+recorded without a parent becomes its child, the trace's origin is the
+root's start and its duration the root's.
+
 Traces are sampled into a bounded :class:`TraceLog` ring per server,
-served as JSON on ``GET /traces.json``.
+served as JSON on ``GET /traces.json``. The ring holds two kinds: one
+trace a request, and (engine server, batching on) one ``dispatch``
+trace a dispatcher cycle (serving/batcher.py).
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import os
 import re
@@ -113,21 +122,26 @@ class Trace:
     read (``to_dict``/``stage_seconds``) first takes an atomic
     ``list(...)`` copy, so a reader can never see a half-written
     record (tuples are immutable and fully built before the append).
-    In the serving wiring the writers never actually overlap anyway:
-    the handler thread is blocked on its future while the batcher's
-    dispatcher records queue-wait/device spans. A lock here would add
+    In the serving wiring the writers seldom overlap: the handler
+    thread is blocked on its future while the batcher's dispatcher
+    records queue-wait/device spans; only the phases the dispatcher
+    copies onto a rider after its future is set may land while the
+    handler appends its own, and an append is all either does. A lock
+    here would add
     two GIL handoff points per span on a 24-thread serving path for a
     race that cannot corrupt anything — measured as a real qps cost
     in the tracing-overhead bench phase."""
 
     __slots__ = ("trace_id", "name", "request_id", "parent_span_id",
                  "service", "tags", "_t0", "_wall_start", "_spans",
-                 "_span_seq", "_span_prefix", "_duration", "observer")
+                 "_span_seq", "_span_prefix", "_duration", "_root",
+                 "observer")
 
     def __init__(self, name: str, request_id: str | None = None,
                  trace_id: str | None = None,
                  parent_span_id: str | None = None,
-                 service: str | None = None):
+                 service: str | None = None,
+                 start_perf: float | None = None):
         self.trace_id = (trace_id
                          or f"{_TRACE_ID_PREFIX}{next(_TRACE_ID_SEQ):012x}")
         self.name = name
@@ -139,8 +153,13 @@ class Trace:
         #: which server recorded this segment ("router"/"engine"/...)
         self.service = service
         self.tags: dict[str, Any] = {}
-        self._t0 = time.perf_counter()
-        self._wall_start = time.time()
+        #: the origin span offsets count from: now, or a
+        #: ``time.perf_counter`` reading the caller took earlier (the
+        #: handler's stamp at the request line, the end of the
+        #: dispatcher's previous cycle), so no span starts before it
+        now = time.perf_counter()
+        self._t0 = now if start_perf is None else start_perf
+        self._wall_start = time.time() - (now - self._t0)
         #: flat records: (name, parent_id, span_id, start_off_s, dur_s)
         self._spans: list[tuple[str, str, str, float, float]] = []
         #: per-trace span-id sequence — ids must survive pre-allocation
@@ -149,6 +168,8 @@ class Trace:
         self._span_seq = itertools.count()
         self._span_prefix = f"{_SPAN_ID_PREFIX}{next(_SPAN_SEG_SEQ):x}"
         self._duration: float | None = None
+        #: (name, span id) of the root span, once ``open_root`` ran
+        self._root: tuple[str, str] | None = None
         #: optional span-completion callback ``(name, start_off_s,
         #: dur_s)`` — the train profiler samples device memory as each
         #: DASE stage closes (obs/device.TrainProfiler). Exceptions are
@@ -181,6 +202,8 @@ class Trace:
         path never pays an os.urandom read per span."""
         if span_id is None:
             span_id = f"s{self._span_prefix}.{next(self._span_seq):x}"
+        if not parent_id and self._root is not None:
+            parent_id = self._root[1]
         self._spans.append(
             (name, parent_id, span_id,
              start_perf - self._t0, max(0.0, end_perf - start_perf)))
@@ -204,8 +227,28 @@ class Trace:
             self.add_span(name, origin + start, origin + start + dur,
                           parent_id)
 
-    def finish(self, **tags: Any) -> None:
-        self._duration = time.perf_counter() - self._t0
+    def open_root(self, name: str) -> None:
+        """Open the span that covers the whole trace, from its origin:
+        from here on a span recorded without a parent is its child.
+        Its own record is written by ``close_root``, which may run
+        after the trace is finished and in the ring; until then
+        ``to_dict`` shows it as long as the trace has lasted."""
+        self._root = (name, self.reserve_span_id())
+
+    def close_root(self, end_perf: float) -> None:
+        """Record the root span, origin to ``end_perf``, and end the
+        trace there: ``durationMs`` is the root's."""
+        name, span_id = self._root
+        self.finish(end_perf=end_perf)
+        self._spans.append(
+            (name, _ROOT_PARENT, span_id, 0.0, end_perf - self._t0))
+
+    def finish(self, end_perf: float | None = None, **tags: Any) -> None:
+        """End the trace now, or at ``end_perf`` (a clock reading the
+        caller also closed a span with)."""
+        # pio: lint-ignore[shared-state-race]: one float swapped by reference (GIL-atomic); a trace is finished by the one thread that owns it (a handler its request's, the dispatcher its cycle's record) and to_dict reads either the old or the new duration, both valid
+        self._duration = (time.perf_counter() if end_perf is None
+                          else end_perf) - self._t0
         if tags:
             self.tags.update(tags)
 
@@ -234,6 +277,12 @@ class Trace:
         spans = list(self._spans)
         duration = self._duration
         tags = dict(self.tags)
+        root = self._root
+        if root is not None and not any(s[2] == root[1] for s in spans):
+            # the root is still open (the response is not flushed yet):
+            # its children must not point at a span the document lacks
+            spans.append((root[0], _ROOT_PARENT, root[1], 0.0,
+                          duration or 0.0))
         doc: dict[str, Any] = {
             "traceId": self.trace_id,
             "name": self.name,
@@ -249,7 +298,7 @@ class Trace:
                     "durationMs": round(dur * 1e3, 3),
                 }
                 for name, parent, span_id, start, dur in sorted(
-                    spans, key=lambda s: s[3])
+                    spans, key=lambda s: (s[3], -s[4]))
             ],
         }
         if self.request_id:
@@ -304,13 +353,15 @@ _NULL_SPAN = _NullSpan()
 def start_trace(name: str, request_id: str | None = None,
                 trace_id: str | None = None,
                 parent_span_id: str | None = None,
-                service: str | None = None) -> Trace:
+                service: str | None = None,
+                start_perf: float | None = None) -> Trace:
     """A new root trace (or, with ``trace_id``/``parent_span_id`` from
     :func:`parse_trace_context`, a CHILD SEGMENT of a cross-process
     trace). Call sites gate this behind their tracing flag — the flag
     check is the whole cost of the disabled path."""
     return Trace(name, request_id=request_id, trace_id=trace_id,
-                 parent_span_id=parent_span_id, service=service)
+                 parent_span_id=parent_span_id, service=service,
+                 start_perf=start_perf)
 
 
 def active_trace() -> Trace | None:
@@ -339,10 +390,42 @@ def span(name: str):
     return trace.span(name)
 
 
+class GcPauseSpans:
+    """A ``gc.callbacks`` hook: a collection of generation 1 or 2 that
+    runs on a thread with an ambient trace lands on it as the span
+    ``gc.pause.gen<n>`` (the collector holds the GIL, so the pause is
+    every thread's; generation 0 runs every few hundred allocations
+    and takes microseconds: not recorded). One collection runs at a
+    time, so one start stamp is enough. A server installs it when it
+    starts with tracing on and removes it when it stops."""
+
+    def __init__(self):
+        self._start: float | None = None
+
+    def __call__(self, phase: str, info: Mapping[str, int]) -> None:
+        if info["generation"] == 0:
+            return
+        if phase == "start":
+            self._start = time.perf_counter()
+            return
+        trace, start, self._start = _current.get(), self._start, None
+        if trace is not None and start is not None:
+            trace.add_span(f"gc.pause.gen{info['generation']}", start,
+                           time.perf_counter())
+
+    def install(self) -> None:
+        gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+
 class TraceLog:
     """Bounded ring of recently finished traces (newest first on
-    read). Recording is one deque append under the ring's lock —
-    serialization to JSON-able dicts happens at READ time, relying on
+    read; a trace may still receive spans after it is recorded: the
+    handler's ``request`` root, the dispatcher's four). Recording is
+    one deque append under the ring's lock — serialization to JSON-able dicts happens at READ time, relying on
     the lock-free :class:`Trace` read contract (``to_dict`` copies the
     span list atomically under the GIL; see the Trace docstring for
     why the trace itself carries no lock), so the request hot path

@@ -26,6 +26,13 @@ Hot-path guarantees, each carried by a counter in
 
 ``get_deployed`` is read fresh per batch, so /reload hot-swaps apply
 from the next batch on.
+
+With a traced query in the batch the dispatcher also keeps a record of
+its own: one ``dispatch`` trace a cycle in the server's ``TraceLog``,
+whose four spans partition the thread's time from the end of the
+previous cycle to the end of this one (``_close_cycle``;
+docs/observability.md). What tracing itself costs runs after the
+futures are set.
 """
 
 from __future__ import annotations
@@ -100,10 +107,16 @@ class QueryBatcher:
 
     def __init__(self, get_deployed, policy: BatchPolicy | None = None,
                  stats: ServingStats | None = None, batch_max: int = 64,
-                 batch_wait_ms: float = 5.0):
+                 batch_wait_ms: float = 5.0, trace_log: Any = None):
         import queue as _queue
 
         self._get_deployed = get_deployed
+        #: the server's TraceLog: where a traced cycle's ``dispatch``
+        #: record goes (None: the record is made and not kept)
+        self._trace_log = trace_log
+        #: perf_counter at the end of the last cycle, if it was traced
+        #: (dispatcher thread only): where ``dispatcher.idle`` starts
+        self._cycle_end: float | None = None
         # legacy ctor shape (batch_max/batch_wait_ms) builds the fixed
         # policy PR 1 shipped with
         self._policy = policy or FixedBatchPolicy(
@@ -284,6 +297,55 @@ class QueryBatcher:
                 pass
 
     def _finish(self, batch: list[_Pending], t_first: float) -> None:
+        """One dispatcher cycle from its first dequeue on. A cycle with
+        a traced rider is recorded (module docstring): the record is in
+        the ring before the first future is set, so whoever has an
+        answer finds the ring in the order dispatch, then request; it
+        is filled in after the last. The dispatcher's whole tracing
+        cost for an untraced batch is the None checks here."""
+        traced = [e for e in batch if e.trace is not None]
+        if not traced:
+            self._cycle_end = None
+            self._serve(batch, t_first, None)
+            return
+        record = Trace("dispatch", service="engine",
+                       start_perf=(t_first if self._cycle_end is None
+                                   else self._cycle_end))
+        if self._trace_log is not None:
+            self._trace_log.record(record)
+        self._close_cycle(record, traced, t_first,
+                          *self._serve(batch, t_first, record))
+
+    def _close_cycle(self, record: Trace, traced: list[_Pending],
+                     t_first: float, live: int, groups: int,
+                     t0: float | None, t1: float | None) -> None:
+        """Fill the cycle's record in: the four spans meet end to start,
+        from the previous traced cycle's end (none: a first cycle) to
+        this instant; a cycle whose riders all expired is all
+        ``dispatcher.collect``."""
+        t_end = time.perf_counter()
+        if self._cycle_end is not None:
+            record.add_span("dispatcher.idle", self._cycle_end, t_first)
+        if t0 is None:
+            record.add_span("dispatcher.collect", t_first, t_end)
+        else:
+            record.add_span("dispatcher.collect", t_first, t0)
+            record.add_span("dispatcher.dispatch", t0, t1)
+            record.add_span("dispatcher.handoff", t1, t_end)
+        for e in traced:
+            e.trace.tags["dispatch"] = record.trace_id
+        record.finish(end_perf=t_end, batch=live, groups=groups,
+                      traced=len(traced),
+                      requests=[e.trace.trace_id for e in traced])
+        self._cycle_end = t_end
+
+    def _serve(self, batch: list[_Pending], t_first: float,
+               record: Trace | None):
+        """Expire, dedup, dispatch, hand the results back. Returns
+        ``(live queries, groups, dispatch start, query_batch returned)``
+        on the perf_counter clock, the last two None when nothing was
+        dispatched; read only where ``record`` says the cycle is
+        traced."""
         # 1. fail anything already past its deadline — dispatching it
         # would burn a device slot on a client that stopped waiting
         now = time.monotonic()
@@ -294,7 +356,7 @@ class QueryBatcher:
             else:
                 live.append(entry)
         if not live:
-            return
+            return 0, 0, None, None
         # 2. dedup identical concurrent queries (same canonical key):
         # one device slot, every waiter shares the result
         groups: list[list[_Pending]] = []
@@ -308,9 +370,9 @@ class QueryBatcher:
                 groups.append([entry])
         deployed = self._get_deployed()
         deadlines = [e.deadline for e in live if e.deadline is not None]
+        # the batch shares one dispatch: honor its tightest deadline
+        t0 = time.perf_counter()
         try:
-            # the batch shares one dispatch: honor its tightest deadline
-            t0 = time.perf_counter()
             # queue-wait attribution (enqueue -> dispatch start): one
             # lock acquisition for the whole batch's samples, plus the
             # per-entry trace spans when tracing rode along;
@@ -318,25 +380,31 @@ class QueryBatcher:
             # dispatcher free (from the batch's first dequeue, or this
             # entry's own arrival if later)
             self.stats.observe_queue_waits([t0 - e.t_enq for e in live])
-            traced = [e for e in live if e.trace is not None]
+            traced = ([e for e in live if e.trace is not None]
+                      if record is not None else ())
             for e in traced:
                 e.trace.add_span(
                     "batcher.hold", max(t_first, e.t_enq), t0,
                     e.trace.add_span("batcher.queue_wait", e.t_enq, t0))
             # one ambient trace for the dispatch, only when a traced
             # query rides in it: ``span()`` calls under query_batch
-            # (dispatch.* phases, obs/compile's xla_compile) record on
-            # it and are copied below onto each traced entry's trace
-            dispatch = Trace("dispatch") if traced else None
-            ambient = (use_trace(dispatch) if dispatch is not None
+            # (dispatch.* phases, obs/compile's xla_compile, a
+            # gc.pause) record on it and are copied below onto each
+            # traced entry's trace. The cycle's record takes none of
+            # them: in the ring its names are its own four
+            phases = Trace("dispatch") if traced else None
+            ambient = (use_trace(phases) if phases is not None
                        else contextlib.nullcontext())
             with self._scope(min(deadlines) if deadlines else None), ambient:
                 results = deployed.query_batch([g[0].query for g in groups])
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            dt = t1 - t0
             self.stats.observe_device_time(dt)
-            for e in traced:
-                e.trace.add_spans_from(dispatch, e.trace.add_span(
-                    "batcher.device_dispatch", t0, t0 + dt))
+            # results before bookkeeping: a traced rider gets the one
+            # span its wake-up reads (_record_wake) ahead of its
+            # result, the phases under it once every waiter has its own
+            parents = [e.trace.add_span("batcher.device_dispatch", t0, t1)
+                       for e in traced]
             # query_batch records request bookkeeping only for the
             # group leaders it saw; the deduped waiters were answered
             # by the same dispatch and must count as served requests
@@ -351,6 +419,8 @@ class QueryBatcher:
                         except Exception:
                             pass
             self.stats.record_batch(len(groups), len(live))
+            for e, parent in zip(traced, parents):
+                e.trace.add_spans_from(phases, parent)
         except Exception:
             logger.exception(
                 "batched predict failed; retrying %d quer(ies) individually",
@@ -358,6 +428,10 @@ class QueryBatcher:
             record_fallback("serving/query-batcher")
             for group in groups:
                 self._fallback_group(group)
+            # the cycle's dispatch covers the failed batch and the
+            # retries, which set their futures themselves
+            t1 = time.perf_counter() if record is not None else None
+        return len(live), len(groups), t0, t1
 
     _UNSET = object()
 
