@@ -19,9 +19,11 @@ process at a time, so at no moment do two of them hold JAX):
 5. ``flash_attention`` compiled (never interpreted) against
    ``full_attention`` at both ends of the envelope the dispatcher
    declares, at the sessionrec serving shape; retention's fused state
-   pass against the ``jax.numpy`` step; the flat top-k's two-stage
-   selection against one ``lax.top_k`` at the Books cell's shape and
-   over a catalog 100 items longer; the ``deepseek_v2`` kind's tiled
+   pass against the ``jax.numpy`` step, and the fused way in (QK-norm,
+   RoPE, chunk order) against ``ops/qk_norm.prepare``'s; the flat
+   top-k's two-stage selection against one ``lax.top_k`` at the Books
+   cell's shape and over a catalog 100 items longer; the
+   ``deepseek_v2`` kind's tiled
    attention core (S 8,192, 128 heads, 192/128) and grouped product (40
    experts x 307 rows) against their plain forms.
 
@@ -173,8 +175,9 @@ def kernels_child() -> int:
         ok &= good
         print(f"flash S={s}: max|diff|={err:.3e} finite={finite} "
               f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
-    return 0 if (ok and retention_kernel_ok() and two_stage_topk_ok()
-                 and mla_kernels_ok() and sparse_kernels_ok()) else 1
+    return 0 if (ok and retention_kernel_ok() and qk_norm_kernel_ok()
+                 and two_stage_topk_ok() and mla_kernels_ok()
+                 and sparse_kernels_ok()) else 1
 
 
 def sparse_kernels_ok() -> bool:
@@ -331,6 +334,56 @@ def retention_kernel_ok() -> bool:
         print(f"retention {name} S={s} H={h} G={g} chunk={chunk}: "
               f"max|diff|={err:.3e} first_call_s={t1 - t0:.2f} "
               f"{'ok' if good else 'MISMATCH'}")
+    return ok
+
+
+def qk_norm_kernel_ok() -> bool:
+    """The fused way in (``ops/pallas_qk_norm.py``) against the
+    ``jax.numpy`` form compiled by XLA at the widths the session cells
+    run, three chunks of 256: MiniCPM-SALA's lightning q (32 heads),
+    Brumby's q (40 heads, a step of 128 rows; its ``jax.numpy`` form
+    writes a rounding after the norm, which XLA's TPU backend does not
+    perform, and the kernel has none) in retention's chunk order, and
+    the sparse layers' q token-major without positions. Same equations:
+    equal bit for bit when PR 37 read them; the tolerance is a last bit
+    of bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import qk_norm, retention
+
+    ok, s, d = True, 768, 128
+    rope = qk_norm.rope_tables(s, d, 10000.0)
+    for name, heads, how in (
+            ("lightning q", 32, dict(rope=rope, chunk=256)),
+            ("brumby q", 40, dict(rope=rope, chunk=256,
+                                  norm_dtype=jnp.bfloat16)),
+            ("sparse q", 32, dict(scale=d ** -0.5))):
+        chunk = how.pop("chunk", None)
+        if not qk_norm.fuses(d, heads * d, chunk or s, inference=True):
+            print(f"qk_norm {name}: the rule does not choose the kernel")
+            return False
+        kx, kw = jax.random.split(jax.random.PRNGKey(heads))
+        x = jax.random.normal(kx, (2, s, heads * d), jnp.bfloat16)
+        w = (2 + 0.1 * jax.random.normal(kw, (d,))).astype(jnp.bfloat16)
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(qk_norm.fused(
+            x, w, heads=heads, eps=1e-6, chunk=chunk,
+            **{k: v for k, v in how.items() if k != "norm_dtype"}))
+        t1 = time.perf_counter()
+        want = jax.jit(lambda x, w, how=how, heads=heads: qk_norm.prepare(
+            x, w, heads=heads, eps=1e-6, **how))(x, w)
+        if chunk:
+            want = retention.chunk_order(want, chunk)
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(got - want)))
+        good = got.shape == want.shape and bool(jnp.all(
+            jnp.isfinite(got))) and bool(jnp.all(
+                jnp.abs(got - want) <= RETENTION_TOL * (1 + jnp.abs(want))))
+        ok &= good
+        print(f"qk_norm {name} S={s} heads={heads} chunk={chunk}: "
+              f"max|diff|={err:.3e} equal={float(jnp.mean(got == want)):.4f} "
+              f"first_call_s={t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
     return ok
 
 

@@ -54,6 +54,7 @@ class ServingStats:
         # the session engine's dispatches (templates/sessionrec)
         "seq_programs", "seq_tokens", "seq_padded_tokens",
         "seq_split_dispatches", "seq_fused_retention_programs",
+        "seq_fused_qk_norm_programs",
         "seq_moe_assignments", "seq_moe_tokens", "seq_moe_max_expert_load",
         "seq_sparse_rows", "seq_sparse_blocks_selected",
         "seq_sparse_keys_scored",
@@ -135,7 +136,8 @@ class ServingStats:
         ``seq_<field>`` (``split`` to ``seq_split_dispatches``)."""
         with self._lock:
             for field in ("programs", "tokens", "padded_tokens",
-                          "fused_retention_programs", "moe_assignments",
+                          "fused_retention_programs",
+                          "fused_qk_norm_programs", "moe_assignments",
                           "moe_tokens", "moe_max_expert_load", "sparse_rows",
                           "sparse_blocks_selected", "sparse_keys_scored"):
                 self._counts["seq_" + field] += getattr(report, field)

@@ -45,13 +45,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.ops import qk_norm
 from predictionio_tpu.ops.attention import (
     blockwise_attention,
     full_attention,
     ring_attention,
 )
 from predictionio_tpu.ops.retention import (
-    fuses_state_pass, pick_chunk, power_retention)
+    WayIn, fuses_state_pass, pick_chunk, power_retention)
 
 logger = logging.getLogger(__name__)
 
@@ -520,22 +521,7 @@ def _forward_sasrec(params, seqs, cfg, mesh, seq_axis, inference):
 
 
 def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate (B, S, heads, d) by position 0..S-1: pairs (i, i + d/2),
-    frequency theta**(-2i/d), over all d dimensions; float32."""
-    S, d = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
-    x = x.astype(jnp.float32)
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    return qk_norm.normed(x, w, eps).astype(x.dtype)
 
 
 def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
@@ -551,19 +537,24 @@ def _forward_brumby(params, seqs, cfg, mesh, seq_axis, inference):
     B, S = seqs.shape
     H, G, hd, dt = cfg.n_heads, cfg.kv_heads, cfg.hd, cfg.dtype
     f32 = jnp.float32
+    # once a program: every layer's q and k turn by the same tables
+    rope = qk_norm.rope_tables(S, hd, cfg.rope_theta)
 
     def block(x, layer):
         h = _rms(x, layer["in_norm"], cfg.rms_eps)
         q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
         k = (h @ layer["wk"].astype(dt)).reshape(B, S, G, hd)
         v = (h @ layer["wv"].astype(dt)).reshape(B, S, G, hd)
-        q = _rope(_rms(q, layer["q_norm"], cfg.rms_eps), cfg.rope_theta)
-        k = _rope(_rms(k, layer["k_norm"], cfg.rms_eps), cfg.rope_theta)
         log_g = jax.nn.log_sigmoid(
             jnp.einsum("bsd,dg->bsg", h, layer["wg"].astype(dt),
                        preferred_element_type=f32) + cfg.gate_init_logit)
-        y = power_retention(q.astype(dt), k.astype(dt), v, log_g,
-                            degree=cfg.retention_degree, inference=inference)
+        # q and k go in as projected: QK-norm (rounded to the stream's
+        # type, as _rms rounds), RoPE and the rounding to operands
+        # happen in retention's pass into chunk order
+        y = power_retention(
+            q, k, v, log_g, degree=cfg.retention_degree, inference=inference,
+            way_in=WayIn(layer["q_norm"], layer["k_norm"], cfg.rms_eps, rope,
+                         dt))
         x = x + y.reshape(B, S, H * hd) @ layer["wo"].astype(dt)
         with jax.named_scope("swiglu"):
             h = _rms(x, layer["post_norm"], cfg.rms_eps)
@@ -712,12 +703,9 @@ def _forward_minicpm_sala(params, seqs, cfg, mesh, seq_axis, inference):
     valid = seqs != PAD
     last = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0)
     log_decay = jnp.asarray(lightning_log_decay(w.lightning_nkv))
-
-    def normed(x, weight, scale=1.0):
-        x32 = x.astype(f32)
-        return x32 * (jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
-                                             keepdims=True) + eps)
-                      * scale) * weight.astype(f32)
+    # once a program: the lightning layers' q and k turn by the same
+    # tables
+    rope = qk_norm.rope_tables(S, w.lightning_head_dim, cfg.rope_theta)
 
     def gated(o, h, layer):
         gate = jax.nn.sigmoid((h @ layer["wg"].astype(dt)).astype(f32))
@@ -725,11 +713,14 @@ def _forward_minicpm_sala(params, seqs, cfg, mesh, seq_axis, inference):
 
     def sparse_mixer(h, layer):
         H, G, hd = cfg.n_heads, cfg.kv_heads, cfg.hd
-        q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
-        k = (h @ layer["wk"].astype(dt)).reshape(B, S, G, hd)
         v = h @ layer["wv"].astype(dt)
-        q = normed(q, layer["q_norm"], 1.0 / math.sqrt(hd)).astype(dt)
-        k = normed(k, layer["k_norm"]).astype(dt)
+        # QK-norm (no positions: the sparse layers are NoPE), float32
+        # inside one pass, bfloat16 in and out
+        q = qk_norm.prepare(h @ layer["wq"].astype(dt), layer["q_norm"],
+                            heads=H, eps=eps, scale=1.0 / math.sqrt(hd),
+                            inference=inference)
+        k = qk_norm.prepare(h @ layer["wk"].astype(dt), layer["k_norm"],
+                            heads=G, eps=eps, inference=inference)
         o, counts, kept = sparse_attention.attend(
             q.reshape(B, S, H * hd), k.reshape(B, S, G * hd), v, sz,
             groups=G, valid=valid, inference=inference)
@@ -745,10 +736,11 @@ def _forward_minicpm_sala(params, seqs, cfg, mesh, seq_axis, inference):
         q = (h @ layer["wq"].astype(dt)).reshape(B, S, H, hd)
         k = (h @ layer["wk"].astype(dt)).reshape(B, S, H, hd)
         v = (h @ layer["wv"].astype(dt)).reshape(B, S, H, hd)
-        q = _rope(normed(q, layer["q_norm"]), cfg.rope_theta).astype(dt)
-        k = _rope(normed(k, layer["k_norm"]), cfg.rope_theta).astype(dt)
-        o = power_retention(q, k, v, log_decay, degree=1,
-                            inference=inference)
+        # q and k go in as projected: QK-norm x RoPE in float32 with no
+        # rounding between them, rounded once, written in chunk order
+        o = power_retention(
+            q, k, v, log_decay, degree=1, inference=inference,
+            way_in=WayIn(layer["q_norm"], layer["k_norm"], eps, rope))
         o = _rms(o.reshape(B, S, H * hd), layer["o_norm"], eps)
         return gated(o, h, layer)
 
@@ -790,10 +782,21 @@ def _kernels_minicpm_sala(cfg: SeqRecConfig, seq_len: int) -> tuple:
     from predictionio_tpu.ops import sparse_attention
 
     w = cfg.sala
+    names = ()
     if "minicpm4" in w.mixer_types and sparse_attention.uses_kernel(
             seq_len, True, w.sparse, cfg.hd, cfg.n_heads // cfg.kv_heads):
-        return sparse_attention.kernel_names(seq_len, w.sparse)
-    return ()
+        names = sparse_attention.kernel_names(seq_len, w.sparse)
+    # each mixer's way in: head width, projection width, and the rows a
+    # step must divide (retention's chunk; the sequence, token-major)
+    ways_in = {
+        "lightning-attn": (w.lightning_head_dim,
+                           w.lightning_nh * w.lightning_head_dim,
+                           pick_chunk(seq_len)),
+        "minicpm4": (cfg.hd, cfg.n_heads * cfg.hd, seq_len)}
+    if any(qk_norm.fuses(*ways_in[m], inference=True)
+           for m in set(w.mixer_types)):
+        names += ("qk_norm_rope",)
+    return names
 
 
 def _tally_deepseek_v2(cfg: SeqRecConfig, tokens: int, per_expert) -> dict:
@@ -836,9 +839,13 @@ def _bytes_deepseek_v2(cfg: SeqRecConfig) -> int:
 
 
 def _kernels_brumby(cfg: SeqRecConfig, seq_len: int) -> tuple:
-    fused = fuses_state_pass(cfg.hd, cfg.n_heads // cfg.kv_heads,
-                             pick_chunk(seq_len), inference=True)
-    return ("retention_state_pass",) if fused else ()
+    chunk = pick_chunk(seq_len)
+    fused = fuses_state_pass(cfg.hd, cfg.n_heads // cfg.kv_heads, chunk,
+                             inference=True)
+    way_in = qk_norm.fuses(cfg.hd, cfg.n_heads * cfg.hd, chunk,
+                           inference=True)
+    return (("retention_state_pass",) if fused else ()) \
+        + (("qk_norm_rope",) if way_in else ())
 
 
 def _kernels_deepseek_v2(cfg: SeqRecConfig, seq_len: int) -> tuple:
@@ -857,6 +864,14 @@ def fuses_retention(cfg: SeqRecConfig, seq_len: int) -> bool:
     state pass in the fused kernel: the kind's own rule, for the
     counters of whoever launches the program."""
     return "retention_state_pass" in BLOCKS[cfg.block].kernels(cfg, seq_len)
+
+
+def fuses_qk_norm(cfg: SeqRecConfig, seq_len: int) -> bool:
+    """Whether such a program runs its mixers' way in (QK-norm, RoPE,
+    the rounding, retention's chunk order) in the fused kernel
+    (``ops/qk_norm.fuses``): the kind's own rule, for the same
+    counters."""
+    return "qk_norm_rope" in BLOCKS[cfg.block].kernels(cfg, seq_len)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -46,16 +46,36 @@ meet, so that nothing as wide as the state but the state crosses HBM;
 observe and with no option. Everything else of the step — the in-chunk
 quadratic form, the normaliser, the gates — and the ``lax.scan`` over
 chunks are ``jax.numpy`` on both.
+
+The layouts, and who owns them. Every (S, heads, d) array between a
+mixer's projections and its output projection crosses HBM once each
+way, in the operands' type. **In**: :func:`chunk_order` defines the
+chunk-major layout and :func:`_chunk_major` is its one caller, for both
+degrees; given a :class:`WayIn` it also norms, rotates and rounds q and
+k in that same pass (``ops/qk_norm``: the fused kernel writes chunk
+order itself on a compiled backend, the ``jax.numpy`` form elsewhere),
+so no float32 copy of q or k is ever an array. **The scan** stacks what
+a step returns: each step rounds its float32 sum once to the caller's
+type and lays it as rows of the way out (:func:`_rows`), so the stack is
+(chunks, B, C, H * d) in bfloat16 for a bfloat16 caller, half of what a
+float32 stack was. **Out**: :func:`_token_major` merges the chunks and
+pins the narrow array with a barrier. The scan stays one top-level
+``while`` a layer in the compiled program: the benchmark's
+``power_retention_*`` and ``lightning_attention_*`` readers time that op
+(``benchmarks/layer_metrics``) and read nothing if it is unrolled,
+nested or renamed; what they time includes the step's write of its rows.
 """
 
 from __future__ import annotations
+
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from predictionio_tpu.ops import pallas_attention, pallas_retention
+from predictionio_tpu.ops import pallas_attention, pallas_retention, qk_norm
 
 #: ``phi`` keeps the upper block triangle of ``u u^T`` in blocks of this
 #: many coordinates (diagonal blocks whole, the others doubled): at
@@ -155,6 +175,7 @@ def power_retention(
     chunk: int | None = None,
     eps: float = 1e-6,
     inference: bool = False,
+    way_in: WayIn | None = None,
     _state_dtype=jnp.float32,
 ) -> jax.Array:
     """The mixing above; returns (B, S, H, d) in ``q.dtype``.
@@ -166,8 +187,12 @@ def power_retention(
     alone. ``chunk`` is chosen from the sequence length when not given (tests
     pass small ones). ``inference`` says that the caller takes no
     gradient: the state pass may then run in the forward-only kernel
-    (:func:`fuses_state_pass`). ``_state_dtype`` exists for one test,
-    which shows that a state accumulated in bfloat16 is caught."""
+    (:func:`fuses_state_pass`), and so may the way in. With ``way_in``
+    q and k are their projections' outputs, and the norm, the rotation
+    and the one rounding that make them operands happen in the pass
+    that writes chunk order (:class:`WayIn`, :func:`_chunk_major`).
+    ``_state_dtype`` exists for one test, which shows that a state
+    accumulated in bfloat16 is caught."""
     if degree not in (1, 2):
         raise NotImplementedError(
             f"power retention of degree {degree}: the family holds degree "
@@ -178,57 +203,132 @@ def power_retention(
         raise ValueError(
             f"{q.shape[2]} query heads over {k.shape[2]} key/value heads")
     chunk = chunk or pick_chunk(q.shape[1])
-    if degree == 1:
-        with jax.named_scope("lightning_attention"):
-            return _linear_retention(q, k, v, log_g, chunk, _state_dtype)
-    kernel = "compiled" if fuses_state_pass(
-        q.shape[3], q.shape[2] // k.shape[2], chunk, inference=inference,
-        state_dtype=_state_dtype) else None
-    with jax.named_scope("power_retention"):
-        return _power_retention(q, k, v, log_g, chunk, eps, _state_dtype,
-                                kernel)
+    # degree 1's constant decay is no operand of the scan
+    constant = degree == 1 and log_g.ndim == 1
+    with jax.named_scope(
+            "lightning_attention" if degree == 1 else "power_retention"):
+        operands = _chunk_major(q, k, v, None if constant else log_g, chunk,
+                                way_in, inference)
+        if degree == 1:
+            y = _linear_retention(*operands, log_g if constant else None,
+                                  _state_dtype, q.dtype)
+        else:
+            kernel = "compiled" if fuses_state_pass(
+                q.shape[3], q.shape[2] // k.shape[2], chunk,
+                inference=inference, state_dtype=_state_dtype) else None
+            y = _power_retention(*operands, eps, _state_dtype, kernel,
+                                 q.dtype)
+        return _token_major(y, *q.shape[1:3])
 
 
-def _chunk_major(q, k, v, log_g, C):
-    """Both degrees' layout: the sequence padded to whole chunks of
-    ``C``, then chunk-major with heads before positions, bfloat16
-    operands and float32 gates: q (n, B, G, R, C, d), k and v (n, B, G,
-    C, d), ``log_g`` (B, S, G) as (n, B, G, C) (degree 1 with a constant
-    decay passes none: G = 0 there)."""
+class WayIn(NamedTuple):
+    """What stands between the q and k projections and the scan when
+    :func:`power_retention` is given the projections' outputs: a
+    per-head RMS norm with these weights, RoPE by these tables
+    (``ops/qk_norm.rope_tables``, built once a program; None: no
+    positions) and one rounding to the operands' type; ``norm_dtype``
+    is ``ops/qk_norm.prepare``'s: the rounding Brumby's code writes
+    between norm and rotation, which the CPU performs and the TPU (XLA's
+    backend and the kernel alike) does not; MiniCPM-SALA's code has
+    none."""
+    q_weight: jax.Array
+    k_weight: jax.Array
+    eps: float
+    rope: tuple | None = None
+    norm_dtype: Any = None
+
+
+def chunk_order(x, C):
+    """**The layout, defined here and nowhere else**: (B, S, heads, d),
+    S a whole number of chunks of ``C``, to chunk-major with heads
+    before positions, (S / C, B, heads, C, d). The scan reads one
+    leading index a step; a head's (C, d) tile is contiguous. The fused
+    way in (``ops/pallas_qk_norm.py``) writes this order from its
+    output blocks' index map; a test holds the two equal."""
+    B, S, heads, d = x.shape
+    return x.reshape(B, S // C, C, heads, d).transpose(1, 0, 3, 2, 4)
+
+
+def _chunk_major(q, k, v, log_g, C, way_in=None, inference=False):
+    """Both degrees' way in, the one caller of :func:`chunk_order`: the
+    sequence padded to whole chunks of ``C``, then bfloat16 operands in
+    chunk order with the query heads split by group, q (n, B, G, R, C,
+    d), k and v (n, B, G, C, d), and float32 gates, ``log_g`` (B, S, G)
+    as (n, B, G, C) (None stays None: degree 1's constant decay).
+
+    With ``way_in`` q and k arrive as projected and become operands
+    here, in one pass each: on a compiled TPU backend for a caller that
+    takes no gradient (``ops/qk_norm.fuses``) the fused kernel reads the
+    bfloat16 projection and writes normed, rotated, rounded tiles
+    straight into chunk order; elsewhere ``ops/qk_norm.prepare``'s
+    ``jax.numpy`` form runs token-major and :func:`chunk_order` moves
+    its bfloat16 result. v is only moved."""
     B, S, H, d = q.shape
     G = k.shape[2]
-    R = H // G
     pad = (-S) % C
+    rope = way_in.rope if way_in is not None else None
     if pad:
         q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for t in (q, k, v))
-        log_g = jnp.pad(log_g, ((0, 0), (0, pad), (0, 0)))
+        if log_g is not None:
+            log_g = jnp.pad(log_g, ((0, 0), (0, pad), (0, 0)))
+        if rope is not None:
+            rope = tuple(jnp.pad(t, ((0, pad), (0, 0))) for t in rope)
     n = (S + pad) // C
     bf16, f32 = jnp.bfloat16, jnp.float32
-    # chunk-major, heads before positions: (n, B, G, [R,] C, d)
-    qc = q.reshape(B, n, C, G, R, d).transpose(1, 0, 3, 4, 2, 5).astype(bf16)
-    kc = k.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
-    vc = v.reshape(B, n, C, G, d).transpose(1, 0, 3, 2, 4).astype(bf16)
-    gc = log_g.astype(f32).reshape(B, n, C, log_g.shape[2]) \
-        .transpose(1, 0, 3, 2)
+
+    def prepared(x, weight):
+        heads = x.shape[2]
+        how = dict(heads=heads, eps=way_in.eps, rope=rope)
+        flat = x.reshape(B, S + pad, heads * d)
+        if qk_norm.fuses(d, heads * d, C, inference=inference):
+            out = qk_norm.fused(flat, weight, chunk=C, **how)
+        else:
+            out = chunk_order(qk_norm.prepare(
+                flat, weight, norm_dtype=way_in.norm_dtype, **how), C)
+        return out.astype(bf16)
+
+    if way_in is None:
+        qc, kc = (chunk_order(x.astype(bf16), C) for x in (q, k))
+    else:
+        qc, kc = prepared(q, way_in.q_weight), prepared(k, way_in.k_weight)
+    qc = qc.reshape(n, B, G, H // G, C, d)
+    vc = chunk_order(v.astype(bf16), C)
+    gc = None if log_g is None else log_g.astype(f32) \
+        .reshape(B, n, C, log_g.shape[2]).transpose(1, 0, 3, 2)
     return qc, kc, vc, gc
 
 
-def _token_major(y, S, dtype):
-    """(n, B, G, R, C, d) out of the scan -> (B, S, H, d)."""
-    n, B, G, R, C, d = y.shape
-    y = y.transpose(1, 0, 4, 2, 3, 5).reshape(B, n * C, G * R, d)
-    return y[:, :S].astype(dtype)
+def _rows(y, dtype):
+    """A chunk step's float32 output (B, G, R, C, d), rounded once to
+    the caller's type and laid as the way out wants it: (B, C, H * d),
+    a position's heads side by side. The step's last fusion writes its
+    (C, d) tiles in that order at no cost; stacked in chunk order the
+    same tiles need a pass of their own to be moved."""
+    B, G, R, C, d = y.shape
+    return y.astype(dtype).transpose(0, 3, 1, 2, 4).reshape(B, C, G * R * d)
 
 
-def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
-    """``kernel``: None for the ``jax.numpy`` state pass, ``"compiled"``
+def _token_major(y, S, heads):
+    """The way out: (n, B, C, H * d) as the scan stacked :func:`_rows`
+    (already in the caller's type: a step rounds its float32 sum once)
+    -> (B, S, H, d). At B = 1 nothing moves. The barrier keeps the
+    array narrow: without it XLA may fuse the consumer's widening (an
+    output norm, a gate) into the move and write, relay out and read a
+    float32 array twice the size (PERF.md section 5, PR 37)."""
+    n, B, C, width = y.shape
+    y = y.transpose(1, 0, 2, 3).reshape(B, n * C, width)
+    return lax.optimization_barrier(y[:, :S]).reshape(B, S, heads, -1)
+
+
+def _power_retention(qc, kc, vc, gc, eps, state_dtype, kernel, out_dtype):
+    """Degree 2 over :func:`_chunk_major`'s operands; returns the
+    chunks' outputs stacked, (n, B, C, H * d) in ``out_dtype``
+    (:func:`_rows`).
+    ``kernel``: None for the ``jax.numpy`` state pass, ``"compiled"``
     or ``"interpret"`` (tests, on the CPU) for the fused one."""
-    B, S, H, d = q.shape
-    G = k.shape[2]
-    R = H // G
+    _, B, G, R, C, d = qc.shape
     bf16, f32 = jnp.bfloat16, jnp.float32
-    qc, kc, vc, gc = _chunk_major(q, k, v, log_g, C)
     causal = np.tril(np.ones((C, C), bool))
     inv_d = 1.0 / d
     hi = lax.Precision.HIGHEST
@@ -276,30 +376,30 @@ def _power_retention(q, k, v, log_g, C, eps, state_dtype, kernel):
         k32 = ki.astype(f32)
         norm = keep * norm.astype(f32) + inv_d * jnp.einsum(
             "bgsd,bgse->bgde", k32 * left[..., None], k32, precision=hi)
-        return (state.astype(state_dtype), norm.astype(state_dtype)), y
+        return (state.astype(state_dtype), norm.astype(state_dtype)), \
+            _rows(y, out_dtype)
 
     # the kernel keeps its state per key/value head of the batch, the
     # features in its own order (pallas_retention.block_pairs)
     state_shape = (B * G,) if kernel else (B, G)
     init = (jnp.zeros((*state_shape, phi_width(d), d), state_dtype),
             jnp.zeros((B, G, d, d), state_dtype))
-    _, y = lax.scan(step, init, (qc, kc, vc, gc))           # (n, B, G, R, C, d)
-    return _token_major(y, S, q.dtype)
+    _, y = lax.scan(step, init, (qc, kc, vc, gc))           # (n, B, C, H * d)
+    return y
 
 
-def _linear_retention(q, k, v, log_g, C, state_dtype):
-    """Degree 1 through the same chunks: inside a chunk ``((q k^T) *
-    decay / sqrt(d)) v``, between chunks one (d, d) float32 state per
-    key/value head, read by the chunk's queries and then decayed to the
-    chunk's end with its keys and values added. A ``log_g`` of shape
-    (G,) is one constant decay per head: the decay matrix is then the
+def _linear_retention(qc, kc, vc, gc, log_decay, state_dtype, out_dtype):
+    """Degree 1 through the same chunks (:func:`_chunk_major`'s
+    operands; returns the stacked outputs as :func:`_power_retention`
+    does): inside a chunk ``((q k^T) * decay / sqrt(d)) v``, between
+    chunks one (d, d) float32 state per key/value head, read by the
+    chunk's queries and then decayed to the chunk's end with its keys
+    and values added. ``log_decay`` (G,) in the place of the gates
+    ``gc`` is one constant decay per head: the decay matrix is then the
     same in every chunk and is worked once, outside the scan."""
-    B, S, H, d = q.shape
-    G = k.shape[2]
+    _, B, G, _, C, d = qc.shape
     bf16, f32 = jnp.bfloat16, jnp.float32
-    constant = log_g.ndim == 1
-    qc, kc, vc, gc = _chunk_major(
-        q, k, v, jnp.zeros((B, S, 0), f32) if constant else log_g, C)
+    constant = gc is None
     causal = np.tril(np.ones((C, C), bool))
     scale = 1.0 / np.sqrt(d)
 
@@ -316,8 +416,8 @@ def _linear_retention(q, k, v, log_g, C, state_dtype):
                 jnp.exp(total)[..., None, None])
 
     if constant:
-        fixed = decays(jnp.broadcast_to(log_g.astype(f32)[None, :, None],
-                                        (1, G, C)))
+        fixed = decays(jnp.broadcast_to(
+            log_decay.astype(f32)[None, :, None], (1, G, C)))
 
     def step(state, xs):
         qi, ki, vi, gi = xs
@@ -332,8 +432,8 @@ def _linear_retention(q, k, v, log_g, C, state_dtype):
         vl = (vi.astype(f32) * left).astype(bf16)
         state = keep * state.astype(f32) + jnp.einsum(
             "bgsd,bgse->bgde", ki, vl, preferred_element_type=f32)
-        return state.astype(state_dtype), y
+        return state.astype(state_dtype), _rows(y, out_dtype)
 
     _, y = lax.scan(step, jnp.zeros((B, G, d, d), state_dtype),
                     (qc, kc, vc, gc))
-    return _token_major(y, S, q.dtype)
+    return y

@@ -220,6 +220,9 @@ class SeqDispatch:
     #: of the programs, those whose retention layers ran the fused
     #: state pass (seqrec.fuses_retention)
     fused_retention_programs: int = 0
+    #: of the programs, those whose mixers' way in (QK-norm, RoPE, chunk
+    #: order) ran the fused kernel (seqrec.fuses_qk_norm)
+    fused_qk_norm_programs: int = 0
     #: what a kind's programs counted on the device, by the kind's own
     #: ``BlockKind.tally``, summed over the programs. The routed layers
     #: ("deepseek_v2"): assignments to held experts over all expert
@@ -373,6 +376,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         # one answer for every program: the rule looks at the widths and
         # at the history length, not at the batch
         fused = seqrec.fuses_retention(model.cfg, S)
+        fused_way_in = seqrec.fuses_qk_norm(model.cfg, S)
         tally = seqrec.BLOCKS[model.cfg.block].tally
         counted = collections.Counter()     # SeqDispatch field -> sum
         while pos < len(rows):
@@ -403,6 +407,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                 programs=programs, tokens=int(lengths.sum()),
                 padded_tokens=len(rows) * S, split=int(len(rows) > widest),
                 fused_retention_programs=programs if fused else 0,
+                fused_qk_norm_programs=programs if fused_way_in else 0,
                 **counted))
         return out
 

@@ -18,9 +18,10 @@ import pytest
 
 from benchmarks.reference import brumby_jnp as ref
 from predictionio_tpu.models import seqrec
-from predictionio_tpu.ops import retention
+from predictionio_tpu.ops import qk_norm, retention
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
+from tests import retention_cases as cases
 
 ITEMS, S = 500, 64
 #: the tiny preset: d 64, 4 and 2 heads of 16, SwiGLU 128, 2 layers
@@ -121,6 +122,76 @@ def test_gradients_match_autodiff_of_the_quadratic_form():
     for g, w in zip(got, want):
         assert bool(jnp.all(jnp.isfinite(g)))
         assert _rel(g, w) < 0.1
+
+
+@pytest.mark.parametrize("name", list(cases.CASES))
+def test_gradients_through_retention_are_the_parents(name):
+    """``inference=False`` goes through the same layout functions, the
+    step's cast and the way out's barrier: the gradient of ``sum(out *
+    probe)`` by q, k, v and the gates, against the parent commit's
+    (3ae3a70, on the CPU: the sum of absolute values of each; a sum's
+    order may differ in its last bits)."""
+    case = cases.CASES[name]
+    q, k, v, log_g = cases.inputs(name, **case)
+    probe = cases.probe(q.shape)
+
+    def loss(q, k, v, log_g):
+        out = retention.power_retention(
+            q, k, v, log_g, degree=case["degree"], chunk=case["chunk"],
+            inference=False)
+        return jnp.sum(out.astype(jnp.float32) * probe)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, v, log_g)
+    for g, x, want in zip(grads, (q, k, v, log_g), cases.PARENT[name][1]):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        assert bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_allclose(
+            float(jnp.sum(jnp.abs(g.astype(jnp.float32)))), want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("R", [1, 2])
+def test_the_way_in_rounds_where_the_model_did_and_carries_the_gradient(R):
+    """Brumby's q and k through ``power_retention``'s way in — QK-norm
+    rounded to the stream's type as ``_rms`` rounded it, RoPE, the cast
+    — against the parent's formulation written out: the operands and
+    the mixing bit for bit, the gradient by the projections' outputs
+    and the norm's weights within float32 tolerance."""
+    B, n, G, d, C = 2, 40, 2, 16, 16
+    H = G * R
+    rng = np.random.default_rng(R)
+    q = jnp.asarray(rng.standard_normal((B, n, H * d)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((B, n, G * d)), jnp.bfloat16)
+            for _ in range(2))
+    wq, wk = (jnp.asarray(1 + 0.1 * rng.standard_normal(d), jnp.float32)
+              for _ in range(2))
+    log_g = jnp.asarray(-np.abs(rng.standard_normal((B, n, G))) * 0.05,
+                        jnp.float32)
+    probe = cases.probe((B, n, H, d))
+    how = dict(eps=1e-6, theta=1e6, norm_dtype=jnp.bfloat16)
+
+    def new(q, k, wq, wk):
+        way_in = retention.WayIn(wq, wk, 1e-6,
+                                 qk_norm.rope_tables(n, d, 1e6), jnp.bfloat16)
+        return retention.power_retention(
+            q.reshape(B, n, H, d), k.reshape(B, n, G, d),
+            v.reshape(B, n, G, d), log_g, chunk=C, way_in=way_in)
+
+    def parents(q, k, wq, wk):
+        return retention.power_retention(
+            cases.plain_way_in(q, wq, heads=H, **how),
+            cases.plain_way_in(k, wk, heads=G, **how),
+            v.reshape(B, n, G, d), log_g, chunk=C)
+
+    np.testing.assert_array_equal(np.asarray(new(q, k, wq, wk), np.float32),
+                                  np.asarray(parents(q, k, wq, wk),
+                                             np.float32))
+    got, want = (jax.grad(
+        lambda *a, f=f: jnp.sum(f(*a).astype(jnp.float32) * probe),
+        argnums=(0, 1, 2, 3))(q, k, wq, wk) for f in (new, parents))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_padding_after_the_last_event_changes_nothing():

@@ -23,9 +23,10 @@ import pytest
 from benchmarks.reference import minicpm_sala_jnp as ref
 from predictionio_tpu.api.stats import ServingStats
 from predictionio_tpu.models import seqrec
-from predictionio_tpu.ops import retention, sparse_attention as sa
+from predictionio_tpu.ops import qk_norm, retention, sparse_attention as sa
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
+from tests import retention_cases as cases
 
 ITEMS, S = 300, 192
 MIXERS = ("minicpm4", "lightning-attn", "lightning-attn", "minicpm4")
@@ -263,6 +264,81 @@ def test_power_retention_of_degree_2_is_byte_for_byte_what_it_was():
 DEGREE_2_DIGEST = "c9246bd5b56c5e36c44a288894f088695de9e230ec5b9f37a3dcf46649c0cbb4"
 
 
+@pytest.mark.parametrize("name", list(cases.CASES))
+def test_power_retention_is_byte_for_byte_the_parents(name):
+    """PR 37 moved the cast into the scan's step, the way out into the
+    step's last write and the layout into one function: values do not
+    move. The digests are the parent commit's outputs (3ae3a70) on the
+    CPU: both degrees, R = 1 and R > 1, S not a whole number of chunks,
+    B = 2, a constant decay and a gate a position, bfloat16 and float32
+    callers."""
+    case = cases.CASES[name]
+    q, k, v, log_g = cases.inputs(name, **case)
+    out = retention.power_retention(q, k, v, log_g, degree=case["degree"],
+                                    chunk=case["chunk"])
+    assert out.shape == q.shape and out.dtype == q.dtype
+    assert cases.digest(out) == cases.PARENT[name][0]
+    # a caller that takes no gradient gets the same bytes on the CPU
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(retention.power_retention(
+            q, k, v, log_g, degree=case["degree"], chunk=case["chunk"],
+            inference=True), np.float32))
+
+
+def test_a_forward_pass_builds_the_rope_tables_once():
+    """cos and sin are made where the program starts, not once a layer
+    and array (four lightning layers here: eight rotations)."""
+    cfg, weights = _weights(sala={**WIDTHS, "mixer_types": [
+        "lightning-attn"] * 4})
+    assert cfg.sala.mixer_types.count("lightning-attn") == 4
+    seqs = jnp.asarray(_history(1)[None, :64])
+    for inference in (True, False):
+        jaxpr = str(jax.make_jaxpr(
+            lambda w: seqrec.BLOCKS["minicpm_sala"].forward(
+                w, seqs, cfg, None, "seq", inference))(weights))
+        assert jaxpr.count(" cos ") == jaxpr.count(" sin ") == 1
+        assert jaxpr.count("optimization_barrier") == 4     # a way out each
+
+
+def test_the_lightning_mixers_way_in_is_the_parents_formulation():
+    """One lightning layer's q through ``power_retention``'s way in
+    against norm, RoPE by ``concatenate``, cast and
+    ``reshape().transpose()`` written out: bit for bit (no rounding
+    between norm and rotation)."""
+    cfg, weights = _weights()
+    layer = weights["layers"][1]
+    rng = np.random.default_rng(4)
+    B, n, H, d, C = 2, 70, 4, 16, 32
+    q, k = (jnp.asarray(rng.standard_normal((B, n, H * d)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((B, n, H, d)), jnp.bfloat16)
+    way_in = retention.WayIn(layer["q_norm"], layer["k_norm"], 1e-6,
+                             qk_norm.rope_tables(n, d, 10000.0))
+    qc, kc, vc, gc = retention._chunk_major(
+        q.reshape(B, n, H, d), k.reshape(B, n, H, d), v, None, C, way_in)
+    assert gc is None and qc.shape == (3, B, H, 1, C, d)
+    pad = ((0, 0), (0, 3 * C - n), (0, 0))
+    for got, x, weight in ((qc[:, :, :, 0], q, layer["q_norm"]),
+                           (kc, k, layer["k_norm"])):
+        # the parent normed and rotated, then padded: padding is zeros
+        want = cases.plain_way_in(x, weight, heads=H, eps=1e-6, theta=10000.0)
+        want = jnp.pad(want.reshape(B, n, H * d), pad).reshape(
+            B, 3, C, H, d).transpose(1, 0, 3, 2, 4)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    # and the mixing over them is the mixing over the parent's operands
+    log_decay = jnp.asarray(seqrec.lightning_log_decay(H))
+    plain = [cases.plain_way_in(x, w, heads=H, eps=1e-6, theta=10000.0)
+             for x, w in ((q, layer["q_norm"]), (k, layer["k_norm"]))]
+    np.testing.assert_array_equal(
+        np.asarray(retention.power_retention(
+            q.reshape(B, n, H, d), k.reshape(B, n, H, d), v, log_decay,
+            degree=1, chunk=C, way_in=way_in), np.float32),
+        np.asarray(retention.power_retention(
+            *plain, v, log_decay, degree=1, chunk=C), np.float32))
+
+
 # -- the sparse kernels in interpret mode -------------------------------------
 
 KSZ = sa.SparseSizes(**{**SPARSE, "topk": 5})
@@ -369,7 +445,9 @@ def test_only_a_compiled_backend_at_an_eligible_shape_runs_the_kernels(
         sala={}).seqrec_config(vocab=73448)
     assert full.sala.mixer_types.count("minicpm4") == 8
     assert seqrec.BLOCKS["minicpm_sala"].kernels(full, 32768) == \
-        sa.kernel_names(32768, sz)
+        sa.kernel_names(32768, sz) + ("qk_norm_rope",)
+    assert seqrec.fuses_qk_norm(full, 32768)
+    assert not seqrec.fuses_qk_norm(cfg, S)                 # heads of 16
 
 
 # -- the program against the reference ---------------------------------------
