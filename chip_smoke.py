@@ -31,7 +31,9 @@ process at a time, so at no moment do two of them hold JAX):
    two grouped products of a relu2 expert (64 experts x 768 rows) and
    its attention (S 16,384, 32 heads over 2, nothing masked) against
    their plain forms; the routed layers' row kernel (``moe_rows``) at
-   both MoE cells' widths against plain gathers, bit for bit.
+   both MoE cells' widths against plain gathers, bit for bit; the
+   ``laguna`` kind's windowed kernel (S 16,384, 72 heads over 8, a
+   window of 512) against its plain form at every position.
 
 It passes only on a TPU: any other platform, any failed child or any
 malformed answer is a non-zero exit with the child's last lines, and no
@@ -184,7 +186,7 @@ def kernels_child() -> int:
     return 0 if (ok and retention_kernel_ok() and qk_norm_kernel_ok()
                  and two_stage_topk_ok() and mla_kernels_ok()
                  and sparse_kernels_ok() and nemotron_h_parts_ok()
-                 and moe_rows_ok()) else 1
+                 and moe_rows_ok() and window_kernel_ok()) else 1
 
 
 def moe_rows_ok() -> bool:
@@ -405,6 +407,47 @@ def sparse_kernels_ok() -> bool:
           f"key blocks scored {counts.tolist()} "
           f"{'ok' if good else 'MISMATCH'}")
     return ok and good
+
+
+def window_kernel_ok() -> bool:
+    """The ``laguna`` kind's windowed kernel at its cell's shape (S
+    16,384, 72 query heads over 8 key/value heads of 128, a window of
+    512) against its plain form at every position: the windows that
+    start at position 0, mid-tile and on tile bounds; and the keys it
+    scored, read off its tile bounds (640 a position and group past the
+    first window)."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import sparse_attention as sa
+
+    s, G, R, d, window = 16384, 8, 9, 128, 512
+    if not sa.uses_window_kernel(s, True, d, R):
+        print("window: the rule does not choose the kernel")
+        return False
+    keys = jax.random.split(jax.random.PRNGKey(s + 1), 3)
+    q = (jax.random.normal(keys[0], (1, s, G * R * d)) * 4 / d ** 0.5
+         ).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, s, G * d)).astype(jnp.bfloat16)
+            for key in keys[1:])
+    core = jax.jit(lambda q, k, v: sa.attend_window(
+        q, k, v, groups=G, window=window, inference=True))
+    t0 = time.perf_counter()
+    out, counts = jax.block_until_ready(core(q, k, v))
+    t1 = time.perf_counter()
+    plain = jax.jit(lambda q, k, v: sa._attend_plain(
+        q.reshape(1, s, G, R, d), k.reshape(1, s, G, d),
+        v.reshape(1, s, G, d), None, None, window))(q, k, v)
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                - plain.reshape(1, s, -1))))
+    scored = int(sa.window_scored(s, window, True).sum()) * G
+    good = bool(jnp.all(jnp.isfinite(out.astype(jnp.float32)))) and \
+        err <= FLASH_TOL * 4 and counts.tolist() == [s * G, scored]
+    print(f"window attention S={s} W={window}: max|diff|={err:.3e} "
+          f"rows, keys scored {counts.tolist()} "
+          f"({counts[1] / counts[0]:.1f} a position) first_call_s="
+          f"{t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
+    return good
 
 
 def mla_kernels_ok() -> bool:

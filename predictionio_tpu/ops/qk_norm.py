@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from predictionio_tpu.ops import pallas_attention, pallas_qk_norm
 
@@ -45,6 +46,37 @@ def rope_tables(seq_len: int, d: int, theta: float):
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
+
+
+def partial_order(d: int, rotary: int) -> np.ndarray:
+    """The lane order of a head that turns RoPE over its first ``rotary``
+    lanes (pairs (i, i + rotary/2), the rest passed through, as a
+    ``partial_rotary_factor`` below 1 publishes it) into the full-width
+    pairs (i, i + d/2) that :func:`rotate` and the kernel turn: each
+    rotary half takes the front of one half of the head, the passed-
+    through lanes the rest. Applied to the **columns of the weights**
+    (q and k alike, and the norm's weight), so every dot product is the
+    published one."""
+    half, pas = rotary // 2, (d - rotary) // 2
+    lanes = np.arange(d)
+    return np.concatenate([lanes[:half], lanes[rotary:rotary + pas],
+                           lanes[half:rotary], lanes[rotary + pas:]])
+
+
+def partial_rope_tables(seq_len: int, d: int, inv_freq, magnitude: float):
+    """:func:`rope_tables` for heads in :func:`partial_order`: the
+    ``len(inv_freq)`` pairs at the front of each half turn by
+    ``inv_freq`` a position with cos and sin times ``magnitude`` (YaRN's
+    ``attention_factor``), the lanes after them pass through (cos 1,
+    sin 0)."""
+    ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos, sin = jnp.cos(ang) * magnitude, jnp.sin(ang) * magnitude
+    pad = ((0, 0), (0, d // 2 - ang.shape[1]))
+    cos = jnp.pad(cos, pad, constant_values=1.0)
+    sin = jnp.pad(sin, pad)
     return (jnp.concatenate([cos, cos], axis=-1),
             jnp.concatenate([-sin, sin], axis=-1))
 

@@ -51,6 +51,16 @@ option, flag or environment variable):
   causal attention.
 - the **plain** forms (``jax.numpy``: differentiable, any backend, any
   S): the same equations in query tiles under ``lax.map``.
+
+A sliding-window layer (Laguna's, ``models/laguna.py``) has its own
+core, :func:`attend_window`: position t sees keys ``t - W < i <= t``.
+Its kernel ``window_attention`` is ``sparse_block_attention``'s tiling
+with no visit map: the loop over key tiles of ``TILE_W`` keys starts at
+the first tile the query tile's window reaches and ends at its diagonal
+(:func:`window_tiles`), and the window is a mask inside those tiles; a
+group's K and V are resident as there. The rule is
+:func:`uses_window_kernel`; the plain form is ``_attend_plain`` with the
+window as a mask.
 """
 
 from __future__ import annotations
@@ -72,6 +82,9 @@ _NEG = -1e30
 #: and keys a visit covers; PERF.md section 6 (PR 34) has the sweep
 TILE_Q = 128
 TILE_K = 1024
+#: keys a step of the windowed kernel covers: a query tile of 128 under
+#: a window of 512 visits 5 such tiles, 640 keys a position
+TILE_W = 128
 #: positions a step of the selection kernel scores
 TILE_S = 64
 #: positions a step of the plain forms works
@@ -199,9 +212,10 @@ def _selection_plain(q, k, sz):
 # -- attention: the plain form ------------------------------------------------
 
 
-def _attend_plain(q, k, v, kept, sz):
+def _attend_plain(q, k, v, kept, sz, window: int = 0):
     """q (B, S, G, R, d) scaled, k and v (B, S, G, d), ``kept`` (B, G,
-    S, nb) or None -> (B, S, G, R, d) float32."""
+    S, nb) or None -> (B, S, G, R, d) float32. ``window`` > 0: a
+    position sees its own and the ``window - 1`` keys before it."""
     B, S, G, R, d = q.shape
     qt, pos, padded = _query_tiles(q)
     n, tile = pos.shape
@@ -216,6 +230,8 @@ def _attend_plain(q, k, v, kept, sz):
         s = jnp.einsum("btgrd,bsgd->bgrts", qi, k.astype(qi.dtype),
                        preferred_element_type=jnp.float32)
         ok = keys[None, :] <= pi[:, None]                       # (tile, S)
+        if window:
+            ok = ok & (keys[None, :] > pi[:, None] - window)
         if ki:
             ok = ok & jnp.repeat(ki[0], sz.block_size, axis=-1)[..., :S] \
                 [:, :, None]
@@ -482,6 +498,105 @@ def attention(q, k, v, kept, visits, *, groups: int, block: int,
     )(bits, *operands)
 
 
+def window_tiles(qi, tq: int, tk: int, window: int):
+    """(first, last) key tile a query tile ``qi`` of ``tq`` positions
+    reaches under a window of ``window`` keys (its own and the ``window
+    - 1`` before it): the tiles the windowed kernel visits. Works on
+    Python ints and on traced scalars alike."""
+    lo = qi * tq - (window - 1)
+    first = (lo + abs(lo)) // 2 // tk                   # max(lo, 0) // tk
+    return first, (qi * tq + tq - 1) // tk
+
+
+def _window_kernel(q_ref, k_ref, v_ref, o_ref, q_sc, m_sc, l_sc, acc_sc, *,
+                   tq, tk, R, d, window):
+    """Grid (batch, group, query tiles). q (tq, R x d) scaled; k, v (S,
+    d) whole. Visits only the key tiles the query tile's window reaches
+    (:func:`window_tiles`) and masks ``t - window < i <= t`` inside
+    them."""
+    qi = pl.program_id(2)
+    _stack_heads(q_ref, q_sc, tq, R, d)
+    m_sc[...] = jnp.full_like(m_sc, _NEG)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    q_pos = qi * tq + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+
+    def visit(kt, carry):
+        start = pl.multiple_of(kt * tk, tk)
+        keys = k_ref[pl.ds(start, tk), :]
+        s = lax.dot_general(q_sc[...], keys, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        back = q_pos - (kt * tk + col)
+        ok = jnp.logical_and(back >= 0, back < window)
+        s = jnp.where(ok[None], s.reshape(R, tq, tk), _NEG) \
+            .reshape(R * tq, tk)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[pl.ds(start, tk), :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+        return carry
+
+    # a row that sees nothing in the first tiles visited holds _NEG as
+    # its maximum there, and what those tiles added is scaled by
+    # exp(_NEG - score) = 0 at the first tile it sees a key in (its own
+    # position is in the last one)
+    first, last = window_tiles(qi, tq, tk, window)
+    lax.fori_loop(first, last + 1, visit, 0)
+    for j in range(R):
+        rows = slice(j * tq, (j + 1) * tq)
+        o_ref[:, j * d:(j + 1) * d] = (acc_sc[rows, :] / l_sc[rows, :]) \
+            .astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "groups", "window", "interpret", "tile_q", "tile_k"))
+def window_attention(q, k, v, *, groups: int, window: int,
+                     interpret: bool = False, tile_q: int = TILE_Q,
+                     tile_k: int = TILE_W):
+    """Causal attention of each position over its own and the ``window
+    - 1`` keys before it: q (B, S, H x d) scaled, k and v (B, S, G x d)
+    -> (B, S, H x d) in ``v.dtype``. One group's K and V whole in VMEM,
+    as in :func:`attention`; the loop over key tiles starts at the first
+    tile the query tile's window reaches."""
+    B, S, hd = q.shape
+    G = groups
+    d = k.shape[-1] // G
+    R = hd // (G * d)
+    tq, tk = min(tile_q, S), min(tile_k, S)
+
+    def q_map(b, g, i):
+        return b, i, g
+
+    def kv_map(b, g, i):
+        return b, 0, g
+
+    return pl.pallas_call(
+        functools.partial(_window_kernel, tq=tq, tk=tk, R=R, d=d,
+                          window=window),
+        grid=(B, G, S // tq),
+        in_specs=[pl.BlockSpec((None, tq, R * d), q_map),
+                  pl.BlockSpec((None, S, d), kv_map),
+                  pl.BlockSpec((None, S, d), kv_map)],
+        out_specs=pl.BlockSpec((None, tq, R * d), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, S, hd), v.dtype),
+        scratch_shapes=[pltpu.VMEM((R * tq, d), q.dtype),
+                        pltpu.VMEM((R * tq, 1), jnp.float32),
+                        pltpu.VMEM((R * tq, 1), jnp.float32),
+                        pltpu.VMEM((R * tq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="window_attention",
+        interpret=interpret,
+    )(q, k, v)
+
+
 # -- the layer's core ---------------------------------------------------------
 
 
@@ -576,3 +691,61 @@ def attend(q, k, v, sz, *, groups: int, valid=None, inference: bool = False):
         if kept is not None:
             counts = visit_counts(kept, scored, valid)
     return out, counts, kept
+
+
+# -- the windowed layer's core ------------------------------------------------
+
+
+def uses_window_kernel(seq_len: int, inference: bool, d: int = 128,
+                       heads_per_group: int = 9) -> bool:
+    """Whether :func:`attend_window` runs the kernel ``window_attention``:
+    a serving program on a compiled TPU backend, whole tiles, lane-wide
+    heads and one group's K and V inside the VMEM envelope."""
+    tq, tk = min(TILE_Q, seq_len), min(TILE_W, seq_len)
+    return (inference and pallas_attention._mode() == "compiled"
+            and seq_len % tq == 0 and seq_len % tk == 0
+            and d % _LANES == 0 and tq % 16 == 0 and heads_per_group >= 1
+            and 8 * seq_len * d <= _MAX_RESIDENT_BYTES)
+
+
+def window_scored(seq_len: int, window: int, kernel: bool) -> np.ndarray:
+    """(seq_len,) int64: the keys scored for each position of a group.
+    The kernel scores whole key tiles, those its query tile's window
+    reaches (:func:`window_tiles`); the plain form the ones its mask lets
+    through, ``min(t + 1, window)``."""
+    t = np.arange(seq_len)
+    if not kernel:
+        return np.minimum(t + 1, window)
+    tq, tk = min(TILE_Q, seq_len), min(TILE_W, seq_len)
+    first, last = window_tiles(t // tq, tq, tk, window)
+    return (last - first + 1) * tk
+
+
+def attend_window(q, k, v, *, groups: int, window: int, valid=None,
+                  inference: bool = False):
+    """One sliding layer's core: q (B, S, H x d) normed and scaled by
+    1/sqrt(d), k (normed) and v (B, S, G x d); position t sees keys
+    ``t - window < i <= t``. Returns (out (B, S, H x d) in ``v.dtype``,
+    counts int32 (2,): rows (real position x group) and the keys scored
+    for them, read off the tile bounds the kernel is given, or what the
+    plain form's mask lets through). ``valid`` (B, S) says which
+    positions the counts are over (all, when not given)."""
+    B, S, hd = q.shape
+    G = groups
+    d = k.shape[-1] // G
+    R = hd // (G * d)
+    valid = jnp.ones((B, S), bool) if valid is None else valid
+    kernel = uses_window_kernel(S, inference, d, R)
+    with jax.named_scope("window_attention"):
+        if kernel:
+            out = window_attention(q, k, v, groups=G, window=window)
+        else:
+            out = _attend_plain(q.reshape(B, S, G, R, d),
+                                k.reshape(B, S, G, d), v.reshape(B, S, G, d),
+                                None, None, window) \
+                .reshape(B, S, hd).astype(v.dtype)
+        scored = jnp.asarray(window_scored(S, window, kernel), jnp.int32)
+        counts = jnp.stack([jnp.sum(valid, dtype=jnp.int32) * G,
+                            jnp.sum(jnp.where(valid, scored[None], 0),
+                                    dtype=jnp.int32) * G])
+    return out, counts

@@ -89,6 +89,13 @@ STALE_BENCHMARK_LINES = {
         "moe_rows_device_ms lists the Nemotron-H cell beside the nine "
         "metrics the cell brought (tests/benchmarks/test_moe_rows_metric.py"
         "::test_what_the_stale_line_shadows asserts the rest)"),
+    "test_moe_rows_metric.py::test_the_manifest_lists_both_routed_cells": (
+        'assert entry == {"name": METRIC, "unit": "ms", "better": "lower", '
+        '"source": "device_trace", "layer": "kernels", "moves": '
+        '"query_p50_ms", "workloads": [nemotron.CELL, deepseek.CELL]}',
+        "moe_rows_device_ms lists the Laguna cell after the two MoE cells "
+        "(tests/benchmarks/test_laguna_kind.py::test_what_the_stale_line_"
+        "shadows asserts the rest)"),
 }
 
 

@@ -523,6 +523,14 @@ def test_mosaic_builds_both_ends_of_the_envelope(one_chip, monkeypatch):
             q, k, sz=sz, groups=G), (q, k)), (stage_2, (q, k, k, kept))):
         assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile() \
             .as_text()
+    # the Laguna cell's sliding layers: 72 query heads over 8 key/value
+    # heads of 128, 16,384 events, a window of 512
+    Sw, Gw, Rw = 16384, 8, 9
+    text =jax.jit(lambda q, k, v: sa.window_attention(
+        q, k, v, groups=Gw, window=512)).lower(
+            sds((1, Sw, Gw * Rw * D), bf16), sds((1, Sw, Gw * D), bf16),
+            sds((1, Sw, Gw * D), bf16)).compile().as_text()
+    assert "tpu_custom_call" in text and "window_attention" in text
     # the ALS top-k programs at the Books cells' shape (ops/topk.py), in
     # this test for the same reason: from the model's bfloat16 serving
     # copy no program holds a float32 array of the table's shape (a
