@@ -413,9 +413,9 @@ def window_kernel_ok() -> bool:
     """The ``laguna`` kind's windowed kernel at its cell's shape (S
     16,384, 72 query heads over 8 key/value heads of 128, a window of
     512) against its plain form at every position: the windows that
-    start at position 0, mid-tile and on tile bounds; and the keys it
-    scored, read off its tile bounds (640 a position and group past the
-    first window)."""
+    start at position 0, mid-tile and on tile bounds; the keys it
+    scored, read off its band (640 a position and group, everywhere);
+    and the kernel's own time, warm, by the host clock."""
     import jax
     import jax.numpy as jnp
 
@@ -442,11 +442,20 @@ def window_kernel_ok() -> bool:
                                 - plain.reshape(1, s, -1))))
     scored = int(sa.window_scored(s, window, True).sum()) * G
     good = bool(jnp.all(jnp.isfinite(out.astype(jnp.float32)))) and \
-        err <= FLASH_TOL * 4 and counts.tolist() == [s * G, scored]
+        err <= FLASH_TOL * 4 and counts.tolist() == [s * G, scored] \
+        and scored == 640 * s * G
+    kernel = functools.partial(sa.window_attention, groups=G, window=window)
+    jax.block_until_ready(kernel(q, k, v))
+    t2 = time.perf_counter()
+    for _ in range(10):
+        out = kernel(q, k, v)
+    jax.block_until_ready(out)
+    kernel_ms = (time.perf_counter() - t2) * 100
     print(f"window attention S={s} W={window}: max|diff|={err:.3e} "
           f"rows, keys scored {counts.tolist()} "
           f"({counts[1] / counts[0]:.1f} a position) first_call_s="
-          f"{t1 - t0:.2f} {'ok' if good else 'MISMATCH'}")
+          f"{t1 - t0:.2f} kernel_ms={kernel_ms:.3f} "
+          f"{'ok' if good else 'MISMATCH'}")
     return good
 
 

@@ -54,11 +54,13 @@ option, flag or environment variable):
 
 A sliding-window layer (Laguna's, ``models/laguna.py``) has its own
 core, :func:`attend_window`: position t sees keys ``t - W < i <= t``.
-Its kernel ``window_attention`` is ``sparse_block_attention``'s tiling
-with no visit map: the loop over key tiles of ``TILE_W`` keys starts at
-the first tile the query tile's window reaches and ends at its diagonal
-(:func:`window_tiles`), and the window is a mask inside those tiles; a
-group's K and V are resident as there. The rule is
+Its kernel ``window_attention`` holds a group's K and V resident as
+``sparse_block_attention`` does, and scores a query tile's whole band in
+one pass: the band is the most tiles of ``TILE_W`` keys any query tile's
+window reaches (:func:`window_band`: 640 keys for a query tile of 128
+under a window of 512), one product gives its scores, the window is a
+mask over the band, and one exact softmax and one product with the
+band's values follow; no running maximum, no loop. The rule is
 :func:`uses_window_kernel`; the plain form is ``_attend_plain`` with the
 window as a mask.
 """
@@ -82,8 +84,9 @@ _NEG = -1e30
 #: and keys a visit covers; PERF.md section 6 (PR 34) has the sweep
 TILE_Q = 128
 TILE_K = 1024
-#: keys a step of the windowed kernel covers: a query tile of 128 under
-#: a window of 512 visits 5 such tiles, 640 keys a position
+#: the windowed kernel's band is whole tiles of this many keys: a query
+#: tile of 128 under a window of 512 reaches 5 of them, 640 keys a
+#: position
 TILE_W = 128
 #: positions a step of the selection kernel scores
 TILE_S = 64
@@ -501,57 +504,54 @@ def attention(q, k, v, kept, visits, *, groups: int, block: int,
 def window_tiles(qi, tq: int, tk: int, window: int):
     """(first, last) key tile a query tile ``qi`` of ``tq`` positions
     reaches under a window of ``window`` keys (its own and the ``window
-    - 1`` before it): the tiles the windowed kernel visits. Works on
-    Python ints and on traced scalars alike."""
+    - 1`` before it). Works on Python ints, numpy arrays and traced
+    scalars alike."""
     lo = qi * tq - (window - 1)
     first = (lo + abs(lo)) // 2 // tk                   # max(lo, 0) // tk
     return first, (qi * tq + tq - 1) // tk
 
 
-def _window_kernel(q_ref, k_ref, v_ref, o_ref, q_sc, m_sc, l_sc, acc_sc, *,
-                   tq, tk, R, d, window):
+def window_band(seq_len: int, tq: int, tk: int, window: int) -> int:
+    """The keys the windowed kernel scores for each query tile, static:
+    the most key tiles any query tile's window reaches
+    (:func:`window_tiles`) times ``tk``. A query tile's band starts at
+    ``min(first x tk, seq_len - band)``: at the first tile its window
+    reaches, pulled back where it would run past the history."""
+    first, last = window_tiles(np.arange(seq_len // tq), tq, tk, window)
+    return int((last - first + 1).max()) * tk
+
+
+def _window_kernel(q_ref, k_ref, v_ref, o_ref, q_sc, *, tq, tk, R, d,
+                   window, band):
     """Grid (batch, group, query tiles). q (tq, R x d) scaled; k, v (S,
-    d) whole. Visits only the key tiles the query tile's window reaches
-    (:func:`window_tiles`) and masks ``t - window < i <= t`` inside
-    them."""
+    d) whole. Scores the query tile's band of ``band`` keys
+    (:func:`window_band`) in one product, masks ``t - window < i <= t``
+    over the whole band, and takes one exact softmax and one product
+    with the band's values: every row sees its own position, so its
+    maximum is a real score. (A mask on the band's first and last tiles
+    alone, where it cuts past the first window, was 11% slower on a
+    v5e: cutting the scores apart and joining them costs more than the
+    mask; PERF.md section 6.)"""
     qi = pl.program_id(2)
     _stack_heads(q_ref, q_sc, tq, R, d)
-    m_sc[...] = jnp.full_like(m_sc, _NEG)
-    l_sc[...] = jnp.zeros_like(l_sc)
-    acc_sc[...] = jnp.zeros_like(acc_sc)
-    q_pos = qi * tq + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-    col = lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-
-    def visit(kt, carry):
-        start = pl.multiple_of(kt * tk, tk)
-        keys = k_ref[pl.ds(start, tk), :]
-        s = lax.dot_general(q_sc[...], keys, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        back = q_pos - (kt * tk + col)
-        ok = jnp.logical_and(back >= 0, back < window)
-        s = jnp.where(ok[None], s.reshape(R, tq, tk), _NEG) \
-            .reshape(R * tq, tk)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[...] = alpha * acc_sc[...] + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[pl.ds(start, tk), :],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
-        return carry
-
-    # a row that sees nothing in the first tiles visited holds _NEG as
-    # its maximum there, and what those tiles added is scaled by
-    # exp(_NEG - score) = 0 at the first tile it sees a key in (its own
-    # position is in the last one)
-    first, last = window_tiles(qi, tq, tk, window)
-    lax.fori_loop(first, last + 1, visit, 0)
+    first, _ = window_tiles(qi, tq, tk, window)
+    start = pl.multiple_of(jnp.minimum(first * tk, k_ref.shape[0] - band), tk)
+    s = lax.dot_general(q_sc[...], k_ref[pl.ds(start, band), :],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    back = qi * tq + lax.broadcasted_iota(jnp.int32, (tq, band), 0) \
+        - (start + lax.broadcasted_iota(jnp.int32, (tq, band), 1))
+    ok = jnp.logical_and(back >= 0, back < window)
+    s = jnp.where(ok[None], s.reshape(R, tq, band), _NEG) \
+        .reshape(R * tq, band)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    out = lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[pl.ds(start, band), :],
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) \
+        / jnp.sum(p, axis=-1, keepdims=True)
     for j in range(R):
-        rows = slice(j * tq, (j + 1) * tq)
-        o_ref[:, j * d:(j + 1) * d] = (acc_sc[rows, :] / l_sc[rows, :]) \
-            .astype(o_ref.dtype)
+        o_ref[:, j * d:(j + 1) * d] = \
+            out[j * tq:(j + 1) * tq].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -562,13 +562,13 @@ def window_attention(q, k, v, *, groups: int, window: int,
     """Causal attention of each position over its own and the ``window
     - 1`` keys before it: q (B, S, H x d) scaled, k and v (B, S, G x d)
     -> (B, S, H x d) in ``v.dtype``. One group's K and V whole in VMEM,
-    as in :func:`attention`; the loop over key tiles starts at the first
-    tile the query tile's window reaches."""
+    as in :func:`attention`; a query tile reads its band of them."""
     B, S, hd = q.shape
     G = groups
     d = k.shape[-1] // G
     R = hd // (G * d)
     tq, tk = min(tile_q, S), min(tile_k, S)
+    band = window_band(S, tq, tk, window)
 
     def q_map(b, g, i):
         return b, i, g
@@ -578,17 +578,14 @@ def window_attention(q, k, v, *, groups: int, window: int,
 
     return pl.pallas_call(
         functools.partial(_window_kernel, tq=tq, tk=tk, R=R, d=d,
-                          window=window),
+                          window=window, band=band),
         grid=(B, G, S // tq),
         in_specs=[pl.BlockSpec((None, tq, R * d), q_map),
                   pl.BlockSpec((None, S, d), kv_map),
                   pl.BlockSpec((None, S, d), kv_map)],
         out_specs=pl.BlockSpec((None, tq, R * d), q_map),
         out_shape=jax.ShapeDtypeStruct((B, S, hd), v.dtype),
-        scratch_shapes=[pltpu.VMEM((R * tq, d), q.dtype),
-                        pltpu.VMEM((R * tq, 1), jnp.float32),
-                        pltpu.VMEM((R * tq, 1), jnp.float32),
-                        pltpu.VMEM((R * tq, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((R * tq, d), q.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -710,15 +707,13 @@ def uses_window_kernel(seq_len: int, inference: bool, d: int = 128,
 
 def window_scored(seq_len: int, window: int, kernel: bool) -> np.ndarray:
     """(seq_len,) int64: the keys scored for each position of a group.
-    The kernel scores whole key tiles, those its query tile's window
-    reaches (:func:`window_tiles`); the plain form the ones its mask lets
-    through, ``min(t + 1, window)``."""
-    t = np.arange(seq_len)
+    The kernel scores its band (:func:`window_band`) at every position;
+    the plain form the keys its mask lets through, ``min(t + 1,
+    window)``."""
     if not kernel:
-        return np.minimum(t + 1, window)
+        return np.minimum(np.arange(seq_len) + 1, window)
     tq, tk = min(TILE_Q, seq_len), min(TILE_W, seq_len)
-    first, last = window_tiles(t // tq, tq, tk, window)
-    return (last - first + 1) * tk
+    return np.full(seq_len, window_band(seq_len, tq, tk, window))
 
 
 def attend_window(q, k, v, *, groups: int, window: int, valid=None,
@@ -727,8 +722,8 @@ def attend_window(q, k, v, *, groups: int, window: int, valid=None,
     1/sqrt(d), k (normed) and v (B, S, G x d); position t sees keys
     ``t - window < i <= t``. Returns (out (B, S, H x d) in ``v.dtype``,
     counts int32 (2,): rows (real position x group) and the keys scored
-    for them, read off the tile bounds the kernel is given, or what the
-    plain form's mask lets through). ``valid`` (B, S) says which
+    for them, read off the band the kernel is given, or what the plain
+    form's mask lets through). ``valid`` (B, S) says which
     positions the counts are over (all, when not given)."""
     B, S, hd = q.shape
     G = groups

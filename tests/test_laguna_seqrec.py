@@ -152,15 +152,29 @@ def test_the_window_mask_is_the_brute_force_one(window):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(causal))
 
 
-@pytest.mark.parametrize("tile_q, tile_k, window", [
-    (128, 128, 48),      # windows start mid-tile, and at 0 for t < 48
-    (64, 32, 48),        # a window over two key tiles and a part
-    (32, 128, 200),      # a key tile wider than a query tile
-    (128, 64, 256),      # the window reaches position 0 from every row
-    (128, 128, 1)])      # each position alone
+@pytest.mark.parametrize("tile_q, tile_k, window, n", [
+    # windows start mid-tile, and at 0 for t < 48
+    pytest.param(128, 128, 48, 256, id="128-128-48"),
+    # a window over two key tiles and a part
+    pytest.param(64, 32, 48, 256, id="64-32-48"),
+    # a key tile wider than a query tile
+    pytest.param(32, 128, 200, 256, id="32-128-200"),
+    # the window reaches position 0 from every row
+    pytest.param(128, 64, 256, 256, id="128-64-256"),
+    # each position alone
+    pytest.param(128, 128, 1, 256, id="128-128-1"),
+    # the history is one band: every query tile scores all of it
+    pytest.param(128, 128, 200, 256, id="one-band"),
+    # bands of three tiles where some tiles' windows reach two: the last
+    # query tiles' bands are pulled back from past the history's end
+    pytest.param(32, 128, 200, 512, id="band-pulled-back"),
+    # the cell's tiles: a window of four whole tiles, a band of five
+    pytest.param(128, 128, 512, 1024, id="window-of-whole-tiles"),
+    # a query tile wider than the window
+    pytest.param(128, 32, 48, 512, id="query-tile-wider-than-window")])
 def test_the_windowed_kernel_interpreted_is_its_plain_form(
-        tile_q, tile_k, window):
-    q, k, v = _qkv(tile_q + tile_k + window)
+        tile_q, tile_k, window, n):
+    q, k, v = _qkv(tile_q + tile_k + window, n=n)
     B, n, G, R, d = q.shape
     got = sa.window_attention(q.reshape(B, n, -1), k.reshape(B, n, -1),
                               v.reshape(B, n, -1), groups=G, window=window,
@@ -176,28 +190,41 @@ def test_the_windowed_kernel_interpreted_is_its_plain_form(
 
 
 def test_the_tiles_a_window_reaches_and_the_keys_counted():
-    """``window_tiles`` against a search over every key tile; the keys
-    counted a position (the kernel's whole tiles, the plain form's
-    mask) against a count of the pairs; at the cell's shape 640 keys a
-    position past the first window, where every visible key would be
+    """``window_tiles`` against a search over every key tile; the band
+    (``window_band``) against the same search: as wide as the most tiles
+    a query tile reaches, and holding every key each one reaches inside
+    the history; the keys counted a position (the kernel's band, the
+    plain form's mask) against a count of the pairs; at the cell's shape
+    640 keys at every position, where every visible key would be
     ~8,200."""
     for tq, tk, window, n in ((128, 128, 512, 2048), (128, 128, 48, 512),
-                              (64, 32, 48, 512), (32, 128, 200, 512)):
+                              (64, 32, 48, 512), (32, 128, 200, 512),
+                              (128, 128, 256, 1024), (256, 128, 512, 2048),
+                              (128, 32, 48, 512), (128, 128, 200, 256)):
+        band = sa.window_band(n, tq, tk, window)
+        assert band % tk == 0 and band <= n
+        widths = []
         for qi in range(n // tq):
             rows = np.arange(qi * tq, qi * tq + tq)
-            reached = [kt for kt in range(n // tk) if np.any(
-                (rows[:, None] - (kt * tk + np.arange(tk))[None, :] >= 0)
-                & (rows[:, None] - (kt * tk + np.arange(tk))[None, :]
-                   < window))]
+            back = rows[:, None] - np.arange(n)[None, :]
+            seen = (back >= 0) & (back < window)                  # (tq, n)
+            reached = [kt for kt in range(n // tk)
+                       if seen[:, kt * tk:(kt + 1) * tk].any()]
             first, last = sa.window_tiles(qi, tq, tk, window)
             assert list(range(first, last + 1)) == reached
+            widths.append(len(reached) * tk)
+            start = min(first * tk, n - band)
+            assert start % tk == 0 and start >= 0
+            assert not seen[:, :start].any() and \
+                not seen[:, start + band:].any()
+        assert band == max(widths)
+    assert sa.window_band(16384, 128, 128, 512) == 640
     plain = sa.window_scored(96, WINDOW, kernel=False)
     t, i = np.arange(96)[:, None], np.arange(96)[None, :]
     np.testing.assert_array_equal(
         plain, ((t - i >= 0) & (t - i < WINDOW)).sum(axis=1))
     cell = sa.window_scored(16384, 512, kernel=True)
-    assert set(cell[512:].tolist()) == {640} and cell[:128].tolist() == \
-        [128] * 128
+    assert cell.tolist() == [640] * 16384
     assert cell.sum() / 16384 < 1024 < 8192
 
 
