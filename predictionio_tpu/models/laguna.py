@@ -284,7 +284,8 @@ def kernels(cfg, seq_len: int) -> tuple:
     if any(qk_norm.fuses(cfg.hd, H * cfg.hd, seq_len, inference=True)
            for H in set(w.num_attention_heads_per_layer)):
         names += ("qk_norm_rope",)
-    return names + (seqrec._routed_kernels(cfg) if w.count("sparse") else ())
+    return names + (seqrec._routed_kernels(cfg, w.moe_intermediate_size)
+                    if w.count("sparse") else ())
 
 
 def tally(cfg, tokens: int, per_expert, window) -> dict:

@@ -1215,7 +1215,7 @@ def _kernels_deepseek_v2(cfg: SeqRecConfig, seq_len: int) -> tuple:
     w = cfg.mla_moe
     return (("mla_flash_attention",) if mla_attention.uses_kernel(
         seq_len, True, w.qk_nope_head_dim, w.qk_rope_head_dim, w.v_head_dim,
-        cfg.n_heads) else ()) + _routed_kernels(cfg)
+        cfg.n_heads) else ()) + _routed_kernels(cfg, w.moe_intermediate_size)
 
 
 def _kernels_nemotron_h(cfg: SeqRecConfig, seq_len: int) -> tuple:
@@ -1227,18 +1227,23 @@ def _kernels_nemotron_h(cfg: SeqRecConfig, seq_len: int) -> tuple:
             and sparse_attention.uses_kernel(
                 seq_len, True, dense, cfg.hd, cfg.n_heads // cfg.kv_heads)
             else ()) \
-        + (_routed_kernels(cfg) if w.count("E") else ())
+        + (_routed_kernels(cfg, w.moe_intermediate_size) if w.count("E")
+           else ())
 
 
-def _routed_kernels(cfg: SeqRecConfig) -> tuple:
-    """A serving program's routed layers' kernels (``ops/moe``'s rules):
-    ``gmm``, and ``moe_rows`` where the rows are whole lanes."""
+def _routed_kernels(cfg: SeqRecConfig, ffn: int) -> tuple:
+    """A serving program's routed layers' kernels (``ops/moe``'s rules)
+    over experts ``ffn`` wide: ``gmm``; "gmm_transpose_rhs" where it
+    reads an expert weight in the order the TPU holds it, as its
+    transpose; ``moe_rows`` where the rows are whole lanes."""
     from predictionio_tpu.ops import moe
 
     kernel = "compiled" if moe.uses_kernel(True) else None
+    D = cfg.d_model
     return (("gmm",) if kernel else ()) \
-        + (("moe_rows",) if moe.moves_rows_by_dma(kernel, cfg.d_model)
-           else ())
+        + (("gmm_transpose_rhs",) if moe.reads_transposed(kernel, D, ffn)
+           or moe.reads_transposed(kernel, ffn, D) else ()) \
+        + (("moe_rows",) if moe.moves_rows_by_dma(kernel, D) else ())
 
 
 def fuses_retention(cfg: SeqRecConfig, seq_len: int) -> bool:
@@ -1265,6 +1270,14 @@ def moves_moe_rows(cfg: SeqRecConfig, seq_len: int) -> bool:
     return "moe_rows" in BLOCKS[cfg.block].kernels(cfg, seq_len)
 
 
+def reads_experts_as_held(cfg: SeqRecConfig, seq_len: int) -> bool:
+    """Whether such a program's grouped products read an expert weight in
+    the order the TPU holds it, as its transpose, where read as given it
+    would be copied whole first (``ops/moe.reads_transposed``): the
+    kind's own rule, for the same counters."""
+    return "gmm_transpose_rhs" in BLOCKS[cfg.block].kernels(cfg, seq_len)
+
+
 def runs_window_attention(cfg: SeqRecConfig, seq_len: int) -> bool:
     """Whether such a program's sliding-window layers run the kernel
     ``window_attention`` (``ops/sparse_attention.uses_window_kernel``):
@@ -1283,7 +1296,8 @@ class BlockKind:
     bytes_per_token: Any
     #: (cfg, seq_len) -> names of the Pallas kernels a serving program
     #: over histories that long engages, by the rules the forward pass
-    #: itself applies (static shape and backend)
+    #: itself applies (static shape and backend); "gmm_transpose_rhs"
+    #: beside "gmm" where it reads an expert weight transposed
     kernels: Any = lambda cfg, seq_len: ()
     #: None, or (cfg, tokens of the program, *those arrays on the host)
     #: -> {field of templates/sessionrec.SeqDispatch: what to add}: the

@@ -30,8 +30,12 @@ only here) and the rows move into expert order and back by the kernel
 ``moe_rows`` (``ops/pallas_moe_rows.py``): a DMA a held row, none for a
 slot of an absent expert, the sum back in token order the plain form's
 bit for bit (rows of whole 128-lane tiles; at other widths the plain
-gathers around ``gmm``). Elsewhere ``jax.lax.ragged_dot`` and plain gathers, which
-are differentiable. Neither form scatters (:func:`sort_by_expert`).
+gathers around ``gmm``). An expert weight ``(held, K, N)`` whose ``N`` is not
+a whole number of 128 lanes while ``K`` is, Nemotron-H's ``w_up`` (2,688 by
+1,856), the TPU holds with ``K`` minor: ``gmm`` reads it in that order, as
+its transpose (:func:`reads_transposed`), and the program holds no copy of
+it. Elsewhere ``jax.lax.ragged_dot`` and plain gathers, which are
+differentiable. Neither form scatters (:func:`sort_by_expert`).
 """
 
 from __future__ import annotations
@@ -110,6 +114,20 @@ def moves_rows_by_dma(kernel: str | None, width: int) -> bool:
     return bool(kernel) and width % pallas_moe_rows.LANES == 0
 
 
+def reads_transposed(kernel: str | None, k: int, n: int) -> bool:
+    """Whether ``gmm`` reads an expert weight ``(held, k, n)`` as its
+    transpose ``(held, n, k)`` (``transpose_rhs``): wherever it runs, at
+    an ``n`` that is not a whole number of 128 lanes and a ``k`` that is.
+    The TPU holds such an array with its last two dimensions swapped
+    (``{1,2,0}``: ``k`` minor, no lane padding), and ``gmm`` reads its
+    right operand row-major, so read as it is given the program first
+    copies the whole weight into that order (Nemotron-H's ``w_up``,
+    64 x 2,688 x 1,856: 2.02 ms a layer on a v5e). Read transposed, the
+    swap is the held layout itself, a bitcast."""
+    lanes = pallas_moe_rows.LANES
+    return bool(kernel) and n % lanes != 0 and k % lanes == 0
+
+
 def _grouped(lhs, rhs, sizes, kernel, out_dtype):
     """rows of group g times rhs[g]; rows past the groups undefined."""
     if kernel is None:
@@ -120,7 +138,9 @@ def _grouped(lhs, rhs, sizes, kernel, out_dtype):
     k, n = rhs.shape[1:]
     tk, tn = (_long_tile(k), n) if k > n else (k, _long_tile(n))
     tiling = (min(GMM_ROWS, lhs.shape[0]), tk, tn)
-    return gmm(lhs, rhs, sizes, out_dtype, tiling,
+    transposed = reads_transposed(kernel, k, n)
+    return gmm(lhs, jnp.swapaxes(rhs, 1, 2) if transposed else rhs, sizes,
+               out_dtype, tiling, transpose_rhs=transposed,
                interpret=kernel == "interpret")
 
 

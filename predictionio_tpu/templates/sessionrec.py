@@ -235,6 +235,10 @@ class SeqDispatch:
     #: of the programs, those whose routed layers moved their rows into
     #: expert order and back with the kernel (seqrec.moves_moe_rows)
     moe_row_dma_programs: int = 0
+    #: of the programs, those whose grouped products read an expert
+    #: weight in the order the TPU holds it, with no copy of it
+    #: (seqrec.reads_experts_as_held)
+    expert_layout_programs: int = 0
     #: of the programs, those whose sliding-window layers ran the kernel
     #: ``window_attention`` (seqrec.runs_window_attention)
     window_attention_programs: int = 0
@@ -404,6 +408,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
         fused = seqrec.fuses_retention(model.cfg, S)
         fused_way_in = seqrec.fuses_qk_norm(model.cfg, S)
         row_dma = seqrec.moves_moe_rows(model.cfg, S)
+        as_held = seqrec.reads_experts_as_held(model.cfg, S)
         windowed = seqrec.runs_window_attention(model.cfg, S)
         kind = seqrec.BLOCKS[model.cfg.block]
         tally = kind.tally
@@ -441,6 +446,7 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                 fused_retention_programs=programs if fused else 0,
                 fused_qk_norm_programs=programs if fused_way_in else 0,
                 moe_row_dma_programs=programs if row_dma else 0,
+                expert_layout_programs=programs if as_held else 0,
                 window_attention_programs=programs if windowed else 0,
                 **counted))
         return out

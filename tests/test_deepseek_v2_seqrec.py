@@ -237,8 +237,9 @@ def test_a_compiled_backend_moves_the_routed_rows_by_kernel(monkeypatch):
     assert seqrec.BLOCKS["deepseek_v2"].kernels(cfg, 8192)[-1:] == ("gmm",)
     assert not seqrec.moves_moe_rows(cfg, 8192)
     wide = dataclasses.replace(cfg, d_model=128)
-    assert seqrec.BLOCKS["deepseek_v2"].kernels(wide, 8192)[-2:] == (
-        "gmm", "moe_rows")
+    # experts 32 wide over rows of 128: the up products read as held
+    assert seqrec.BLOCKS["deepseek_v2"].kernels(wide, 8192)[-3:] == (
+        "gmm", "gmm_transpose_rhs", "moe_rows")
     assert seqrec.moves_moe_rows(wide, 8192)
 
 

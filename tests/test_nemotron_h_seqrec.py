@@ -626,8 +626,9 @@ def test_kernel_rule_is_shape_and_backend(monkeypatch):
     monkeypatch.setattr(pallas_attention, "_mode", lambda: "compiled")
     # no fourth attention kernel: the sparse layers' one, nothing masked
     assert kind.kernels(published, 16384) == (
-        "sparse_block_attention", "gmm", "moe_rows")
-    assert kind.kernels(published, 500) == ("gmm", "moe_rows")
+        "sparse_block_attention", "gmm", "gmm_transpose_rhs", "moe_rows")
+    assert kind.kernels(published, 500) == (
+        "gmm", "gmm_transpose_rhs", "moe_rows")
     # the routed layers' row moves: the kinds that have them, by the rule
     # the forward pass applies (ops/moe.uses_kernel)
     assert seqrec.moves_moe_rows(published, 16384)
