@@ -591,11 +591,17 @@ class EngineService:
                                   target.score_table_bytes_per_entry)
         self.serving_stats.set_score_table_bytes(table_bytes)
         # the session engine's models report programs and tokens the
-        # same way (pio_serving_seq_* on /metrics, seq* on /stats.json)
+        # same way (pio_serving_seq_* on /metrics, seq* on /stats.json),
+        # and the kernels their programs engage, a rule of static shapes
+        seq_kernels = []
         for model in getattr(self.deployed, "models", ()):
             if hasattr(model, "set_dispatch_observer"):
+                from predictionio_tpu.models import seqrec
+
                 model.set_dispatch_observer(
                     self.serving_stats.record_seq_dispatch)
+                seq_kernels += seqrec.kernels(model.cfg, model.cfg.max_len)
+        self.serving_stats.set_seq_kernels(seq_kernels)
 
     def _missing_index_targets(self) -> list:
         """ANN-capable deployed models WITHOUT a ready index — the

@@ -53,14 +53,11 @@ class ServingStats:
         "ann_queries", "ann_rescored",
         # the session engine's dispatches (templates/sessionrec)
         "seq_programs", "seq_tokens", "seq_padded_tokens",
-        "seq_split_dispatches", "seq_fused_retention_programs",
-        "seq_fused_qk_norm_programs", "seq_moe_row_dma_programs",
-        "seq_expert_layout_programs",
+        "seq_split_dispatches",
         "seq_moe_assignments", "seq_moe_tokens", "seq_moe_max_expert_load",
         "seq_sparse_rows", "seq_sparse_blocks_selected",
         "seq_sparse_keys_scored",
-        "seq_ssm_tokens", "seq_ssm_scan_steps",
-        "seq_window_attention_programs", "seq_window_rows",
+        "seq_ssm_tokens", "seq_ssm_scan_steps", "seq_window_rows",
         "seq_window_keys_scored",
     )
 
@@ -77,6 +74,10 @@ class ServingStats:
         #: serving copy; 4: a float32 table); 0 until a server wires a
         #: model that has one, and while it answers through ANN
         self._score_table_bytes = 0
+        #: the Pallas kernels the session engine's serving programs
+        #: engage (models/seqrec.kernels); empty until a server wires a
+        #: session model
+        self._seq_kernels: tuple[str, ...] = ()
         #: latency attribution (obs/histogram.py; each histogram owns
         #: its own lock): queue component vs device component of the
         #: batched serving path — the Clipper-style split GET /metrics
@@ -133,24 +134,27 @@ class ServingStats:
         with self._lock:
             return self._score_table_bytes
 
+    def set_seq_kernels(self, names) -> None:
+        """The Pallas kernels the session models' serving programs
+        engage (``models/seqrec.kernels`` at ``max_len``): a gauge the
+        engine server sets when it wires its models."""
+        with self._lock:
+            self._seq_kernels = tuple(sorted(set(names)))
+
+    def seq_kernels(self) -> tuple[str, ...]:
+        with self._lock:
+            return self._seq_kernels
+
     def record_seq_dispatch(self, report) -> None:
         """One ``batch_predict`` of the session engine, as the record
         ``templates/sessionrec.SeqDispatch`` (the SeqRecEngineModel
         observer hook): each of its fields adds to the counter
         ``seq_<field>`` (``split`` to ``seq_split_dispatches``)."""
         with self._lock:
-            for field in ("programs", "tokens", "padded_tokens",
-                          "fused_retention_programs",
-                          "fused_qk_norm_programs", "moe_row_dma_programs",
-                          "expert_layout_programs",
-                          "moe_assignments",
-                          "moe_tokens", "moe_max_expert_load", "sparse_rows",
-                          "sparse_blocks_selected", "sparse_keys_scored",
-                          "ssm_tokens", "ssm_scan_steps",
-                          "window_attention_programs", "window_rows",
-                          "window_keys_scored"):
-                self._counts["seq_" + field] += getattr(report, field)
-            self._counts["seq_split_dispatches"] += report.split
+            for field in dataclasses.fields(report):
+                name = "split_dispatches" if field.name == "split" \
+                    else field.name
+                self._counts["seq_" + name] += getattr(report, field.name)
 
     def ann_histogram(self) -> dict[int, int]:
         """Shortlist width -> query count, read under the lock."""
@@ -179,11 +183,13 @@ class ServingStats:
             ann_hist = {str(k): v
                         for k, v in sorted(self._ann_hist.items())}
             table_bytes = self._score_table_bytes
+            seq_kernels = list(self._seq_kernels)
         hits, misses = counts["cache_hits"], counts["cache_misses"]
         looked = hits + misses
         return {
             **{snake_to_camel(k): v for k, v in counts.items()},
             "scoreTableBytesPerEntry": table_bytes,
+            "seqKernels": seq_kernels,
             "batchSizeHistogram": hist,
             "annShortlistHistogram": ann_hist,
             "cacheHitRatio": round(hits / looked, 4) if looked else None,

@@ -1,6 +1,6 @@
-"""The "laguna" block kind of the session engine (``models/seqrec``'s
-``BLOCKS``): the Laguna block as Laguna-S-2.1 publishes it, whose plain
-float32 reference is benchmarks/reference/laguna_jnp.py.
+"""The "laguna" block kind of the session engine: the Laguna block as
+Laguna-S-2.1 publishes it (https://huggingface.co/poolside/Laguna-S-2.1),
+whose plain float32 reference is benchmarks/reference/laguna_jnp.py.
 
 A layer of type full or sliding, with its own count H of query heads
 over G key/value heads of d, then a dense or an expert feed-forward::
@@ -32,13 +32,62 @@ right padding needs no mask.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.models.blocks.common import (
+    PAD, BlockKind, YarnScaling, dense, ffn, rms, routed, routed_kernels,
+    stack, stack_routed, swiglu, tally_routed)
 from predictionio_tpu.ops import qk_norm
+
+#: Laguna-S-2.1's published layers: full attention every fourth, from
+#: the first; the first a dense feed-forward, the others routed experts
+_LAGUNA_TYPES = tuple("full_attention" if i % 4 == 0 else "sliding_attention"
+                      for i in range(48))
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaWidths:
+    """What the "laguna" kind adds to the widths every kind has
+    (``d_model``, ``n_kv_heads`` of ``head_dim``, ``d_ff`` for the dense
+    layers, ``rms_eps``): keys as published, defaults Laguna-S-2.1's.
+    The query heads are a layer's own (``num_attention_heads_per_layer``);
+    each layer type has its own rotary embedding, flat here where the
+    family nests them under ``rope_parameters`` (``engine.json``:
+    ``"laguna": {...}``)."""
+    #: of each layer held here, in order: "full_attention" or
+    #: "sliding_attention"; its query heads; "dense" or "sparse"
+    layer_types: tuple = _LAGUNA_TYPES
+    num_attention_heads_per_layer: tuple = tuple(
+        48 if t == "full_attention" else 72 for t in _LAGUNA_TYPES)
+    mlp_layer_types: tuple = ("dense",) + ("sparse",) * 47
+    sliding_window: int = 512
+    #: full attention: YaRN over the first ``full_partial_rotary_factor``
+    #: of a head's lanes, cos and sin times ``full_attention_factor``
+    full_rope_theta: float = 500000.0
+    full_rope_scaling: YarnScaling = YarnScaling(
+        factor=128.0, original_max_position_embeddings=8192,
+        beta_fast=32.0, beta_slow=1.0)
+    full_attention_factor: float = 1.4852030263919618
+    full_partial_rotary_factor: float = 0.5
+    #: sliding attention: plain RoPE over all of a head's lanes
+    sliding_rope_theta: float = 10000.0
+    sliding_partial_rotary_factor: float = 1.0
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    #: the router's width: the experts of the published layer
+    num_experts: int = 256
+    #: (first, count): the experts this chip holds of them
+    experts_held: tuple = (0, 256)
+    num_experts_per_tok: int = 10
+    moe_routed_scaling_factor: float = 2.5
+
+    def count(self, layer_type: str) -> int:
+        return (self.layer_types + self.mlp_layer_types).count(layer_type)
 
 
 def _layers(cfg):
@@ -66,42 +115,27 @@ def init(key, cfg, dtype):
     w = cfg.laguna
     d, G, hd = cfg.d_model, cfg.kv_heads, cfg.hd
     held = w.experts_held[1]
-    keys = jax.random.split(key, 2 + cfg.n_layers)
-
-    def dense(k, *shape):
-        return (jax.random.normal(k, shape, dtype=jnp.float32)
-                / math.sqrt(shape[-2])).astype(dtype)
-
-    def swiglu(ks, ff, *lead):
-        return {"w_gate": dense(ks[0], *lead, d, ff),
-                "w_up": dense(ks[1], *lead, d, ff),
-                "w_down": dense(ks[2], *lead, ff, d)}
-
-    def table(k):
-        return (jax.random.normal(k, (cfg.vocab, d), dtype=jnp.float32)
-                / math.sqrt(d)).astype(dtype)
-
-    params = {"item_emb": table(keys[0]), "head": table(keys[1]),
-              "out_norm": jnp.ones((d,), dtype), "layers": []}
-    for i, (_, H, mlp) in enumerate(_layers(cfg)):
-        lk = jax.random.split(keys[2 + i], 12)
+    layers = _layers(cfg)
+    params, layer_keys = stack(key, cfg, dtype, 12)
+    for lk, (_, H, mlp) in zip(layer_keys, layers):
         layer = {
             "in_norm": jnp.ones((d,), dtype),
             "post_norm": jnp.ones((d,), dtype),
             "q_norm": jnp.ones((hd,), dtype),
             "k_norm": jnp.ones((hd,), dtype),
-            "wq": dense(lk[0], d, H * hd),
-            "wk": dense(lk[1], d, G * hd),
-            "wv": dense(lk[2], d, G * hd),
-            "wg": dense(lk[3], d, H),
-            "wo": dense(lk[4], H * hd, d),
+            "wq": dense(lk[0], dtype, d, H * hd),
+            "wk": dense(lk[1], dtype, d, G * hd),
+            "wv": dense(lk[2], dtype, d, G * hd),
+            "wg": dense(lk[3], dtype, d, H),
+            "wo": dense(lk[4], dtype, H * hd, d),
         }
         if mlp == "dense":
-            layer["ffn"] = swiglu(lk[5:8], cfg.ff)
+            layer["ffn"] = swiglu(lk[5:8], dtype, d, cfg.ff)
         else:
-            layer["router"] = dense(lk[5], d, w.num_experts)
-            layer["experts"] = swiglu(lk[6:9], w.moe_intermediate_size, held)
-            layer["shared"] = swiglu(lk[9:12],
+            layer["router"] = dense(lk[5], dtype, d, w.num_experts)
+            layer["experts"] = swiglu(lk[6:9], dtype, d,
+                                      w.moe_intermediate_size, held)
+            layer["shared"] = swiglu(lk[9:12], dtype, d,
                                      w.shared_expert_intermediate_size)
         params["layers"].append(layer)
     return params
@@ -140,16 +174,10 @@ def forward(params, seqs, cfg, mesh, seq_axis, inference):
     scored, the experts chosen at every position (expert layers, B, S,
     top_k): all int32). The expert layers compute this chip's experts'
     part and leave the rest out, as the "deepseek_v2" kind's do."""
-    from predictionio_tpu.models.seqrec import PAD, _rms, _swiglu
-    # Pallas and the grouped matmul are imported with the first program
-    # of this kind, not with the module
-    from predictionio_tpu.ops import moe, sparse_attention
+    # Pallas is imported with the first program of this kind, not with
+    # the module
+    from predictionio_tpu.ops import sparse_attention
 
-    if mesh is not None and seq_axis in mesh.shape and \
-            int(mesh.shape[seq_axis]) > 1:
-        raise NotImplementedError(
-            "the laguna block has no sequence-parallel form: use a mesh "
-            f"without a {seq_axis!r} axis")
     w = cfg.laguna
     B, S = seqs.shape
     G, hd, dt, eps, f32 = cfg.kv_heads, cfg.hd, cfg.dtype, cfg.rms_eps, \
@@ -190,52 +218,35 @@ def forward(params, seqs, cfg, mesh, seq_axis, inference):
                 .astype(dt).reshape(B, S, H * hd)
         return o @ layer["wo"].astype(dt), counts
 
-    def experts(h, layer):
-        tokens = h.reshape(B * S, -1)
-        with jax.named_scope("moe.route"):
-            ids, weights, _ = moe.route(
-                tokens, layer["router"], n_group=1, topk_group=1,
-                top_k=w.num_experts_per_tok,
-                scaling=w.moe_routed_scaling_factor, renormalise=True)
-        with jax.named_scope("moe.experts"):
-            ex = layer["experts"]
-            routed, counts = moe.routed_experts(
-                tokens, ids, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
-                w.experts_held[0], inference=inference)
-        with jax.named_scope("moe.shared"):
-            shared = _swiglu(h, layer["shared"], dt)
-        y = routed.reshape(B, S, -1) + shared.astype(f32)
-        return y.astype(dt), (counts, ids.reshape(B, S, -1))
-
     def block(x, layer, layer_type, H, mlp):
         with jax.named_scope(layer_type):
-            y, window = attention(_rms(x, layer["in_norm"], eps), layer,
+            y, window = attention(rms(x, layer["in_norm"], eps), layer,
                                   layer_type, H)
         x = x + y
-        h = _rms(x, layer["post_norm"], eps)
+        h = rms(x, layer["post_norm"], eps)
         if mlp == "dense":
             with jax.named_scope("swiglu"):
-                return x + _swiglu(h, layer["ffn"], dt), window, None
-        y, routed = experts(h, layer)
-        return x + y, window, routed
+                return x + ffn(h, layer["ffn"], dt), window, None
+        y, got = routed(
+            h, layer, w.experts_held[0], inference=inference, n_group=1,
+            topk_group=1, top_k=w.num_experts_per_tok,
+            scaling=w.moe_routed_scaling_factor, renormalise=True)
+        return x + y, window, got
 
     if cfg.remat:
         block = jax.checkpoint(block, static_argnums=(2, 3, 4))
     x = params["item_emb"][seqs].astype(dt)
     window = jnp.zeros((2,), jnp.int32)
-    routed = []
+    per_layer = []
     for layer, (layer_type, H, mlp) in zip(params["layers"], _layers(cfg)):
         x, counts, got = block(x, layer, layer_type, H, mlp)
         if counts is not None:
             window = window + counts
         if got is not None:
-            routed.append(got)
-    if routed:
-        assignments, choices = (jnp.stack(a) for a in zip(*routed))
-    else:
-        assignments = jnp.zeros((0, w.experts_held[1]), jnp.int32)
-        choices = jnp.zeros((0, B, S, w.num_experts_per_tok), jnp.int32)
-    return _rms(x, params["out_norm"], eps), assignments, window, choices
+            per_layer.append(got)
+    assignments, choices = stack_routed(per_layer, w.experts_held[1],
+                                        (B, S, w.num_experts_per_tok))
+    return rms(x, params["out_norm"], eps), assignments, window, choices
 
 
 def bytes_per_token(cfg) -> int:
@@ -263,7 +274,6 @@ def bytes_per_token(cfg) -> int:
 def kernels(cfg, seq_len: int) -> tuple:
     """The Pallas kernels a serving program over ``seq_len`` positions
     engages, by the rules the forward pass applies."""
-    from predictionio_tpu.models import seqrec
     from predictionio_tpu.ops import sparse_attention
 
     w = cfg.laguna
@@ -284,16 +294,20 @@ def kernels(cfg, seq_len: int) -> tuple:
     if any(qk_norm.fuses(cfg.hd, H * cfg.hd, seq_len, inference=True)
            for H in set(w.num_attention_heads_per_layer)):
         names += ("qk_norm_rope",)
-    return names + (seqrec._routed_kernels(cfg, w.moe_intermediate_size)
+    return names + (routed_kernels(cfg, w.moe_intermediate_size)
                     if w.count("sparse") else ())
 
 
 def tally(cfg, tokens: int, per_expert, window) -> dict:
     """The expert layers of one program as the "deepseek_v2" kind reports
     its own, and the sliding layers beside them: rows (real position x
-    sliding layer x key/value head) and the keys scored for them."""
-    from predictionio_tpu.models import seqrec
-
-    return {**seqrec._tally_deepseek_v2(cfg, tokens, per_expert),
+    sliding layer x key/value head) and the keys scored for them, read
+    off the tile bounds the kernel was given (on the plain path, the
+    keys its window lets through)."""
+    return {**tally_routed(cfg, tokens, per_expert),
             "window_rows": int(window[0]),
             "window_keys_scored": int(window[1])}
+
+
+KIND = BlockKind(init, forward, bytes_per_token, kernels, tally=tally,
+                 choices=True)

@@ -183,6 +183,12 @@ def serving_collector(stats: Any) -> Collector:
             samples=[({}, float(stats.score_table_bytes()))],
         ))
         out.append(Metric(
+            name="pio_serving_seq_kernel", kind="gauge",
+            help="1 for each Pallas kernel the session engine's serving "
+                 "programs engage (models/seqrec.kernels)",
+            samples=[({"kernel": name}, 1.0) for name in stats.seq_kernels()],
+        ))
+        out.append(Metric(
             name="pio_serving_batch_size", kind="histogram",
             help="Dispatched (post-dedup) batch sizes",
             histograms=[({}, counts_to_snapshot(stats.batch_histogram()))],

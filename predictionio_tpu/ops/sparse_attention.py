@@ -52,7 +52,7 @@ option, flag or environment variable):
 - the **plain** forms (``jax.numpy``: differentiable, any backend, any
   S): the same equations in query tiles under ``lax.map``.
 
-A sliding-window layer (Laguna's, ``models/laguna.py``) has its own
+A sliding-window layer (Laguna's, ``models/blocks/laguna.py``) has its own
 core, :func:`attend_window`: position t sees keys ``t - W < i <= t``.
 Its kernel ``window_attention`` holds a group's K and V resident as
 ``sparse_block_attention`` does, and scores a query tile's whole band in

@@ -46,6 +46,7 @@ from predictionio_tpu.controller import (
     SanityCheck,
 )
 from predictionio_tpu.models import seqrec
+from predictionio_tpu.models.blocks.common import widths_of
 from predictionio_tpu.obs.trace import span
 from predictionio_tpu.ops.topk import serving_k
 from predictionio_tpu.serving.dispatch_phases import (
@@ -181,26 +182,21 @@ class AlgorithmParams(Params):
     tie_embeddings: bool = True
     # the type serving holds the weights in on the device
     param_dtype: str = "float32"
-    # the "deepseek_v2" backbone's own widths (seqrec.MlaMoeWidths;
-    # engine.json carries a JSON object with the published keys)
+    # a backbone's own widths, a record of its module under
+    # models/blocks/ (engine.json carries a JSON object with the
+    # published keys; None for the other backbones)
     mla_moe: Any = None
-    # the "minicpm_sala" backbone's own widths (seqrec.SalaWidths), the
-    # same way
     sala: Any = None
-    # the "nemotron_h" backbone's own widths (seqrec.NemotronHWidths),
-    # the same way
     nemotron_h: Any = None
-    # the "laguna" backbone's own widths (seqrec.LagunaWidths), the same
-    # way
     laguna: Any = None
 
     def __post_init__(self):
-        object.__setattr__(self, "mla_moe",
-                           seqrec.MlaMoeWidths.of(self.mla_moe))
-        object.__setattr__(self, "sala", seqrec.SalaWidths.of(self.sala))
-        object.__setattr__(self, "nemotron_h",
-                           seqrec.NemotronHWidths.of(self.nemotron_h))
-        object.__setattr__(self, "laguna", seqrec.LagunaWidths.of(self.laguna))
+        for field, record in (("mla_moe", seqrec.MlaMoeWidths),
+                              ("sala", seqrec.SalaWidths),
+                              ("nemotron_h", seqrec.NemotronHWidths),
+                              ("laguna", seqrec.LagunaWidths)):
+            object.__setattr__(self, field,
+                               widths_of(record, getattr(self, field)))
 
     def seqrec_config(self, vocab: int) -> seqrec.SeqRecConfig:
         import jax.numpy as jnp
@@ -226,47 +222,18 @@ class SeqDispatch:
     tokens: int                 # events of the histories scored
     padded_tokens: int          # tokens the programs ran over
     split: int                  # 1 if the token budget split the dispatch
-    #: of the programs, those whose retention layers ran the fused
-    #: state pass (seqrec.fuses_retention)
-    fused_retention_programs: int = 0
-    #: of the programs, those whose mixers' way in (QK-norm, RoPE, chunk
-    #: order) ran the fused kernel (seqrec.fuses_qk_norm)
-    fused_qk_norm_programs: int = 0
-    #: of the programs, those whose routed layers moved their rows into
-    #: expert order and back with the kernel (seqrec.moves_moe_rows)
-    moe_row_dma_programs: int = 0
-    #: of the programs, those whose grouped products read an expert
-    #: weight in the order the TPU holds it, with no copy of it
-    #: (seqrec.reads_experts_as_held)
-    expert_layout_programs: int = 0
-    #: of the programs, those whose sliding-window layers ran the kernel
-    #: ``window_attention`` (seqrec.runs_window_attention)
-    window_attention_programs: int = 0
-    #: what a kind's programs counted on the device, by the kind's own
-    #: ``BlockKind.tally``, summed over the programs. The routed layers
-    #: ("deepseek_v2", "nemotron_h"): assignments to held experts over
-    #: all expert layers, the tokens routed (once, not per layer), and
-    #: the fullest (layer, expert)'s assignments
+    #: what a kind's programs counted on the device, summed over the
+    #: programs: each by the ``tally`` of the kinds that report it (its
+    #: docstring says what it counts; models/blocks/common.tally_routed
+    #: for the routed layers' three)
     moe_assignments: int = 0
     moe_tokens: int = 0
     moe_max_expert_load: int = 0
-    #: the sparse layers ("minicpm_sala"): rows that selected (position
-    #: x sparse layer x key/value head; 0 where the history is at or
-    #: under ``dense_len``), the key blocks they kept, and the keys
-    #: whose scores stage 2 computed for them (from the visit map the
-    #: kernel was given)
     sparse_rows: int = 0
     sparse_blocks_selected: int = 0
     sparse_keys_scored: int = 0
-    #: the state-space layers ("nemotron_h"): tokens their scans ran
-    #: over (a program's tokens x Mamba layers) and the chunk steps
-    #: that took; the ratio is the chunk the program ran
     ssm_tokens: int = 0
     ssm_scan_steps: int = 0
-    #: the sliding-window layers ("laguna"): rows (real position x
-    #: sliding layer x key/value head) and the keys scored for them, read
-    #: off the tile bounds the kernel was given (on the plain path, the
-    #: keys its window lets through)
     window_rows: int = 0
     window_keys_scored: int = 0
 
@@ -403,13 +370,6 @@ class SeqRecAlgorithm(HostModelAlgorithm):
                         masks[r, di] = _NEG
         inv = model.item_index.inverse
         programs = pos = 0
-        # one answer for every program: the rule looks at the widths and
-        # at the history length, not at the batch
-        fused = seqrec.fuses_retention(model.cfg, S)
-        fused_way_in = seqrec.fuses_qk_norm(model.cfg, S)
-        row_dma = seqrec.moves_moe_rows(model.cfg, S)
-        as_held = seqrec.reads_experts_as_held(model.cfg, S)
-        windowed = seqrec.runs_window_attention(model.cfg, S)
         kind = seqrec.BLOCKS[model.cfg.block]
         tally = kind.tally
         counted = collections.Counter()     # SeqDispatch field -> sum
@@ -443,11 +403,6 @@ class SeqRecAlgorithm(HostModelAlgorithm):
             model.dispatch_observer(SeqDispatch(
                 programs=programs, tokens=int(lengths.sum()),
                 padded_tokens=len(rows) * S, split=int(len(rows) > widest),
-                fused_retention_programs=programs if fused else 0,
-                fused_qk_norm_programs=programs if fused_way_in else 0,
-                moe_row_dma_programs=programs if row_dma else 0,
-                expert_layout_programs=programs if as_held else 0,
-                window_attention_programs=programs if windowed else 0,
                 **counted))
         return out
 

@@ -144,10 +144,11 @@ class TestBlockwiseAttention:
         """forward() must take the blockwise path at S >= 4096 (stubbed —
         the point is routing; the math is covered above)."""
         from predictionio_tpu.models import seqrec
+        from predictionio_tpu.models.blocks import sasrec
 
         calls = []
         monkeypatch.setattr(
-            seqrec, "blockwise_attention",
+            sasrec, "blockwise_attention",
             lambda q, k, v, **kw: calls.append(kw["q_block"]) or q,
         )
         cfg = seqrec.SeqRecConfig(vocab=50, max_len=4096, d_model=8,

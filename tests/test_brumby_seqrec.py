@@ -330,8 +330,7 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
     # one record a dispatch (sessionrec.SeqDispatch), always whole
     assert [(r.programs, r.tokens, r.padded_tokens, r.split)
             for (r,) in seen] == [(1, real, 8 * S, 0), (4, real, 8 * S, 1)]
-    assert all(r.fused_retention_programs == 0 and r.moe_tokens == 0
-               for (r,) in seen)
+    assert all(r.moe_tokens == 0 for (r,) in seen)
     for i in whole:
         assert [s.item for s in whole[i].item_scores] == \
             [s.item for s in split[i].item_scores]

@@ -218,8 +218,8 @@ def test_kernel_rule_is_shape_and_backend():
     assert not moe.uses_kernel(inference=True)
     cfg = sessionrec.AlgorithmParams(**PARAMS).seqrec_config(ITEMS + 1)
     assert seqrec.BLOCKS["deepseek_v2"].kernels(cfg, 8192) == ()
-    assert not seqrec.fuses_retention(cfg, 8192)
-    assert not seqrec.moves_moe_rows(cfg, 8192)
+    assert "retention_state_pass" not in seqrec.kernels(cfg, 8192)
+    assert "moe_rows" not in seqrec.kernels(cfg, 8192)
 
 
 def test_a_compiled_backend_moves_the_routed_rows_by_kernel(monkeypatch):
@@ -235,12 +235,12 @@ def test_a_compiled_backend_moves_the_routed_rows_by_kernel(monkeypatch):
     assert not moe.moves_rows_by_dma("compiled", 64)    # a DMA moves lanes
     # rows of 64: the grouped products as gmm, the rows by plain gathers
     assert seqrec.BLOCKS["deepseek_v2"].kernels(cfg, 8192)[-1:] == ("gmm",)
-    assert not seqrec.moves_moe_rows(cfg, 8192)
+    assert "moe_rows" not in seqrec.kernels(cfg, 8192)
     wide = dataclasses.replace(cfg, d_model=128)
     # experts 32 wide over rows of 128: the up products read as held
     assert seqrec.BLOCKS["deepseek_v2"].kernels(wide, 8192)[-3:] == (
         "gmm", "gmm_transpose_rhs", "moe_rows")
-    assert seqrec.moves_moe_rows(wide, 8192)
+    assert "moe_rows" in seqrec.kernels(wide, 8192)
 
 
 # -- the router and the routed layer -----------------------------------------
@@ -429,8 +429,6 @@ def test_config_and_params_hold_the_widths_in_one_record():
     again = params_from_json(sessionrec.AlgorithmParams,
                              json.loads(json.dumps(params_to_json(params))))
     assert again == params
-    with pytest.raises(ValueError, match="yarn"):
-        seqrec.MlaMoeWidths.of({"rope_scaling": {"type": "linear"}})
     tree = seqrec.init_params(jax.random.PRNGKey(0), cfg)
     assert "ffn" in tree["layers"][0] and "router" not in tree["layers"][0]
     assert tree["layers"][1]["router"].shape == (64, 16)
@@ -498,7 +496,7 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
     assert one.moe_assignments < 2 * 8 * S * 3
     assert one.moe_max_expert_load < four.moe_max_expert_load \
         <= four.moe_assignments
-    assert one.fused_retention_programs == 0
+    assert one.sparse_rows == one.ssm_tokens == 0
     stats = ServingStats()
     for report in seen:
         stats.record_seq_dispatch(report)
@@ -548,7 +546,7 @@ def test_engine_server_answers_match_the_reference(engine_model):
         assert 0 < serving["seqMoeAssignments"] < 2 * S * 3
         assert 0 < serving["seqMoeMaxExpertLoad"] <= \
             serving["seqMoeAssignments"]
-        assert serving["seqFusedRetentionPrograms"] == 0
+        assert serving["seqKernels"] == []                      # the CPU
         metrics = get("/metrics")
         assert b"pio_serving_seq_moe_tokens_total %d" % S in metrics
         assert b"pio_serving_seq_moe_assignments_total" in metrics

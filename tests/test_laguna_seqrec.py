@@ -24,11 +24,13 @@ import pytest
 
 from benchmarks.reference import laguna_jnp as ref
 from predictionio_tpu.api.stats import ServingStats
-from predictionio_tpu.models import laguna, seqrec
+from predictionio_tpu.models import seqrec
+from predictionio_tpu.models.blocks import laguna
 from predictionio_tpu.ops import mla_attention, moe, qk_norm
 from predictionio_tpu.ops import sparse_attention as sa
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
+from tests.seq_wiring import wired_stats
 
 ITEMS, S, WINDOW = 300, 96, 16
 TYPES = ["full_attention", "sliding_attention", "full_attention"]
@@ -485,16 +487,15 @@ def test_kernel_rule_and_bytes_a_token(monkeypatch):
     cfg = sessionrec.AlgorithmParams(**PARAMS).seqrec_config(ITEMS + 1)
     kind = seqrec.BLOCKS["laguna"]
     assert kind.kernels(cfg, 16384) == ()                   # the CPU
-    assert not seqrec.runs_window_attention(cfg, 16384)
+    assert "window_attention" not in seqrec.kernels(cfg, 16384)
     published = sessionrec.AlgorithmParams(**PUBLISHED).seqrec_config(
         vocab=25088)
     monkeypatch.setattr(pallas_attention, "_mode", lambda: "compiled")
     assert kind.kernels(published, 16384) == (
         "window_attention", "sparse_block_attention", "qk_norm_rope", "gmm",
         "moe_rows")
-    assert seqrec.runs_window_attention(published, 16384)
-    assert seqrec.fuses_qk_norm(published, 16384)
-    assert seqrec.moves_moe_rows(published, 16384)
+    assert {"window_attention", "qk_norm_rope", "moe_rows"} <= set(
+        seqrec.kernels(published, 16384))
     # heads of 16 lanes are no kernel's; rows of 64 are not whole lanes
     assert kind.kernels(cfg, 96) == ("gmm",)
     # no sliding layer, no window kernel; no expert layer, no grouped one
@@ -796,7 +797,6 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
     keys = sa.window_scored(S, WINDOW, False)
     assert one.window_keys_scored == four.window_keys_scored == \
         2 * (7 * int(keys.sum()) + int(keys[:40].sum()))
-    assert one.window_attention_programs == 0           # the CPU
     assert one.ssm_tokens == one.sparse_rows == 0
     stats = ServingStats()
     for report in seen:
@@ -812,27 +812,25 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
 
 def test_the_dispatch_counts_the_programs_that_ran_the_window_kernel(
         engine_model, monkeypatch):
-    """Where the rule says the windowed kernel runs, every program of the
-    dispatch counts, beside ``seq_programs``; where it does not (the
-    CPU), none does."""
+    """Where the rule says the windowed kernel runs, the server's gauge
+    lists it for every program of the deployment, beside
+    ``seq_programs``; where it does not (the CPU), it does not."""
     algo, model = engine_model
+    assert wired_stats(algo, model).snapshot()["seqKernels"] == []
+    monkeypatch.setattr(seqrec, "kernels", lambda cfg, s: (
+        "window_attention",) if s == cfg.max_len else ())
+    stats = wired_stats(algo, model)
     seen = []
     model.set_dispatch_observer(seen.append)
-    queries = [(i, sessionrec.Query(user=u, num=3))
-               for i, u in enumerate(["u0", "u2"])]
     try:
-        algo.batch_predict(model, queries)
-        monkeypatch.setattr(seqrec, "runs_window_attention",
-                            lambda cfg, s: True)
-        algo.batch_predict(model, queries)
+        algo.batch_predict(model, [(i, sessionrec.Query(user=u, num=3))
+                                   for i, u in enumerate(["u0", "u2"])])
     finally:
         model.set_dispatch_observer(None)
-    assert [(r.programs, r.window_attention_programs) for r in seen] == [
-        (seen[0].programs, 0), (seen[1].programs, seen[1].programs)]
-    stats = ServingStats()
-    stats.record_seq_dispatch(seen[1])
+    stats.record_seq_dispatch(seen[0])
     snap = stats.snapshot()
-    assert snap["seqWindowAttentionPrograms"] == snap["seqPrograms"] > 0
+    assert snap["seqKernels"] == ["window_attention"]
+    assert snap["seqPrograms"] > 0
 
 
 def test_engine_server_answers_match_the_reference(engine_model):
@@ -872,12 +870,12 @@ def test_engine_server_answers_match_the_reference(engine_model):
         keys = int(sa.window_scored(S, WINDOW, False).sum()) * 2
         assert serving["seqWindowRows"] == 2 * S
         assert serving["seqWindowKeysScored"] == keys
-        assert serving["seqWindowAttentionPrograms"] == 0   # the CPU
+        assert serving["seqKernels"] == []                  # the CPU
         metrics = get("/metrics")
         assert b"pio_serving_seq_window_rows_total %d" % (2 * S) in metrics
         assert b"pio_serving_seq_window_keys_scored_total %d" % keys \
             in metrics
-        assert b"pio_serving_seq_window_attention_programs_total 0" in metrics
+        assert b"pio_serving_seq_kernel{" not in metrics
     finally:
         server.stop()
         model.set_dispatch_observer(None)

@@ -23,6 +23,7 @@ import pytest
 from benchmarks.reference import minicpm_sala_jnp as ref
 from predictionio_tpu.api.stats import ServingStats
 from predictionio_tpu.models import seqrec
+from predictionio_tpu.models.blocks import minicpm_sala
 from predictionio_tpu.ops import qk_norm, retention, sparse_attention as sa
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
@@ -207,7 +208,7 @@ def _qkv(seed, n, H=4, d=16):
 def test_the_chunked_lightning_scan_is_the_quadratic_form(n, chunk):
     """chunk < n: the state is carried; n not whole chunks: padded."""
     q, k, v = _qkv(5, n)
-    log_g = jnp.asarray(seqrec.lightning_log_decay(4))
+    log_g = jnp.asarray(minicpm_sala.lightning_log_decay(4))
     got = retention.power_retention(q, k, v, log_g, degree=1, chunk=chunk)
     want = _quadratic(*(np.asarray(a, np.float32) for a in (q, k, v)), log_g)
     # the in-chunk weights and the output are rounded to bfloat16 once:
@@ -223,7 +224,7 @@ def test_the_chunked_lightning_scan_is_the_quadratic_form(n, chunk):
 def test_a_dropped_or_bfloat16_lightning_state_is_caught():
     q, k, v = _qkv(6, 256)
     # the slowest head alone: it remembers ~256 positions
-    log_g = jnp.full((4,), float(seqrec.lightning_log_decay(32)[-1]))
+    log_g = jnp.full((4,), float(minicpm_sala.lightning_log_decay(32)[-1]))
     want = _quadratic(*(np.asarray(a, np.float32) for a in (q, k, v)), log_g)
 
     def off(**kw):
@@ -328,7 +329,7 @@ def test_the_lightning_mixers_way_in_is_the_parents_formulation():
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
     # and the mixing over them is the mixing over the parent's operands
-    log_decay = jnp.asarray(seqrec.lightning_log_decay(H))
+    log_decay = jnp.asarray(minicpm_sala.lightning_log_decay(H))
     plain = [cases.plain_way_in(x, w, heads=H, eps=1e-6, theta=10000.0)
              for x, w in ((q, layer["q_norm"]), (k, layer["k_norm"]))]
     np.testing.assert_array_equal(
@@ -446,8 +447,8 @@ def test_only_a_compiled_backend_at_an_eligible_shape_runs_the_kernels(
     assert full.sala.mixer_types.count("minicpm4") == 8
     assert seqrec.BLOCKS["minicpm_sala"].kernels(full, 32768) == \
         sa.kernel_names(32768, sz) + ("qk_norm_rope",)
-    assert seqrec.fuses_qk_norm(full, 32768)
-    assert not seqrec.fuses_qk_norm(cfg, S)                 # heads of 16
+    assert "qk_norm_rope" in seqrec.kernels(full, 32768)
+    assert "qk_norm_rope" not in seqrec.kernels(cfg, S)     # heads of 16
 
 
 # -- the program against the reference ---------------------------------------
@@ -548,7 +549,7 @@ def test_the_cut_stack_is_layers_9_to_16_of_the_uncut_model():
     program over layers 9-16 alone, fed layer 9's input activations
     (through an embedding table that holds them), with c = 1.4 /
     sqrt(32) in both."""
-    uncut = list(seqrec._SALA_MIXERS)
+    uncut = list(minicpm_sala._SALA_MIXERS)
     n = 96
     full_cfg, full = _weights(seed=4, dtype=jnp.float32, n_layers=32,
                               sala={**WIDTHS, "mixer_types": uncut})
@@ -638,7 +639,7 @@ def test_batch_predict_matches_the_reference_and_reports_the_selection(
         min(t // 16 + 1, 6) for n in (192, 100, 192) for t in range(n))
     assert report.sparse_keys_scored == 4 * 16 * sum(
         t // 16 + 1 for n in (192, 100, 192) for t in range(n))
-    assert report.moe_tokens == report.fused_retention_programs == 0
+    assert report.moe_tokens == 0
     stats = ServingStats()
     stats.record_seq_dispatch(report)
     stats.record_seq_dispatch(report)

@@ -5,26 +5,25 @@ minor, and ``gmm`` reads it as its transpose instead of copying it whole
 into row-major order first. On the CPU (interpret mode) the transposed
 read is the read as given bit for bit; at whole-lane widths, and in the
 plain form, the rule is the identity: the traced program is the same.
-Then the counter, ``seq_expert_layout_programs``, by the kind's rule.
+Then the server's gauge of kernels, ``serving.seqKernels``, by the kind's
+rule.
 ``tests/test_retention_kernel.py`` compiles the Nemotron-H cell's widths
 for a described v5e (no copy of an expert weight); ``chip_smoke.py``
 holds the compiled layer to the read as given on the chip."""
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from predictionio_tpu.api.stats import ServingStats
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.obs.registry import serving_collector
 from predictionio_tpu.ops import moe, pallas_attention
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
+from tests.seq_wiring import wired_stats
 
 #: rows of 256 (whole lanes); experts 208 wide (not whole lanes: held
 #: transposed) and 128, 1,024, 1,536 wide (whole lanes: held as given)
@@ -88,8 +87,9 @@ KINDS = {
 def test_the_counter_counts_the_programs_that_read_the_experts_as_held(
         kind, monkeypatch):
     """Where the rule says the kernel runs (a compiled TPU backend, the
-    widths above), every program of a Nemotron-H-shaped model counts and
-    none of a DeepSeek-V2-shaped one; on the CPU none does."""
+    widths above), the server's gauge of a Nemotron-H-shaped model lists
+    ``gmm_transpose_rhs`` and a DeepSeek-V2-shaped one's does not; on
+    the CPU neither does."""
     import importlib
 
     module_name, key, ffn, as_held = KINDS[kind]
@@ -108,34 +108,33 @@ def test_the_counter_counts_the_programs_that_read_the_experts_as_held(
                    .astype(np.int32) for u, n in enumerate([S, 9, S])})
     algo = sessionrec.SeqRecAlgorithm(params)
     queries = [(i, sessionrec.Query(user=f"u{i}", num=3)) for i in range(3)]
-    rule = seqrec.reads_experts_as_held
+    rule = seqrec.kernels
 
     def on_a_chip(cfg, seq_len):
         with monkeypatch.context() as m:
             m.setattr(pallas_attention, "_mode", lambda: "compiled")
             return rule(cfg, seq_len)
 
-    assert not rule(cfg, S)                                  # the CPU
-    assert on_a_chip(cfg, S) == as_held
+    assert "gmm_transpose_rhs" not in rule(cfg, S)           # the CPU
+    assert ("gmm_transpose_rhs" in on_a_chip(cfg, S)) == as_held
+    cpu_stats = wired_stats(algo, model)
     seen = []
     model.set_dispatch_observer(seen.append)
     try:
         algo.batch_predict(model, queries)
-        monkeypatch.setattr(seqrec, "reads_experts_as_held", on_a_chip)
+        monkeypatch.setattr(seqrec, "kernels", on_a_chip)
         algo.batch_predict(model, queries)
     finally:
         model.set_dispatch_observer(None)
+    chip_stats = wired_stats(algo, model)
     cpu, chip = seen
     assert cpu.programs == chip.programs > 0
-    assert cpu.expert_layout_programs == 0
-    assert chip.expert_layout_programs == (chip.programs if as_held else 0)
-    # the other counters do not move with it
-    assert dataclasses.replace(chip, expert_layout_programs=0) == cpu
-    stats = ServingStats()
-    stats.record_seq_dispatch(chip)
-    snap = stats.snapshot()
-    assert snap["seqExpertLayoutPrograms"] == (
-        snap["seqPrograms"] if as_held else 0)
-    exposed = {m.name: m.samples for m in serving_collector(stats)()}
-    assert exposed["pio_serving_seq_expert_layout_programs_total"] == [
-        ({}, float(snap["seqExpertLayoutPrograms"]))]
+    # the dispatch's counters do not move with the rule
+    assert chip == cpu
+    assert cpu_stats.snapshot()["seqKernels"] == []
+    snap = chip_stats.snapshot()
+    assert ("gmm_transpose_rhs" in snap["seqKernels"]) == as_held
+    assert snap["seqKernels"] == sorted(on_a_chip(cfg, S))
+    exposed = {m.name: m.samples for m in serving_collector(chip_stats)()}
+    assert exposed["pio_serving_seq_kernel"] == [
+        ({"kernel": name}, 1.0) for name in snap["seqKernels"]]

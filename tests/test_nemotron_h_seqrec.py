@@ -26,10 +26,12 @@ import pytest
 from benchmarks.reference import nemotron_h_jnp as ref
 from predictionio_tpu.api.stats import ServingStats
 from predictionio_tpu.models import seqrec
+from predictionio_tpu.models.blocks import nemotron_h
 from predictionio_tpu.ops import moe, retention, ssm
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
 from tests import retention_cases as cases
+from tests.seq_wiring import wired_stats
 
 ITEMS, S = 300, 96
 PATTERN = "EMEM*"
@@ -615,9 +617,7 @@ def test_kernel_rule_is_shape_and_backend(monkeypatch):
     cfg = sessionrec.AlgorithmParams(**PARAMS).seqrec_config(ITEMS + 1)
     kind = seqrec.BLOCKS["nemotron_h"]
     assert kind.kernels(cfg, 16384) == ()                   # the CPU
-    assert not seqrec.fuses_retention(cfg, 16384)
-    assert not seqrec.fuses_qk_norm(cfg, 16384)
-    assert not seqrec.moves_moe_rows(cfg, 16384)
+    assert seqrec.kernels(cfg, 16384) == ()
     published = sessionrec.AlgorithmParams(
         backbone="nemotron_h", d_model=2688, n_heads=32, n_kv_heads=2,
         head_dim=128, n_layers=9, max_len=16384, nemotron_h={
@@ -631,12 +631,12 @@ def test_kernel_rule_is_shape_and_backend(monkeypatch):
         "gmm", "gmm_transpose_rhs", "moe_rows")
     # the routed layers' row moves: the kinds that have them, by the rule
     # the forward pass applies (ops/moe.uses_kernel)
-    assert seqrec.moves_moe_rows(published, 16384)
-    assert not seqrec.moves_moe_rows(cfg, 16384)   # rows of 64: not lanes
+    assert "moe_rows" in seqrec.kernels(published, 16384)
+    assert "moe_rows" not in seqrec.kernels(cfg, 16384)  # rows of 64
     no_experts = dataclasses.replace(published, nemotron_h=dataclasses.replace(
         published.nemotron_h, hybrid_override_pattern="M*"), n_layers=2)
-    assert not seqrec.moves_moe_rows(no_experts, 16384)
-    assert not seqrec.moves_moe_rows(sessionrec.AlgorithmParams(
+    assert "moe_rows" not in seqrec.kernels(no_experts, 16384)
+    assert "moe_rows" not in seqrec.kernels(sessionrec.AlgorithmParams(
         backbone="brumby").seqrec_config(vocab=11), 16384)
     # a token of the program holds 272 KB: the budget takes one history
     assert kind.bytes_per_token(published) == 271_872
@@ -754,11 +754,11 @@ def test_a_served_scan_that_lost_its_carry_fails_the_check():
                 sum(n for _, n in parts))
 
     try:
-        seqrec.ssd_scan = lost
+        nemotron_h.ssd_scan = lost
         jax.clear_caches()
         cut, _ = _program_logits(weights, cfg, history)
     finally:
-        seqrec.ssd_scan = real
+        nemotron_h.ssd_scan = real
         jax.clear_caches()
     assert np.max(np.abs(cut - own)) > ref.SCORE_TOL
     want = np.asarray(ref.last_logits(weights, history,
@@ -920,8 +920,7 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
     # and program, whatever the batch
     assert one.ssm_tokens == four.ssm_tokens == 2 * 8 * S
     assert (one.ssm_scan_steps, four.ssm_scan_steps) == (2 * 3, 4 * 2 * 3)
-    assert one.fused_retention_programs == one.sparse_rows == 0
-    assert one.moe_row_dma_programs == four.moe_row_dma_programs == 0
+    assert one.sparse_rows == four.window_rows == 0
     stats = ServingStats()
     for report in seen:
         stats.record_seq_dispatch(report)
@@ -936,28 +935,25 @@ def test_token_budget_splits_a_batch_and_counters_report_it(
 
 def test_the_dispatch_counts_the_programs_that_moved_rows_by_dma(
         engine_model, monkeypatch):
-    """Where the rule says the row kernel runs, every program of the
-    dispatch counts, and the stats add them up beside ``seq_programs``;
-    where it does not (the CPU), none does."""
+    """Where the rule says the row kernel runs, the server's gauge lists
+    it for every program of the deployment, beside ``seq_programs``;
+    where it does not (the CPU), it does not."""
     algo, model = engine_model
+    assert wired_stats(algo, model).snapshot()["seqKernels"] == []
+    monkeypatch.setattr(seqrec, "kernels", lambda cfg, s: (
+        "gmm", "moe_rows") if s == cfg.max_len else ())
+    stats = wired_stats(algo, model)
     seen = []
     model.set_dispatch_observer(seen.append)
-    queries = [(i, sessionrec.Query(user=u, num=3))
-               for i, u in enumerate(["u0", "u2"])]
     try:
-        algo.batch_predict(model, queries)
-        monkeypatch.setattr(seqrec, "moves_moe_rows", lambda cfg, s: True)
-        algo.batch_predict(model, queries)
+        algo.batch_predict(model, [(i, sessionrec.Query(user=u, num=3))
+                                   for i, u in enumerate(["u0", "u2"])])
     finally:
         model.set_dispatch_observer(None)
-    assert [(r.programs, r.moe_row_dma_programs) for r in seen] == [
-        (seen[0].programs, 0), (seen[1].programs, seen[1].programs)]
-    stats = ServingStats()
-    stats.record_seq_dispatch(seen[1])
-    assert stats.count("seq_moe_row_dma_programs") == \
-        stats.count("seq_programs") > 0
+    stats.record_seq_dispatch(seen[0])
     snap = stats.snapshot()
-    assert snap["seqMoeRowDmaPrograms"] == snap["seqPrograms"]
+    assert snap["seqKernels"] == ["gmm", "moe_rows"]
+    assert snap["seqPrograms"] > 0
 
 
 def test_engine_server_answers_match_the_reference(engine_model):
@@ -998,11 +994,10 @@ def test_engine_server_answers_match_the_reference(engine_model):
             serving["seqMoeAssignments"]
         assert serving["seqSsmTokens"] == 2 * S
         assert serving["seqSsmScanSteps"] == 2 * 3
-        assert serving["seqFusedRetentionPrograms"] == 0
         # the row kernel runs on a compiled TPU backend only
-        assert serving["seqMoeRowDmaPrograms"] == 0
+        assert serving["seqKernels"] == []
         metrics = get("/metrics")
-        assert b"pio_serving_seq_moe_row_dma_programs_total 0" in metrics
+        assert b"pio_serving_seq_kernel{" not in metrics
         assert b"pio_serving_seq_ssm_tokens_total %d" % (2 * S) in metrics
         assert b"pio_serving_seq_ssm_scan_steps_total 6" in metrics
         assert b"pio_serving_seq_moe_assignments_total" in metrics

@@ -29,6 +29,7 @@ from predictionio_tpu.ops import (
 from predictionio_tpu.templates import sessionrec
 from predictionio_tpu.utils.bimap import BiMap
 from tests import retention_cases as cases
+from tests.seq_wiring import wired_stats
 
 D = 128
 F32 = jnp.float32
@@ -225,7 +226,7 @@ def test_gradients_through_the_block_stack_take_the_jnp_step(
 
     before = jax.jit(jax.grad(loss))(model.params)
     monkeypatch.setattr(pallas_attention, "_mode", lambda: "compiled")
-    assert seqrec.fuses_retention(model.cfg, S)
+    assert "retention_state_pass" in seqrec.kernels(model.cfg, S)
     # traced anew: the rule is asked again, and answers for inference only
     after = jax.jit(jax.grad(lambda params: loss(params)))(model.params)
     for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
@@ -236,29 +237,33 @@ def test_gradients_through_the_block_stack_take_the_jnp_step(
 def test_the_dispatch_reports_fused_programs_when_the_rule_says_so(
         engine_model, monkeypatch):
     algo, model = engine_model
-    assert not seqrec.fuses_retention(seqrec.SeqRecConfig(vocab=11), 16384)
+    assert "retention_state_pass" not in seqrec.kernels(
+        seqrec.SeqRecConfig(vocab=11), 16384)
     seen = []
     model.set_dispatch_observer(lambda *a: seen.append(a))
     queries = [(0, sessionrec.Query(user="u0", num=3)),
                (1, sessionrec.Query(user="u1", num=3))]
     try:
-        assert not seqrec.fuses_retention(model.cfg, S)          # the CPU
-        algo.batch_predict(model, queries)
-        # the answer the dispatch would get on a chip; the programs
-        # themselves are compiled already and run as they were
-        monkeypatch.setattr(seqrec, "fuses_retention", lambda cfg, s: True)
+        assert seqrec.kernels(model.cfg, S) == ()                 # the CPU
         algo.batch_predict(model, queries)
     finally:
         model.set_dispatch_observer(None)
-    # one record a dispatch (sessionrec.SeqDispatch), always whole: the
-    # count is a named field, 0 where no program fused
-    assert [dataclasses.astuple(r)[:5] for (r,) in seen] == \
-        [(1, S + 40, 2 * S, 0, 0), (1, S + 40, 2 * S, 0, 1)]
+    # one record a dispatch (sessionrec.SeqDispatch), always whole
+    assert [dataclasses.astuple(r)[:4] for (r,) in seen] == \
+        [(1, S + 40, 2 * S, 0)]
     stats = ServingStats()
     for report in seen:
         stats.record_seq_dispatch(*report)
-    assert stats.count("seq_programs") == 2
-    assert stats.count("seq_fused_retention_programs") == 1
+    assert stats.count("seq_programs") == 1
+    # the kernels are a gauge the server sets when it wires the model,
+    # by the rule the dispatch's programs apply at max_len: on the CPU
+    # none, and on a chip the answer the rule gives there
+    assert wired_stats(algo, model).snapshot()["seqKernels"] == []
+    monkeypatch.setattr(seqrec, "kernels",
+                        lambda cfg, s: ("retention_state_pass",)
+                        if s == S else ())
+    assert wired_stats(algo, model).snapshot()["seqKernels"] == [
+        "retention_state_pass"]
 
 
 def test_stats_and_metrics_show_the_counter_at_zero_on_the_cpu(engine_model):
@@ -290,14 +295,13 @@ def test_stats_and_metrics_show_the_counter_at_zero_on_the_cpu(engine_model):
         with urllib.request.urlopen(f"{base}/stats.json") as resp:
             serving = json.loads(resp.read())["serving"]
         assert serving["seqPrograms"] == 1
-        assert serving["seqFusedRetentionPrograms"] == 0
+        assert serving["seqKernels"] == []                      # the CPU
         # no ALS top-k runs behind the session engine (PR 30's counter)
         assert serving["topkTwoStageDispatches"] == 0
         with urllib.request.urlopen(f"{base}/metrics") as resp:
             metrics = resp.read()
-        assert b"pio_serving_seq_fused_retention_programs_total 0" in metrics
-        assert b"pio_serving_seq_fused_qk_norm_programs_total 0" in metrics
-        assert serving["seqFusedQkNormPrograms"] == 0
+        assert b"# TYPE pio_serving_seq_kernel gauge" in metrics
+        assert b"pio_serving_seq_kernel{" not in metrics
     finally:
         server.stop()
         model.set_dispatch_observer(None)
@@ -440,8 +444,9 @@ def test_retention_takes_the_fused_way_in_when_the_rule_says_so(monkeypatch):
 def test_the_dispatch_reports_the_fused_way_in_beside_the_state_pass(
         engine_model, monkeypatch):
     algo, model = engine_model
-    assert not seqrec.fuses_qk_norm(seqrec.SeqRecConfig(vocab=11), 16384)
-    assert not seqrec.fuses_qk_norm(model.cfg, S)                # the CPU
+    assert "qk_norm_rope" not in seqrec.kernels(
+        seqrec.SeqRecConfig(vocab=11), 16384)
+    assert "qk_norm_rope" not in seqrec.kernels(model.cfg, S)    # the CPU
     full = sessionrec.AlgorithmParams(
         backbone="brumby", d_model=5120, n_heads=40, n_kv_heads=8,
         head_dim=128, d_ff=17408, n_layers=4,
@@ -450,24 +455,18 @@ def test_the_dispatch_reports_the_fused_way_in_beside_the_state_pass(
     monkeypatch.setattr(pallas_attention, "_mode", lambda: "compiled")
     assert seqrec.BLOCKS["brumby"].kernels(full, 16384) == (
         "retention_state_pass", "qk_norm_rope")
-    assert seqrec.fuses_qk_norm(full, 16384)
-    assert seqrec.fuses_qk_norm(model.cfg, S)         # heads of 128 here
-    assert not seqrec.fuses_qk_norm(
+    assert "qk_norm_rope" in seqrec.kernels(full, 16384)
+    assert "qk_norm_rope" in seqrec.kernels(model.cfg, S)  # heads of 128 here
+    assert "qk_norm_rope" not in seqrec.kernels(
         dataclasses.replace(full, head_dim=16), 16384)
+    # both kernels of the model, on the chip, in the server's gauge
+    assert wired_stats(algo, model).snapshot()["seqKernels"] == sorted(
+        seqrec.kernels(model.cfg, S))
     monkeypatch.undo()
-    seen = []
-    model.set_dispatch_observer(seen.append)
-    try:
-        monkeypatch.setattr(seqrec, "fuses_qk_norm", lambda cfg, s: True)
-        algo.batch_predict(model, [(0, sessionrec.Query(user="u0", num=3))])
-    finally:
-        model.set_dispatch_observer(None)
-    assert [(r.programs, r.fused_retention_programs, r.fused_qk_norm_programs)
-            for r in seen] == [(1, 0, 1)]
     stats = ServingStats()
-    stats.record_seq_dispatch(seen[0])
-    assert stats.count("seq_fused_qk_norm_programs") == 1
-    assert stats.count("seq_fused_retention_programs") == 0
+    stats.set_seq_kernels(("qk_norm_rope",))
+    assert stats.seq_kernels() == ("qk_norm_rope",)
+    assert stats.snapshot()["seqKernels"] == ["qk_norm_rope"]
 
 
 # -- the chip's compiler, without the chip -----------------------------------
